@@ -374,6 +374,8 @@ def main(argv=None):
     # entry (serving.decode / serving.spec_verify budget: 1) raises
     # RecompileError mid-drain
     os.environ.setdefault("PADDLE_TPU_STRICT_COMPILE", "1")
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python bench_decode.py",
         description="serving decode benchmark (A/B matrix over cache "

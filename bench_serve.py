@@ -163,7 +163,9 @@ def main(argv=None):
     from paddle_tpu.serving.router import Router
     from paddle_tpu.serving.scheduler import (
         ContinuousBatchingScheduler, Request)
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     spec = 0 if args.spec in ("off", "0") else int(args.spec)
     overlap = args.overlap == "on"
     on_tpu = jax.default_backend() == "tpu"
@@ -238,12 +240,16 @@ def main(argv=None):
         router = None
         prefill_engine = None
         if args.replicas > 1:
+            # one replica a device while there are devices (a fleet that
+            # leaves placement to the default is N engines on chip 0);
+            # more replicas than devices share them round-robin
             engines = [DecodeEngine(model, num_slots=num_slots,
                                     max_len=max_len, seed=0,
                                     page_size=page_size,
                                     kv_dtype=kv_dtype, spec_k=spec,
-                                    tracer=tracer, tp=args.tp)
-                       for _ in range(args.replicas)]
+                                    tracer=tracer,
+                                    device=devices[i % len(devices)])
+                       for i in range(args.replicas)]
             engine = engines[0]
             # deterministic per-replica warmup: routing is load-shaped,
             # so an HTTP warmup drive cannot GUARANTEE every replica

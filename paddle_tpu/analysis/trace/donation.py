@@ -12,7 +12,7 @@ configs that is the difference between fitting and OOM.
 Mechanically: the lowered StableHLO entry (``func.func public @main``)
 carries ``tf.aliasing_output = N`` on every input argument whose donation
 materialized; a flat input that the jaxpr declares donated
-(``donated_invars`` on the pjit equation, or the registry's recorded
+(``donated_invars`` on the jit equation, or the registry's recorded
 metadata) but whose entry argument carries no aliasing attribute is a
 donation miss.  Findings are keyed by the flat input's tree label
 (``in[3]:params/linear.weight``) so baselines survive unrelated
@@ -32,17 +32,35 @@ _MAIN_RE = re.compile(
     r"func\.func\s+(?:public\s+)?@main\s*\((?P<args>.*?)\)\s*->"
     r"(?P<results>[^\n]*)",
     re.S)
-#: attrs are brace-delimited but may CONTAIN braces inside quoted
-#: strings — a sharded entry's arguments carry
-#: ``mhlo.sharding = "{devices=[...]<=[N]}"`` ahead of
-#: ``tf.aliasing_output`` (ISSUE 12), and a naive ``[^}]*`` stops at the
-#: quoted ``}`` and silently drops every attribute after the sharding,
-#: reporting materialized donations as misses on exactly the sharded
-#: entries the audit was extended to cover
 _ARG_RE = re.compile(
-    r"%arg(?P<idx>\d+):\s*(?P<type>(?:tensor|!stablehlo\.token)[^{,)]*)"
-    r"(?:\{(?P<attrs>(?:\"[^\"]*\"|[^{}\"])*)\})?")
+    r"%arg(?P<idx>\d+):\s*(?P<type>(?:tensor|!stablehlo\.token)[^{,)]*)")
 _TYPE_RE = re.compile(r"tensor<[^>]+>")
+
+
+def _balanced_attrs(text: str, pos: int) -> str:
+    """The ``{...}`` attribute dict starting at ``text[pos]`` (after
+    optional blanks), or "" when the argument has none.  Braces nest — a
+    sharded entry's arguments carry ``sdy.sharding = #sdy.sharding<@mesh,
+    [{}, {"mp"}]>`` AHEAD of ``tf.aliasing_output`` — and may sit inside
+    quoted strings, so the dict is matched by depth, not by regex: cutting
+    it short reads materialized donations as misses on exactly the sharded
+    entries."""
+    while pos < len(text) and text[pos] in " \t\n":
+        pos += 1
+    if pos >= len(text) or text[pos] != "{":
+        return ""
+    depth, quoted, start = 0, False, pos
+    for i in range(pos, len(text)):
+        c = text[i]
+        if c == '"':
+            quoted = not quoted
+        elif not quoted and c == "{":
+            depth += 1
+        elif not quoted and c == "}":
+            depth -= 1
+            if depth == 0:
+                return text[start + 1:i]
+    return text[start + 1:]
 
 
 def parse_entry_aliasing(lowered_text: str
@@ -63,8 +81,9 @@ def parse_entry_aliasing(lowered_text: str
         return None
     result_types = _TYPE_RE.findall(m.group("results"))
     out: Dict[int, Dict[str, Any]] = {}
-    for am in _ARG_RE.finditer(m.group("args")):
-        attrs = am.group("attrs") or ""
+    args = m.group("args")
+    for am in _ARG_RE.finditer(args):
+        attrs = _balanced_attrs(args, am.end())
         ty = am.group("type").strip()
         out[int(am.group("idx"))] = {
             "aliased": "tf.aliasing_output" in attrs,
@@ -77,14 +96,14 @@ def parse_entry_aliasing(lowered_text: str
 
 def declared_donations(program: TraceProgram) -> Optional[Tuple[bool, ...]]:
     """Per-flat-input donation flags: the registry's recorded metadata
-    first, else the ``donated_invars`` of the outermost pjit equation."""
+    first, else the ``donated_invars`` of the outermost jit equation."""
     meta = program.meta.get("donated_invars")
     if meta is not None:
         return tuple(bool(b) for b in meta)
     if program.jaxpr is None:
         return None
     for site in walk_eqns(program.jaxpr):
-        if site.depth == 0 and site.eqn.primitive.name == "pjit":
+        if site.depth == 0 and site.eqn.primitive.name == "jit":
             di = site.eqn.params.get("donated_invars")
             if di is not None and any(di):
                 return tuple(bool(b) for b in di)

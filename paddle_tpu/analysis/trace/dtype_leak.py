@@ -19,7 +19,7 @@ an MXU op or a transcendental — is the leak signal.
 
 Scoping: consumers are resolved within the upcast's own jaxpr scope; a
 value escaping into a subjaxpr is accounted to the call primitive
-(``scan``/``cond``/``pjit`` are allowlisted — the subjaxpr's own converts
+(``scan``/``cond``/``jit`` are allowlisted — the subjaxpr's own converts
 are audited in their own scope).
 """
 from __future__ import annotations
@@ -57,7 +57,7 @@ F32_ACCUM_OPS = frozenset({
     # comparisons (produce bool)
     "lt", "le", "gt", "ge", "eq", "ne", "is_finite",
     # call primitives — bodies audited in their own scope
-    "scan", "while", "cond", "pjit", "closed_call", "core_call",
+    "scan", "while", "cond", "jit", "closed_call", "core_call",
     "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
     "remat", "checkpoint", "shard_map", "pallas_call", "named_call",
 })

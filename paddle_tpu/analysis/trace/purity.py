@@ -16,7 +16,7 @@ Three program hygiene invariants at the jaxpr level:
   duplicates, e.g. a re-computed lse that the bwd already receives as a
   residual).  Same expensive-primitive scoping.
 * **stray host callback** — ``pure_callback`` / ``io_callback`` /
-  ``debug_callback`` (``jax.debug.print``) in a production program
+  ``debug_callback`` / ``debug_print`` (``jax.debug.print``) in a production program
   force a device→host round-trip per step; a leftover debug print in the
   train step is a silent multi-ms stall.  Programs that legitimately
   call back (registered with ``allow_callbacks``) are exempt.
@@ -35,7 +35,7 @@ __all__ = ["EXPENSIVE_PRIMS", "CALLBACK_PRIMS", "PurityPass"]
 EXPENSIVE_PRIMS = frozenset({
     "dot_general", "conv_general_dilated", "reduce_sum", "reduce_max",
     "reduce_min", "reduce_prod", "cumsum", "cumlogsumexp", "sort",
-    "scatter", "scatter-add", "gather", "scan", "while", "pjit",
+    "scatter", "scatter-add", "gather", "scan", "while", "jit",
     "pallas_call", "custom_vjp_call", "custom_jvp_call", "shard_map",
     "exp", "log", "tanh", "erf", "logistic", "rsqrt",
 })

@@ -74,45 +74,17 @@ class KernelFootprint:
                    VMEM_LIMIT_BYTES / 1024, VMEM_RESERVE_BYTES / 1024))
 
 
-def _block_elems(block_shape) -> int:
-    """Product of a BlockSpec block shape; non-int entries (mapped /
-    squeezed dims) occupy one element along that axis."""
+def _ref_bytes(ref) -> int:
+    """VMEM bytes of one kernel ref aval (``shape``/``dtype``/
+    ``memory_space``): operands left in HBM (ANY), SMEM refs and
+    semaphores are not VMEM tiles and price to 0."""
+    space = str(getattr(ref, "memory_space", None)).lower()
+    if "any" in space or "smem" in space or "semaphore" in space:
+        return 0
     n = 1
-    for dim in block_shape:
-        n *= dim if isinstance(dim, int) else 1
-    return n
-
-
-def _scratch_bytes(eqn, num_scratch: int) -> (int, List[str]):
-    """Price the kernel's explicit scratch from the trailing invars of the
-    kernel jaxpr (their avals carry shape/dtype; semaphores and SMEM refs
-    price to ~0 — they are not VMEM tiles)."""
-    total, detail = 0, []
-    if not num_scratch:
-        return total, detail
-    kernel_jaxpr = getattr(eqn.params.get("jaxpr"), "jaxpr",
-                           eqn.params.get("jaxpr"))
-    if kernel_jaxpr is None:
-        return total, detail
-    for var in kernel_jaxpr.invars[-num_scratch:]:
-        aval = getattr(var, "aval", None)
-        if aval is None:
-            continue
-        space = str(getattr(aval, "memory_space", "")).lower()
-        dtype = getattr(aval, "dtype", None)
-        shape = getattr(aval, "shape", ())
-        if dtype is None or "semaphore" in str(dtype).lower() \
-                or "semaphore" in space:
-            continue
-        if "smem" in space:
-            continue
-        n = 1
-        for d in shape:
-            n *= int(d)
-        b = n * dtype.itemsize
-        total += b
-        detail.append("scratch%s %s = %d B" % (tuple(shape), dtype, b))
-    return total, detail
+    for d in ref.shape:
+        n *= int(d)
+    return n * ref.dtype.itemsize
 
 
 def pallas_footprints(closed_jaxpr, name: str = "<program>"
@@ -122,33 +94,29 @@ def pallas_footprints(closed_jaxpr, name: str = "<program>"
     for site in walk_eqns(closed_jaxpr, into_pallas=False):
         if site.eqn.primitive.name != "pallas_call":
             continue
-        gm = site.eqn.params.get("grid_mapping")
-        if gm is None:
-            continue
+        gm = site.eqn.params["grid_mapping"]
         fp = KernelFootprint(name, site.path)
         # grid of extent 1 is visited once — no pipelining, single buffer
-        grid = getattr(gm, "grid", ())
         multi_step = 1
-        for g in grid:
+        for g in gm.grid:
             multi_step *= int(g) if isinstance(g, int) else 2
         dbuf = 2 if multi_step > 1 else 1
         for bm in gm.block_mappings:
-            block = getattr(bm, "block_shape", None)
-            aval = getattr(bm, "array_shape_dtype", None)
-            if block is None or aval is None:
-                continue
-            space = str(getattr(bm, "block_aval", "")).lower()
-            if "memoryspace.any" in space or "<any>" in space:
-                # ANY-space operand: stays in HBM, DMA'd via counted scratch
-                continue
-            b = _block_elems(block) * aval.dtype.itemsize * dbuf
+            # block_aval is the per-grid-step tile the kernel body sees
+            # (squeezed dims already dropped to extent 1)
+            ref = bm.block_aval
+            b = _ref_bytes(ref) * dbuf
+            if not b:
+                continue      # ANY-space: stays in HBM, DMA'd via scratch
             fp.operand_bytes += b
             fp.detail.append("block%s %s x%d = %d B"
-                             % (tuple(block), aval.dtype, dbuf, b))
-        sb, sdetail = _scratch_bytes(site.eqn,
-                                     getattr(gm, "num_scratch_operands", 0))
-        fp.scratch_bytes += sb
-        fp.detail.extend(sdetail)
+                             % (tuple(ref.shape), ref.dtype, dbuf, b))
+        for ref in gm.scratch_avals:
+            b = _ref_bytes(ref)
+            if b:
+                fp.scratch_bytes += b
+                fp.detail.append("scratch%s %s = %d B"
+                                 % (tuple(ref.shape), ref.dtype, b))
         out.append(fp)
     return out
 

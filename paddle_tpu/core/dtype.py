@@ -84,15 +84,10 @@ def is_integer(dtype) -> bool:
 
 
 def x64_scope(enable: bool):
-    """Version-portable ``jax.enable_x64`` context manager.
+    """``jax.enable_x64(enable)`` as a context manager.
 
-    The top-level ``jax.enable_x64`` re-export was removed in newer jax;
-    ``jax.experimental.enable_x64`` is the surviving spelling.  Pallas
-    kernels and the CE loss trace under ``x64_scope(False)`` because
-    mosaic cannot lower i64/f64 even though the global x64 mode is on.
+    Pallas kernels trace under ``x64_scope(False)`` because Mosaic cannot
+    lower i64/f64 even though the global x64 mode is on.
     """
     import jax
-    ctx = getattr(jax, "enable_x64", None)
-    if ctx is None:
-        from jax.experimental import enable_x64 as ctx
-    return ctx(enable)
+    return jax.enable_x64(enable)

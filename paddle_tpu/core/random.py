@@ -21,13 +21,15 @@ class Generator:
     """A stream of PRNG keys derived from one root seed."""
 
     def __init__(self, seed: int = 0):
-        self._seed = int(seed)
-        self._key = jax.random.key(self._seed)
-        self._counter = 0
+        self.manual_seed(seed)
 
     def manual_seed(self, seed: int):
+        # the root key is built on first use, not here: creating it runs a
+        # jax computation, and the module-level default generator would make
+        # `import paddle_tpu` initialise the backend — on a TPU host that
+        # takes the chip from whichever process the importer meant to start
         self._seed = int(seed)
-        self._key = jax.random.key(self._seed)
+        self._key = None
         self._counter = 0
         return self
 
@@ -39,6 +41,8 @@ class Generator:
         return self._seed
 
     def next_key(self):
+        if self._key is None:
+            self._key = jax.random.key(self._seed)
         self._counter += 1
         return jax.random.fold_in(self._key, self._counter)
 
@@ -49,8 +53,7 @@ class Generator:
         return {"seed": self._seed, "counter": self._counter}
 
     def set_state(self, state):
-        self._seed = int(state["seed"])
-        self._key = jax.random.key(self._seed)
+        self.manual_seed(state["seed"])
         self._counter = int(state["counter"])
 
 

@@ -333,15 +333,24 @@ class Parameter(Tensor):
 
 
 def _resolve_device(name: str):
-    name = name.lower()
-    if name in ("cpu",):
-        return jax.devices("cpu")[0]
-    if name in ("gpu", "cuda", "tpu", "accelerator", "xla"):
-        return jax.devices()[0]
-    if ":" in name:
-        kind, idx = name.split(":")
-        return jax.devices(kind if kind not in ("gpu", "cuda") else None)[int(idx)]
-    return jax.devices()[0]
+    """'cpu', 'tpu', 'tpu:2', ... -> the jax device.  'gpu'/'cuda'/
+    'accelerator'/'xla' mean "the accelerator" as in reference scripts and
+    resolve to the default backend; 'tpu' means a TPU, and a process that
+    has none raises instead of handing back whatever device 0 is."""
+    kind, _, idx = name.lower().partition(":")
+    if kind in ("gpu", "cuda", "accelerator", "xla"):
+        devices = jax.devices()
+    elif kind == "tpu":
+        if jax.default_backend() != "tpu":
+            raise RuntimeError(
+                "device %r requested but this process has no TPU (jax "
+                "backend: %s)" % (name, jax.default_backend()))
+        devices = jax.devices("tpu")
+    elif kind == "cpu":
+        devices = jax.devices("cpu")
+    else:
+        raise ValueError("unknown device %r" % name)
+    return devices[int(idx) if idx else 0]
 
 
 def is_tensor(x) -> bool:

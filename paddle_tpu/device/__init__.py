@@ -14,6 +14,10 @@ def get_all_devices():
 
 
 def set_device(device: str):
+    """Make ``device`` ('cpu', 'tpu', 'tpu:1', ...) the default placement.
+    Raises when the process has no such device."""
+    from ..core.tensor import _resolve_device
+    jax.config.update("jax_default_device", _resolve_device(device))
     _current[0] = device
     return device
 
@@ -58,10 +62,7 @@ def is_compiled_with_ipu() -> bool:
 
 
 def is_compiled_with_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 class Stream:

@@ -68,16 +68,11 @@ _default_axis = "dp"
 
 def ensure_varying(arr, axis):
     """Promote a constant to device-varying for scan carries inside
-    shard_map (vma typing on newer jax).  pcast is the current spelling;
-    pvary is the deprecated one (ADVICE r4: the silent no-op fallback
-    would break carries once pvary is removed — pcast-first avoids it)."""
-    try:
-        return jax.lax.pcast(arr, axis, to="varying")
-    except (AttributeError, TypeError, ValueError):
-        try:
-            return jax.lax.pvary(arr, axis)
-        except (AttributeError, ValueError):
-            return arr
+    shard_map (vma typing); a value that already varies over ``axis`` is
+    returned as is."""
+    if axis in jax.typeof(arr).vma:
+        return arr
+    return jax.lax.pcast(arr, axis, to="varying")
 
 
 def _axis_of(group) -> str:
@@ -310,8 +305,7 @@ def all_to_all_single(in_tensor, out_tensor=None, in_split_sizes=None,
     def raw(x):
         if not _in_trace(axis):
             return x
-        n = jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size") else \
-            _mesh.axis_size(axis)
+        n = jax.lax.axis_size(axis)
         resh = x.reshape((n, x.shape[0] // n) + x.shape[1:])
         out = jax.lax.all_to_all(resh, axis, split_axis=0, concat_axis=0,
                                  tiled=False)

@@ -94,6 +94,14 @@ def get_mesh() -> Optional[Mesh]:
     return _global_mesh
 
 
+def multi_device_mesh() -> Optional[Mesh]:
+    """The global mesh when it spans more than one device, else None — what
+    a trace-time dispatch asks to tell a GSPMD program from a one-device
+    one (the host's chip count says nothing about the program)."""
+    mesh = _global_mesh
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
 @contextlib.contextmanager
 def mesh_scope(mesh: Mesh):
     """Temporarily install ``mesh`` as the global mesh (restored on exit).

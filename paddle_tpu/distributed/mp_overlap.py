@@ -42,8 +42,8 @@ itself carries across all-reduce implementations.
 Chunking: each ring block can be split into ``chunks`` column sub-blocks
 permuted independently (more, smaller transfers to hide behind shorter
 matmuls) — the knob the ``mp_overlap`` autotune family times on chip.
-All bodies run with ``check_rep=False``: ppermute results are not
-provably replicated to the rep checker even when they are by
+All bodies run with ``check_vma=False``: ppermute results are not
+provably replicated to the varying-axes checker even when they are by
 construction.
 """
 from __future__ import annotations
@@ -54,8 +54,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from . import mesh as _mesh
@@ -217,7 +216,7 @@ def _runner(cand, key):
 
     fn = jax.jit(shard_map(body, mesh=mesh,
                            in_specs=(P(None, MP_AXIS), P(MP_AXIS, None)),
-                           out_specs=P(None, None), check_rep=False))
+                           out_specs=P(None, None), check_vma=False))
     fn(x, w).block_until_ready()   # compile outside the timed region
 
     def run():
@@ -361,7 +360,7 @@ def _row_island(x, w, axis, n, chunks):
     return shard_map(
         body, mesh=mesh,
         in_specs=(_batch_spec(x.ndim, axis), P(axis, None)),
-        out_specs=_batch_spec(x.ndim), check_rep=False)(x, w)
+        out_specs=_batch_spec(x.ndim), check_vma=False)(x, w)
 
 
 from functools import partial  # noqa: E402  (decorators below need it)
@@ -392,7 +391,7 @@ def _row_bwd(axis, n, chunks, res, dy):
         in_specs=(_batch_spec(x.ndim, axis), P(axis, None),
                   _batch_spec(dy.ndim)),
         out_specs=(_batch_spec(x.ndim, axis), P(axis, None)),
-        check_rep=False)(x, w, dy)
+        check_vma=False)(x, w, dy)
     return dx.astype(x.dtype), dw.astype(w.dtype)
 
 
@@ -437,7 +436,7 @@ def _col_island(x, w, axis):
     return shard_map(
         body, mesh=mesh,
         in_specs=(_batch_spec(x.ndim), P(None, axis)),
-        out_specs=_batch_spec(x.ndim, axis), check_rep=False)(x, w)
+        out_specs=_batch_spec(x.ndim, axis), check_vma=False)(x, w)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
@@ -467,7 +466,7 @@ def _col_bwd(axis, n, chunks, res, dy):
         in_specs=(_batch_spec(x.ndim), P(None, axis),
                   _batch_spec(dy.ndim, axis)),
         out_specs=(_batch_spec(x.ndim), P(None, axis)),
-        check_rep=False)(x, w, dy)
+        check_vma=False)(x, w, dy)
     return dx.astype(x.dtype), dw.astype(w.dtype)
 
 
@@ -513,7 +512,7 @@ def _lm_island(x, w, axis, n, chunks):
     return shard_map(
         body, mesh=mesh,
         in_specs=(_batch_spec(x.ndim), P(axis, None)),
-        out_specs=_batch_spec(x.ndim), check_rep=False)(x, w)
+        out_specs=_batch_spec(x.ndim), check_vma=False)(x, w)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
@@ -545,7 +544,7 @@ def _lm_bwd(axis, n, chunks, res, dy):
         in_specs=(_batch_spec(x.ndim), P(axis, None),
                   _batch_spec(dy.ndim)),
         out_specs=(_batch_spec(x.ndim), P(axis, None)),
-        check_rep=False)(x, w, dy)
+        check_vma=False)(x, w, dy)
     return dx.astype(x.dtype), dw.astype(w.dtype)
 
 
@@ -604,7 +603,7 @@ def vocab_embed(ids, wte, arg=None):
     return shard_map(
         body, mesh=mesh,
         in_specs=(P(*([None] * ids.ndim)), P(MP_AXIS, None)),
-        out_specs=P(*([None] * (ids.ndim + 1))), check_rep=False)(ids, wte)
+        out_specs=P(*([None] * (ids.ndim + 1))), check_vma=False)(ids, wte)
 
 
 # ---------------------------------------------------------------------------
@@ -663,11 +662,11 @@ def qkv_heads(x, w, b, num_heads, head_dim, arg=None):
         return shard_map(
             body, mesh=mesh,
             in_specs=(P(None, None, None), P(None, MP_AXIS)),
-            out_specs=(out_spec,) * 3, check_rep=False)(x, w)
+            out_specs=(out_spec,) * 3, check_vma=False)(x, w)
 
     def body(x_full, w_l, b_l):
         return _deal(x_full @ w_l + b_l)
     return shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None, None), P(None, MP_AXIS), P(MP_AXIS)),
-        out_specs=(out_spec,) * 3, check_rep=False)(x, w, b)
+        out_specs=(out_spec,) * 3, check_vma=False)(x, w, b)

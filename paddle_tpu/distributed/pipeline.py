@@ -202,8 +202,8 @@ def spmd_pipeline(stage_fn: Callable, stacked_params, x, num_stages: int,
                                    jax.lax.dynamic_index_in_dim(
                                        x, 0, axis=0, keepdims=False)))
     outputs0 = jnp.zeros((num_micro,) + buf0.shape, buf0.dtype)
-    # newer jax: constants entering the loop must be device-varying; no-op
-    # when the value is already varying or pvary doesn't exist
+    # constants entering the loop must be device-varying (no-op when the
+    # value already varies)
     buf0 = _ensure_varying(buf0, axis)
     outputs0 = _ensure_varying(outputs0, axis)
     _, outputs = jax.lax.fori_loop(0, num_micro + num_stages - 1, tick,
@@ -721,8 +721,6 @@ class _CompiledPipelineStep:
                                                       slots["blocks"]),
                      "head": shard_optimizer_state(slots["head"], "sdp")}
             self.opt_state = {**self.opt_state, "slots": slots}
-        else:
-            self.opt_state = jax.device_put(self.opt_state)  # replicate
         self._step = None
 
     # -- functional wrappers ------------------------------------------------
@@ -743,11 +741,8 @@ class _CompiledPipelineStep:
         return loss._array if isinstance(loss, Tensor) else loss
 
     def _build(self):
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax: only the experimental spelling
-            from jax.experimental.shard_map import shard_map
 
         n, m, bps = self._num_stages, self._num_micro, self._bps
         pspec = {"embed": jax.tree_util.tree_map(
@@ -847,9 +842,15 @@ class _CompiledPipelineStep:
         # recompile watchdog: the 1F1B schedule is compile-once — a second
         # program means the microbatch geometry is churning per step
         from ..observability.watchdog import watch
+        # params and optimizer state come back in exactly the layout they
+        # go in: left to GSPMD they return under an equivalent but
+        # differently spelled sharding, and the second call compiles again
+        pinned = jax.tree_util.tree_map(lambda a: a.sharding,
+                                        (self.params, self.opt_state))
         self._step = watch(
             "pipeline.1f1b_step",
-            jax.jit(full_step, donate_argnums=self._donate_argnums),
+            jax.jit(full_step, donate_argnums=self._donate_argnums,
+                    out_shardings=(None, None) + pinned),
             expected=1)
 
     def step(self, x, y, scale=None):
@@ -1071,11 +1072,8 @@ def canonical_1f1b_step(num_stages: int = 4, num_micro: int = 4,
     fewer than ``num_stages`` devices are available (the registry records
     that as a skip; any OTHER exception is a broken builder and fails the
     audit)."""
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # jax<=0.4.x: only the experimental spelling
-        from jax.experimental.shard_map import shard_map
 
     devices = jax.devices()
     if len(devices) < num_stages:
@@ -1106,7 +1104,7 @@ def canonical_1f1b_step(num_stages: int = 4, num_micro: int = 4,
         lambda p, x_, l_: spmd_pipeline_1f1b(
             stage_fn, loss_fn, p, x_, l_, num_stages, num_micro),
         mesh=mesh, in_specs=(pspec, P(), P()),
-        out_specs=(P(), pspec), check_rep=False)
+        out_specs=(P(), pspec), check_vma=False)
 
     def full_step(params, x, labels):
         loss, grads = pipe(params, x, labels)

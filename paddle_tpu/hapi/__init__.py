@@ -427,8 +427,8 @@ def flops(net, input_size=None, inputs=None, dtypes=None, custom_ops=None,
     finally:
         if was_training:
             net.train()
-    # ONE cost_analysis parser for the whole repo (incl. the 0.4.x
-    # list-shape compat): observability.costs — the same extraction the
+    # ONE cost_analysis parser for the whole repo:
+    # observability.costs — the same extraction the
     # `programs` CLI and TPU506 run on the canonical registry.  strict:
     # a RAISING cost_analysis must propagate (this API returns a bare
     # int — a swallowed failure would read as "0 FLOPs", a plausible

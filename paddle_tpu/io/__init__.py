@@ -253,22 +253,41 @@ class DistributedBatchSampler(BatchSampler):
         self.epoch = epoch
 
 
-def default_collate_fn(batch):
-    """Stack samples into batched numpy arrays (reference:
-    fluid/dataloader/collate.py default_collate_fn)."""
+def _collate_host(batch):
+    """default_collate_fn's stacking, on the host: numpy in, numpy out.
+    Leaves that will become Tensors come back as ``np.ndarray``."""
     sample = batch[0]
     if isinstance(sample, Tensor):
-        return Tensor(np.stack([np.asarray(s._array) for s in batch]))
+        return np.stack([np.asarray(s._array) for s in batch])
     if isinstance(sample, np.ndarray):
-        return Tensor(np.stack(batch))
+        return np.stack(batch)
     if isinstance(sample, (int, float, np.integer, np.floating)):
-        return Tensor(np.asarray(batch))
+        return np.asarray(batch)
     if isinstance(sample, (list, tuple)):
         transposed = list(zip(*batch))
-        return tuple(default_collate_fn(list(s)) for s in transposed)
+        return tuple(_collate_host(list(s)) for s in transposed)
     if isinstance(sample, dict):
-        return {k: default_collate_fn([b[k] for b in batch]) for k in sample}
+        return {k: _collate_host([b[k] for b in batch]) for k in sample}
     return batch
+
+
+def _to_tensors(tree):
+    import jax.tree_util as jtu
+    return jtu.tree_map(
+        lambda a: Tensor(a) if isinstance(a, np.ndarray) else a, tree)
+
+
+def _has_device_leaf(tree) -> bool:
+    import jax
+    import jax.tree_util as jtu
+    return any(isinstance(leaf, (Tensor, jax.Array)) for leaf in
+               jtu.tree_leaves(tree, is_leaf=lambda l: isinstance(l, Tensor)))
+
+
+def default_collate_fn(batch):
+    """Stack samples into batched Tensors (reference:
+    fluid/dataloader/collate.py default_collate_fn)."""
+    return _to_tensors(_collate_host(batch))
 
 
 class DataLoader:
@@ -357,18 +376,32 @@ class DataLoader:
     def _iter_multiprocess(self):
         """Real worker processes over the native shared-memory ring queue
         (csrc/shm_queue.cpp) — the C++ data-feed path.  Returns None when
-        the native transport is unavailable (caller falls back to threads).
+        the native transport is unavailable or the dataset yields device
+        data (caller falls back to threads).
+
+        The workers are FORKED copies of a process that may own the
+        accelerator, and a forked copy must never touch jax: they fetch
+        samples and stack them with numpy, nothing else.  Tensors are
+        built — and a custom ``collate_fn`` runs — in the parent.
         """
+        all_batches = list(self.batch_sampler)
+        if not all_batches:
+            return None
+        # what one sample looks like decides the path: a dataset that
+        # hands out Tensors (TensorDataset) would make the forked workers
+        # read device arrays
+        if _has_device_leaf(self.dataset[all_batches[0][0]]):
+            return None
         try:
             from .shm_queue import ShmQueue
             out_q = ShmQueue(capacity=128 << 20)
-        except Exception:
+        except RuntimeError:          # native library unavailable
             return None
         import multiprocessing as mp
         ctx = mp.get_context("fork")
-        all_batches = list(self.batch_sampler)
-        nw = min(self.num_workers, max(len(all_batches), 1))
+        nw = min(self.num_workers, len(all_batches))
         dataset = self.dataset
+        default_collate = self.collate_fn is default_collate_fn
         collate = self.collate_fn
         init_fn = self.worker_init_fn
         qname = out_q.name
@@ -379,15 +412,9 @@ class DataLoader:
             if init_fn is not None:
                 init_fn(wid)
             for bi in range(wid, len(all_batches), nw):
-                idxs = all_batches[bi]
-                batch = collate([dataset[i] for i in idxs])
-                import numpy as _np
-                from ..core.tensor import Tensor as _T
-                import jax.tree_util as jtu
-                payload = jtu.tree_map(
-                    lambda t: _np.asarray(t._array) if isinstance(t, _T) else t,
-                    batch, is_leaf=lambda l: isinstance(l, _T))
-                q.put((bi, payload))
+                samples = [dataset[i] for i in all_batches[bi]]
+                q.put((bi, _collate_host(samples) if default_collate
+                       else samples))
             q.put(("done", wid))
 
         procs = [ctx.Process(target=worker, args=(w,), daemon=True)
@@ -396,8 +423,6 @@ class DataLoader:
             p.start()
 
         def gen():
-            from ..core.tensor import Tensor as _T
-            import jax.tree_util as jtu
             pending = {}
             done = 0
             nxt = 0
@@ -418,8 +443,8 @@ class DataLoader:
                             continue
                         payload = payload_or_wid
                     nxt += 1
-                    yield jtu.tree_map(
-                        lambda a: _T(a) if hasattr(a, "dtype") else a, payload)
+                    yield (_to_tensors(payload) if default_collate
+                           else collate(payload))
             finally:
                 out_q.close()
                 for p in procs:

@@ -630,13 +630,39 @@ class TrainStep:
         from ..observability import registry as _obs
         from ..observability.watchdog import watch
         self._step = watch("jit.train_step",
-                           jax.jit(step_fn, donate_argnums=donate_args),
+                           jax.jit(step_fn, donate_argnums=donate_args,
+                                   out_shardings=self._pin_state_layout()),
                            expected=1)
         self._m_step_seconds = _obs.histogram("train.step_seconds")
         self._m_steps = _obs.counter("train.steps")
         self._m_grad_norm = _obs.gauge("train.grad_norm")
         # fetched once; the NOOP_BEACON singleton when liveness is off
         self._beacon = _liveness.beacon("train.step")
+
+    def _pin_state_layout(self):
+        """When the carried state (params, buffers, optimizer state) lives
+        on a multi-device mesh: put any leaf still off the mesh onto it
+        (replicated) and return the ``out_shardings`` that hand the state
+        back in exactly the layout it is fed in.  None (compiler's choice)
+        on one device.  Without the pin GSPMD hands the state back under an
+        equivalent but differently spelled sharding (``P('mp')`` for a
+        declared ``P('mp', None)``), the second call misses the jit cache
+        and the "compile-once" step compiles twice."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        from ..optimizer.optimizer import mesh_of
+        state = (self.params, self.buffers, self.opt_state)
+        meshes = [m for m in map(mesh_of, jax.tree_util.tree_leaves(state))
+                  if m is not None]
+        if not meshes:
+            return None
+        rep = NamedSharding(meshes[0], PartitionSpec())
+        self.params, self.buffers, self.opt_state = jax.tree_util.tree_map(
+            lambda a: a if mesh_of(a) is not None else
+            jax.device_put(a, rep), state)
+        pinned = jax.tree_util.tree_map(
+            lambda a: a.sharding,
+            (self.params, self.buffers, self.opt_state))
+        return (None,) + pinned + ((None,) if self._emit_grad_norm else ())
 
     def trace_args(self, batch):
         """The exact argument tuple ``self._step`` runs with, for
@@ -684,7 +710,7 @@ class TrainStep:
             # PartitionSpec IS a tuple: without the explicit check a single
             # spec like PartitionSpec("sdp") would be unpacked into one
             # raw axis-name STRING per batch element, which NamedSharding
-            # rejects (jax 0.4.x) or silently misreads
+            # rejects
             if isinstance(specs, PartitionSpec) or not isinstance(
                     specs, (list, tuple)):
                 specs = [specs] * len(batch_a)

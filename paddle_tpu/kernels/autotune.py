@@ -123,11 +123,8 @@ def families() -> Dict[str, KernelFamily]:
 # ---------------------------------------------------------------------------
 
 def platform() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    import jax
+    return jax.default_backend()
 
 
 def key_str(key: dict) -> str:

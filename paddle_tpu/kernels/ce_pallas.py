@@ -21,10 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.dtype import x64_scope
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-from .pallas_compat import CompilerParams
 
 DEFAULT_BLOCK_ROWS = 8
 
@@ -220,7 +219,7 @@ def _lse_call_cfg(x2, br, c, interpret):
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((br,), jnp.float32),
                         pltpu.VMEM((br,), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x2)
@@ -297,7 +296,7 @@ def _lse_runner_cleanup(key):
     _LSE_RUNNER_DATA.pop(at.key_str(key), None)
 
 
-def _lse_traceable(cand, key):
+def _lse_traceable(cand, key, interpret=True):
     """Data-free candidate program for the TPU504 VMEM estimator and the
     trace-tier audit (see flash_attention_pallas._fwd_traceable)."""
     n, v = key["n"], key["v"]
@@ -305,7 +304,8 @@ def _lse_traceable(cand, key):
 
     def fn(x):
         with x64_scope(False):
-            return _lse_call_cfg(x, cfg["block_rows"], cfg["chunk"], True)
+            return _lse_call_cfg(x, cfg["block_rows"], cfg["chunk"],
+                                 interpret)
     return fn, (jax.ShapeDtypeStruct((n, v), jnp.dtype(key["dtype"])),)
 
 

@@ -9,34 +9,81 @@ custom_vjp; see flash_attention_pallas.py).
 """
 from __future__ import annotations
 
-import functools
+import contextlib
+import math
 
 import jax
-import jax.numpy as jnp
 
 _DEFAULT_BLOCK_Q = 128
 _DEFAULT_BLOCK_K = 128
 
+#: True only inside :func:`interpret_scope`
+_INTERPRET = False
 
-def _platform() -> str:
+
+@contextlib.contextmanager
+def interpret_scope():
+    """Run the kernel in the Pallas interpreter, on any backend, for every
+    program TRACED inside the scope — how a test or a CPU rehearsal sends a
+    whole model through the kernel path (the model's attention call has no
+    ``interpret`` argument to pass).  Process-wide, not per thread: serving
+    traces its programs on the scheduler thread.  Never entered by the
+    library itself."""
+    global _INTERPRET
+    prev, _INTERPRET = _INTERPRET, True
     try:
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+        yield
+    finally:
+        _INTERPRET = prev
 
 
-def supported(q, k=None) -> bool:
+#: mesh axes that shard the batch / head dims of a (B, S, H, D) activation
+#: in a GSPMD program (distributed/mesh.py AXIS_ORDER; 'sep' shards the
+#: sequence and runs the ring path inside its own shard_map instead)
+_BATCH_AXES = ("dp", "sdp")
+_HEAD_AXES = ("mp",)
+
+
+def _active_mesh():
+    """The global mesh the GSPMD program is being traced for, or None for
+    a one-device program and inside a shard_map (manual axes: the caller
+    already holds per-shard operands)."""
+    from ..distributed.mesh import multi_device_mesh
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return multi_device_mesh()
+
+
+def _live_axes(mesh, names):
+    """Those of ``names`` the mesh splits more than one way."""
+    if mesh is None:
+        return ()
+    return tuple(a for a in names if mesh.shape.get(a, 1) > 1)
+
+
+def _ways(mesh, axes) -> int:
+    return math.prod(mesh.shape[a] for a in axes)
+
+
+def supported(q, k=None, interpret=None) -> bool:
     """Whether the Pallas path applies to (B, S, H, D) query/key.
 
     Restricted to square self-attention (s_q == s_k, both block-aligned):
     the kernel's causal mask is start-aligned and a ragged key tail would be
     silently dropped — cross/cached attention takes the XLA reference path.
+    Under a multi-device mesh the kernel runs per shard (see
+    :func:`flash_attention_bshd`), so the head-count limits are checked at
+    the per-shard head count.  ``interpret`` (default: whether an
+    :func:`interpret_scope` is active) admits non-TPU backends — the kernel
+    then runs in the Pallas interpreter.
     """
     import os
     if os.getenv("PADDLE_TPU_DISABLE_FLASH", "").lower() in ("1", "true",
                                                              "yes"):
         return False
-    if _platform() != "tpu":
+    if interpret is None:
+        interpret = _INTERPRET
+    if not interpret and jax.default_backend() != "tpu":
         return False
     if q.ndim != 4:
         return False
@@ -45,16 +92,51 @@ def supported(q, k=None) -> bool:
         return False
     if s % _DEFAULT_BLOCK_Q or d not in (64, 128, 256):
         return False
+    mesh = _active_mesh()
+    head_ways = _ways(mesh, _live_axes(mesh, _HEAD_AXES))
+    if h % head_ways == 0:
+        h //= head_ways
     # the forward holds K+V VMEM-resident; very long sequences exceed the
     # budget and must take the XLA path
     from .flash_attention_pallas import max_supported_seq
     return s <= max_supported_seq(h, d)
 
 
-def flash_attention_bshd(q, k, v, causal=False, scale=None):
-    """q,k,v: (B, S, H, D) -> (B, S, H, D) — native layout, no transposes."""
+def flash_attention_bshd(q, k, v, causal=False, scale=None, interpret=None):
+    """q,k,v: (B, S, H, D) -> (B, S, H, D) — native layout, no transposes.
+
+    A Mosaic custom call has no GSPMD partitioning rule, so in a program
+    traced for a multi-device mesh the kernel is wrapped in a shard_map
+    over the axes that shard batch ('dp', 'sdp') and heads ('mp'): every
+    device runs the kernel on its own (B/dp, S, H/mp, D) block and no
+    collective feeds it.  A batch or head count the mesh does not divide
+    raises — there is no quiet O(S^2) path behind this entry.
+    """
     from .flash_attention_pallas import flash_attention_bshd_native
-    return flash_attention_bshd_native(q, k, v, causal=causal, scale=scale)
+    if interpret is None:
+        interpret = _INTERPRET
+
+    def kernel(q_, k_, v_):
+        return flash_attention_bshd_native(q_, k_, v_, causal=causal,
+                                           scale=scale, interpret=interpret)
+
+    mesh = _active_mesh()
+    batch_axes = _live_axes(mesh, _BATCH_AXES)
+    head_axes = _live_axes(mesh, _HEAD_AXES)
+    if not (batch_axes or head_axes):
+        return kernel(q, k, v)
+    b, h = q.shape[0], q.shape[2]
+    if b % _ways(mesh, batch_axes) or h % _ways(mesh, head_axes):
+        raise ValueError(
+            "flash attention under mesh %s: batch %d / heads %d are not "
+            "divisible by the %s x %s axes that shard them"
+            % (dict(mesh.shape), b, h, batch_axes, head_axes))
+    spec = jax.sharding.PartitionSpec(batch_axes or None, None,
+                                      head_axes or None, None)
+    # check_vma=False: pallas_call outputs carry no varying-axes type of
+    # their own, and nothing here is replicated across the mapped axes
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def flash_attention_bshd_with_lse(q, k, v, causal=False, scale=None,

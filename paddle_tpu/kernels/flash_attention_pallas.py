@@ -70,7 +70,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.dtype import x64_scope
-from .pallas_compat import CompilerParams
 
 
 def _block_env(name, default):
@@ -100,7 +99,7 @@ _NEG_INF = -1e30
 # plain base-e `scale` factor (dS = scale * P * (dP - delta) regardless).
 _LOG2E = 1.4426950408889634
 
-_SEQ2 = CompilerParams(
+_SEQ2 = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"))
 
 #: A/B flag: mask the causal band by multiplying p after exp2 (max over
@@ -270,7 +269,7 @@ def _band_diff(block_q: int, block_k: int):
     vis[i, j] = (col0 + j <= row0 + i) = (j - i <= row0 - col0), so a band
     block's whole mask is ONE compare of this (block-independent) matrix
     against the scalar block offset.  Built from in-kernel iotas — Pallas
-    under the jax pin rejects captured host constants — but hoisted out of
+    rejects captured host constants — but hoisted out of
     the per-k-block loop by the callers (and loop-invariant for Mosaic),
     unlike the base path's per-block row_ids/col_ids builds."""
     return jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) - \
@@ -384,7 +383,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale, hg,
     # q/o: (1, BQ, HG*D); k/v: (1, S, HG*D) — the WHOLE sequence resident
     # in VMEM, scanned with a fori loop (measured faster than grid-streamed
     # K/V blocks at these shapes: the pipeline only added grid overhead);
-    # lse: (1, 1, HG, NQ, BQ) — or per-q-block (1, 1, HG, 1, BQ) under parq.
+    # lse: (1, 1, HG, NQ, BQ) — or per-q-block (1, 1, 1, HG, BQ) under parq
+    # (q-block-major, so the block's last two dims are the whole (HG, BQ)
+    # tile Mosaic requires; the wrapper swaps it back).
     block_q = q_ref.shape[1]
     s = k_ref.shape[1]
     qi = _pid(2)
@@ -442,7 +443,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale, hg,
         # lse in base-2 units: m is already log2-scaled
         lse_row = (m + jnp.log(l_safe) * jnp.float32(_LOG2E))[None, :]
         if parq:
-            lse_ref[0, 0, hh, pl.ds(0, 1), :] = lse_row
+            lse_ref[0, 0, 0, pl.ds(hh, 1), :] = lse_row
         else:
             lse_ref[0, 0, hh, pl.ds(qi, 1), :] = lse_row
 
@@ -648,7 +649,7 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec):
                 pltpu.VMEM((2, block_k, hgd), v3.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ],
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(q3, k3, v3)
@@ -664,9 +665,12 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec):
         kv_spec = pl.BlockSpec((1, sk, hgd), lambda bi, g, i: (bi, 0, g))
         if parq:
             # per-q-block lse blocks: nothing is revisited, so every grid
-            # dim can carry "parallel" dimension_semantics
-            lse_spec = pl.BlockSpec((1, 1, hg, 1, block_q),
-                                    lambda bi, g, i: (bi, g, 0, i, 0))
+            # dim can carry "parallel" dimension_semantics.  Stored
+            # q-block-major — a (1, BQ) tail is not (8, 128)-tileable, the
+            # whole (HG, BQ) tail is — and swapped back below
+            lse_shape = _sds((b, n_hg, nq, hg, block_q), jnp.float32, q3)
+            lse_spec = pl.BlockSpec((1, 1, 1, hg, block_q),
+                                    lambda bi, g, i: (bi, g, i, 0, 0))
             sem = ("parallel", "parallel", "parallel")
         else:
             # whole folded lse slice per (b, head-group), revisited
@@ -680,10 +684,10 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec):
             in_specs=[q_spec3, kv_spec, kv_spec],
             out_specs=[q_spec3, lse_spec],
             out_shape=[out_shape, lse_shape],
-            compiler_params=CompilerParams(dimension_semantics=sem),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=sem),
             interpret=interpret,
         )(q3, k3, v3)
-        return out, lse
+        return out, (jnp.swapaxes(lse, 2, 3) if parq else lse)
     # long-sequence path: K/V blocks streamed by the grid — O(block) VMEM,
     # keeps the O(S) capability for sequences whose K/V don't fit resident
     kernel = functools.partial(_fwd_kernel_streamed, causal=causal,
@@ -1273,8 +1277,14 @@ def _fwd_candidates(key):
         variants += list(_CAND_FWD_RESIDENT)
     for bq, bk in _candidate_blocks(s, sk, causal, bq0, bk0):
         for v in (["base"] if (bq, bk) != (bq0, bk0) else []) + variants:
+            # the pipelined kernel carries every head's (m, l, acc) through
+            # its fori loop on top of the K/V double buffers: at the
+            # forward's wide group (hg*d = 512) Mosaic's scoped VMEM runs
+            # out at the standard key (16.07M of 16M), at the backward's
+            # group (hg*d <= 256) every block pair below compiles
+            hg = hg_b if "pipelined" in v else hg_f
             cand = {"variant": v,
-                    "config": {"block_q": bq, "block_k": bk, "hg": hg_f}}
+                    "config": {"block_q": bq, "block_k": bk, "hg": hg}}
             if cand not in cands:
                 cands.append(cand)
     # alternate head groups for the base variant only (bounds the grid)
@@ -1410,9 +1420,10 @@ def _runner_cleanup(key):
 # ShapeDtypeStructs, so make_jaxpr prices the BlockSpec working set
 # without touching a device — the autotuner's pre-compile VMEM gate and
 # the analysis registry's per-variant kernel programs both come from
-# these.
+# these.  ``interpret=False`` builds the Mosaic program instead, for
+# ahead-of-time compiles against a TPU topology.
 
-def _fwd_traceable(cand, key):
+def _fwd_traceable(cand, key, interpret=True):
     b, s, sk, h, d = (key[k] for k in ("b", "s", "sk", "h", "d"))
     causal, dtype = key["causal"], jnp.dtype(key["dtype"])
     cfg = cand["config"]
@@ -1420,14 +1431,14 @@ def _fwd_traceable(cand, key):
     scale = 1.0 / d ** 0.5
 
     def fn(q, k, v):
-        return _flash_fwd(q, k, v, causal, scale, d, True, spec)
+        return _flash_fwd(q, k, v, causal, scale, d, interpret, spec)
     sds = jax.ShapeDtypeStruct
     return fn, (sds((b, s, h * d), dtype), sds((b, sk, h * d), dtype),
                 sds((b, sk, h * d), dtype))
 
 
 def _bwd_traceable(which):
-    def make(cand, key):
+    def make(cand, key, interpret=True):
         b, s, sk, h, d = (key[k] for k in ("b", "s", "sk", "h", "d"))
         causal, dtype = key["causal"], jnp.dtype(key["dtype"])
         cfg = cand["config"]
@@ -1441,7 +1452,7 @@ def _bwd_traceable(which):
         def fn(q, k, v, do, lse, delta):
             with x64_scope(False):
                 return call(q, k, v, do, lse, delta, causal, scale, hg, d,
-                            spec, True)
+                            spec, interpret)
         sds = jax.ShapeDtypeStruct
         # lse/delta in the layout the default forward produces (what the
         # production bwd — and the timed runner — actually receives)

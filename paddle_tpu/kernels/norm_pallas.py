@@ -21,10 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.dtype import x64_scope
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-from .pallas_compat import CompilerParams
 
 DEFAULT_BLOCK_ROWS = 256
 
@@ -201,7 +200,7 @@ def _ln_bwd(x2, gamma, mean, rstd, do2, block_rows, interpret):
             jax.ShapeDtypeStruct((f,), jnp.float32),
             jax.ShapeDtypeStruct((f,), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x2, gamma, mean, rstd, do2)
@@ -290,7 +289,7 @@ def softmax_pallas(x, block_rows=DEFAULT_BLOCK_ROWS, interpret=False):
     return out.reshape(x.shape)
 
 
-def _ln_traceable(cand, key):
+def _ln_traceable(cand, key, interpret=True):
     """Data-free candidate program for the TPU504 VMEM estimator and the
     trace-tier audit (see flash_attention_pallas._fwd_traceable)."""
     n, f = key["n"], key["f"]
@@ -299,7 +298,7 @@ def _ln_traceable(cand, key):
 
     def fn(x, g, b):
         with x64_scope(False):
-            return _ln_fwd(x, g, b, 1e-5, br, True)
+            return _ln_fwd(x, g, b, 1e-5, br, interpret)
     sds = jax.ShapeDtypeStruct
     return fn, (sds((n, f), dtype), sds((f,), dtype), sds((f,), dtype))
 

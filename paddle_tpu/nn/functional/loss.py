@@ -11,21 +11,32 @@ import jax
 import jax.numpy as jnp
 
 from ...core.dispatch import call, wrap_op
+from ...core.dtype import x64_scope
+
+
+def _one_device_operand(x) -> bool:
+    """Whether ``x`` lives on one device: a concrete array says so itself;
+    a tracer belongs to a program that spans the global mesh when one with
+    more than one device is installed (distributed.mesh) and to a
+    one-device program otherwise — how many chips the HOST has does not
+    enter into it."""
+    if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+        return len(x.sharding.device_set) == 1
+    from ...distributed.mesh import multi_device_mesh
+    return multi_device_mesh() is None
 
 
 def _pallas_ce_gate(flag_name, logits):
     """Shared eligibility gate for the Pallas CE/LSE routes: flag on, TPU
-    backend, SINGLE device (a Mosaic custom call has no GSPMD partitioning
-    rule — under a multi-device pjit XLA would all-gather the (N, V)
-    logits per device; the sharded-model CE is ParallelCrossEntropy and
-    the 'sep' routing, not this).  Returns (n, v, lead) or None."""
+    backend, one-device operand (a Mosaic custom call has no GSPMD
+    partitioning rule — in a multi-device program XLA would all-gather
+    the (N, V) logits per device; the sharded-model CE is
+    ParallelCrossEntropy and the 'sep' routing, not this).  Returns
+    (n, v, lead) or None."""
     from ...utils.flags import fast_get
     if not fast_get(flag_name):
         return None
-    try:
-        if jax.default_backend() != "tpu" or len(jax.devices()) != 1:
-            return None
-    except Exception:
+    if jax.default_backend() != "tpu" or not _one_device_operand(logits):
         return None
     v = logits.shape[-1]
     lead = logits.shape[:-1]
@@ -49,9 +60,8 @@ def _fused_ce_or_none(logits, lbl, ignore_index):
     from ...kernels import ce_pallas
     if not ce_pallas.supported(n, v):
         return None
-    # explicit i32 index math, no x64 flip at this level (flipping x64
-    # inside an outer trace miscompiles on newer jax — see the XLA gather
-    # below); softmax_ce_pallas scopes its own kernel lowering internally
+    # explicit i32 index math; softmax_ce_pallas scopes its own kernel
+    # lowering (x64 off) internally
     idx = jnp.clip(lbl.astype(jnp.int32), 0, v - 1).reshape(n, 1)
     nll = ce_pallas.softmax_ce_pallas(logits.reshape(n, v), idx)
     nll = nll.reshape(lead)
@@ -125,14 +135,15 @@ def softmax_with_cross_entropy_raw(logits, label, soft_label=False,
             axis=axis))
     # cast BEFORE the clip so every index op is i32: s64 labels would
     # otherwise put emulated 64-bit clamp/compare ops into the TPU program
-    # (caught by tests/test_x64_audit.py; an earlier revision toggled
-    # x64_scope(False) here, but flipping x64 inside an outer trace
-    # miscompiles on newer jax — explicit casts are trace-stable)
+    # (caught by tests/test_x64_audit.py)
     idx = jnp.clip(lbl.astype(jnp.int32), 0, logits.shape[axis] - 1)
-    # promise_in_bounds is honest (idx just got clipped) and keeps the
-    # gather + its transpose in i32; other modes convert through s64
-    t = jnp.take_along_axis(logits, jnp.expand_dims(idx, axis), axis=axis,
-                            mode="promise_in_bounds").astype(jnp.float32)
+    # promise_in_bounds is honest (idx just got clipped); the gather itself
+    # traces with x64 off because take_along_axis widens its indices to
+    # the default int, which is s64 under the global x64 mode
+    with x64_scope(False):
+        t = jnp.take_along_axis(logits, jnp.expand_dims(idx, axis),
+                                axis=axis, mode="promise_in_bounds")
+    t = t.astype(jnp.float32)
     nll = lse - jnp.squeeze(t, axis)
     mask = (lbl != ignore_index)
     return jnp.where(mask, nll, 0.0)

@@ -31,9 +31,14 @@ def layer_norm_raw(x, weight, bias, normalized_shape, epsilon=1e-5):
         for s in x.shape[:-1]:
             rows *= s
         if rows % 8 == 0:
-            interpret = jax.default_backend() != "tpu"
+            if jax.default_backend() != "tpu":
+                raise RuntimeError(
+                    "FLAGS_use_pallas_norm selects a Mosaic kernel and this "
+                    "process runs on %r; call kernels.norm_pallas."
+                    "layer_norm_pallas(..., interpret=True) to run it in "
+                    "the Pallas interpreter" % jax.default_backend())
             return layer_norm_pallas(x, weight, bias, epsilon,
-                                     DEFAULT_BLOCK_ROWS, interpret)
+                                     DEFAULT_BLOCK_ROWS, False)
     # statistics in f32 regardless of activation dtype, output cast back to
     # the input dtype: keeps bf16 activations bf16 through the residual
     # stream (an f32-promoting LN silently turns every downstream matmul

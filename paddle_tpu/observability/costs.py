@@ -23,11 +23,11 @@ generated-code bytes), and the canonical trace-audit registry
 Graceful degradation is the contract, not an accident: backends report
 different subsets (CPU's ``generated_code_size_in_bytes`` is 0, TPU adds
 real code/temp sizes; Pallas kernels price their interpret-mode lowering
-off-chip), ``cost_analysis()`` is list-shaped on jax <= 0.4.x (ONE compat
-shim here — :func:`cost_analysis_dict` — which ``hapi.flops`` also
-routes through), and a missing field is ``None``, never a guess.
+off-chip; ONE extraction helper — :func:`cost_analysis_dict` — which
+``hapi.flops`` also routes through), and a missing field is ``None``,
+never a guess.
 
-Derived peak: XLA 0.4.x exposes no single peak-memory scalar, so
+Derived peak: ``memory_analysis()`` exposes no single peak-memory scalar, so
 ``peak_bytes = argument + output + temp - alias`` — the executable's
 whole-BUFFER high-water bound (donated/aliased buffers counted once;
 generated code is reported separately and excluded on purpose: code
@@ -38,7 +38,6 @@ same derivation, so the gate is self-consistent.
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 __all__ = [
@@ -49,9 +48,10 @@ __all__ = [
 ]
 
 # ---------------------------------------------------------------------------
-# per-part peak specs (published numbers, per chip); substring-matched
-# against jax's device_kind.  Overridable for new parts / corrected specs
-# via PADDLE_TPU_PEAK_FLOPS / PADDLE_TPU_PEAK_HBM_BW (floats, per chip).
+# per-part peak specs (published numbers, per chip; Google Cloud TPU
+# documentation), substring-matched against jax's device_kind.  One v5e
+# chip reports itself as "TPU v5 lite".  A TPU that is not in the table
+# is an error, never an assumed peak.
 # ---------------------------------------------------------------------------
 
 #: bf16 peak FLOP/s per chip by device-kind substring (lowercase).
@@ -70,40 +70,31 @@ PEAK_HBM_BW_BY_KIND = (
 
 
 def _kind_lookup(table, kind: Optional[str]) -> Optional[float]:
-    if not kind:
-        return None
+    """Peak for ``kind`` (default: this process's first device).  None on
+    a CPU; an accelerator kind missing from the table raises."""
+    if kind is None:
+        import jax
+        kind = jax.devices()[0].device_kind
     kind = kind.lower()
+    if kind == "cpu":
+        return None
     for sub, v in table:
         if sub in kind:
             return v
-    return None
-
-
-def _device_kind() -> Optional[str]:
-    try:
-        import jax
-        return jax.devices()[0].device_kind
-    except Exception:
-        return None
+    raise ValueError(
+        "no published peak for device kind %r — add the part to "
+        "observability/costs.py PEAK_*_BY_KIND with its source" % kind)
 
 
 def peak_flops(device_kind: Optional[str] = None) -> Optional[float]:
-    """Peak bf16 FLOP/s of one chip (None off-chip / unknown part)."""
-    env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
-    if env:
-        return float(env)
-    return _kind_lookup(PEAK_FLOPS_BY_KIND,
-                        device_kind or _device_kind())
+    """Peak bf16 FLOP/s of one chip (None on a CPU)."""
+    return _kind_lookup(PEAK_FLOPS_BY_KIND, device_kind)
 
 
 def peak_hbm_bandwidth(device_kind: Optional[str] = None
                        ) -> Optional[float]:
-    """Peak HBM bytes/s of one chip (None off-chip / unknown part)."""
-    env = os.environ.get("PADDLE_TPU_PEAK_HBM_BW")
-    if env:
-        return float(env)
-    return _kind_lookup(PEAK_HBM_BW_BY_KIND,
-                        device_kind or _device_kind())
+    """Peak HBM bytes/s of one chip (None on a CPU)."""
+    return _kind_lookup(PEAK_HBM_BW_BY_KIND, device_kind)
 
 
 def mfu(flops: Optional[float], step_seconds: Optional[float],
@@ -111,32 +102,28 @@ def mfu(flops: Optional[float], step_seconds: Optional[float],
     """Model FLOPs utilization of one compiled step: program FLOPs /
     (step wall seconds * chip peak).  None whenever any input is
     unknown — a fabricated 0.0 would enter the trajectory as a datum."""
-    peak = peak_flops(device_kind)
-    if not flops or not step_seconds or step_seconds <= 0 or not peak:
+    if not flops or not step_seconds or step_seconds <= 0:
         return None
-    return flops / (step_seconds * peak)
+    peak = peak_flops(device_kind)
+    return flops / (step_seconds * peak) if peak else None
 
 
 def bw_util(hbm_bytes: Optional[float], step_seconds: Optional[float],
             device_kind: Optional[str] = None) -> Optional[float]:
     """HBM bandwidth utilization: program bytes-accessed / (step wall
     seconds * chip peak bandwidth)."""
-    peak = peak_hbm_bandwidth(device_kind)
-    if not hbm_bytes or not step_seconds or step_seconds <= 0 or not peak:
+    if not hbm_bytes or not step_seconds or step_seconds <= 0:
         return None
-    return hbm_bytes / (step_seconds * peak)
+    peak = peak_hbm_bandwidth(device_kind)
+    return hbm_bytes / (step_seconds * peak) if peak else None
 
 
 # ---------------------------------------------------------------------------
-# extraction (THE compat shims — hapi.flops routes through these too)
+# extraction (hapi.flops routes through these too)
 # ---------------------------------------------------------------------------
 
 def cost_analysis_dict(compiled, strict: bool = False) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` as ONE flat dict.
-
-    The single 0.4.x compat shim: jax <= 0.4.x returns a list with one
-    dict per device — identical replicas on a single-program compile, so
-    the first is taken; newer jax returns the dict directly.  A backend
+    """``compiled.cost_analysis()`` as a plain dict.  A backend
     that reports nothing yields ``{}``; a RAISING backend is swallowed
     to ``{}`` only under ``strict=False`` (the ProgramReport path, which
     carries available/note fields for the degradation) — ``strict=True``
@@ -148,8 +135,6 @@ def cost_analysis_dict(compiled, strict: bool = False) -> Dict[str, float]:
         if strict:
             raise
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     return dict(ca) if ca else {}
 
 

@@ -20,9 +20,20 @@ from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..core.tensor import Parameter, Tensor
 from .lr import LRScheduler
+
+
+def mesh_of(p):
+    """The multi-device mesh a concrete parameter array is laid out on, or
+    None (one device, a tracer, a non-array leaf)."""
+    sharding = getattr(p, "sharding", None)
+    if isinstance(sharding, NamedSharding) and sharding.mesh.size > 1 \
+            and not isinstance(p, jax.core.Tracer):
+        return sharding.mesh
+    return None
 
 
 def _path_name(key_path) -> str:
@@ -86,6 +97,16 @@ class Optimizer:
                          for k, v in slots.items()}
             # reduced-precision moments keep init_one's intentional dtypes
             slots["master"] = p.astype(jnp.float32)
+        mesh = mesh_of(p)
+        if mesh is not None:
+            # state born on one device and returned mesh-placed by the
+            # first step is a different jit key on the second (the aval
+            # carries the mesh): a silent second compile.  Start every slot
+            # where the step will leave it — laid out like its parameter
+            slots = {k: jax.device_put(
+                v, p.sharding if getattr(v, "shape", None) == p.shape
+                else NamedSharding(mesh, PartitionSpec()))
+                for k, v in slots.items()}
         return slots
 
     def _update_leaf(self, g, p, slots, lr, step, name=None):
@@ -169,9 +190,16 @@ class Optimizer:
 
     # -- compiled-path API ---------------------------------------------------
     def init_state(self, params_tree):
+        step = jnp.zeros((), jnp.int32)
+        meshes = [m for m in map(mesh_of,
+                                 jax.tree_util.tree_leaves(params_tree))
+                  if m is not None]
+        if meshes:
+            step = jax.device_put(step,
+                                  NamedSharding(meshes[0], PartitionSpec()))
         return {
             "slots": jax.tree_util.tree_map(self._init_slots, params_tree),
-            "step": jnp.zeros((), jnp.int32),
+            "step": step,
         }
 
     def apply_gradients(self, params_tree, grads_tree, state, lr):

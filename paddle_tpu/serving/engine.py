@@ -335,8 +335,9 @@ class DecodeEngine:
             # the pool, the parameters, and every entry's outputs to the
             # GIVEN device through the same jit-with-shardings machinery
             # the tp path uses (single-device jit outputs are uncommitted
-            # in this jax, so "create the buffers there" would not
-            # survive the first call) — role-split disaggregated serving
+            # — re-checked on jax 0.9.0 — so "create the buffers there"
+            # would not survive the first call) — role-split disaggregated
+            # serving
             # places its prefill engine on its own chip this way
             self.mesh = Mesh(np.asarray([device]), (MP_AXIS,))
         # -- collective–matmul overlap (ISSUE 20) --------------------------
@@ -1280,9 +1281,10 @@ class DecodeEngine:
         take the PR-5 path; a jax array — the previous step's sampled-
         token output, threaded back by the overlapped scheduler loop
         without a host round-trip — is reshaped eagerly.  Single-device
-        jit outputs are UNCOMMITTED in this jax, so both spellings hit
-        the SAME jit cache entry (compile-once holds across the mix —
-        tested); tensor-parallel engines instead commit the host path
+        jit outputs are UNCOMMITTED (re-checked on jax 0.9.0, where a RAW
+        numpy operand would be a second cache entry — hence jnp.asarray
+        below), so both spellings hit the SAME jit cache entry
+        (compile-once holds across the mix — tested); tensor-parallel engines instead commit the host path
         onto the mesh so it matches the sharded outputs' placement (the
         PR-11 reset lesson: jit keys on commitment there)."""
         if isinstance(tokens, jax.Array):

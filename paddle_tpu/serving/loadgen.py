@@ -26,7 +26,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-__all__ = ["MIXES", "run_load", "run_load_sync", "run_interference",
+__all__ = ["MIXES", "offer", "run_load", "run_load_sync", "run_interference",
            "run_interference_sync", "summarize", "percentile"]
 
 #: named prompt/output length mixes: (prompt_len_range, max_new_range),
@@ -63,14 +63,15 @@ def percentile(values: List[float], q: float) -> float:
 async def _one_request(host: str, port: int, payload: dict,
                        record_gaps: bool = False) -> dict:
     """POST one streaming generate and consume its SSE events.  Returns
-    {status, ttft, tpot, tokens, finish_reason} — ttft/tpot are None
-    when no token arrived (shed, error).  ``record_gaps=True`` also
+    {status, ttft, tpot, tokens, token_ids, finish_reason} — ttft/tpot
+    are None when no token arrived (shed, error); ``token_ids`` is the
+    delivered stream itself.  ``record_gaps=True`` also
     collects ``gaps``: one ``(arrival_time, gap_seconds)`` per
     post-first token event — the per-token samples the interference A/B
     classifies into quiet-vs-wave windows."""
     t0 = time.perf_counter()
     rec = {"status": 0, "ttft": None, "tpot": None, "tokens": 0,
-           "finish_reason": None}
+           "token_ids": [], "finish_reason": None}
     if record_gaps:
         rec["gaps"] = []
     try:
@@ -111,6 +112,7 @@ async def _one_request(host: str, port: int, payload: dict,
                 break
             k = len(ev.get("tokens", ()))
             if k:
+                rec["token_ids"].extend(ev["tokens"])
                 now = time.perf_counter()
                 if first_t is None:
                     first_t = now
@@ -148,10 +150,7 @@ async def run_load(host: str, port: int, qps: float, n_requests: int,
     workload."""
     rng = np.random.default_rng(seed)
     (plo, phi), (nlo, nhi) = MIXES[mix] if isinstance(mix, str) else mix
-    loop = asyncio.get_running_loop()
-    t_start = loop.time()
-    t_next = 0.0
-    tasks = []
+    plan, t_next = [], 0.0
     for _ in range(int(n_requests)):
         plen = int(rng.integers(plo, phi + 1))
         payload = {
@@ -161,16 +160,29 @@ async def run_load(host: str, port: int, qps: float, n_requests: int,
         }
         if eos_token_id is not None:
             payload["eos_token_id"] = int(eos_token_id)
-        delay = (t_start + t_next) - loop.time()
+        plan.append((t_next, payload))
+        t_next += float(rng.exponential(1.0 / float(qps)))
+    recs, wall = await offer(host, port, plan)
+    return summarize(recs, wall, qps=float(qps),
+                     mix=(mix if isinstance(mix, str) else "custom"))
+
+
+async def offer(host: str, port: int, plan) -> tuple:
+    """Send every ``(offset_seconds, payload)`` of ``plan`` at its offset
+    from now (open loop: a late server never delays a later arrival) and
+    wait for all streams.  Returns ``(records, wall_seconds)``, records in
+    plan order — the entry for callers that bring their own requests."""
+    loop = asyncio.get_running_loop()
+    t_start = loop.time()
+    tasks = []
+    for offset, payload in plan:
+        delay = (t_start + offset) - loop.time()
         if delay > 0:
             await asyncio.sleep(delay)
         tasks.append(asyncio.ensure_future(
             _one_request(host, port, payload)))
-        t_next += float(rng.exponential(1.0 / float(qps)))
     recs = await asyncio.gather(*tasks)
-    wall = loop.time() - t_start
-    return summarize(list(recs), wall, qps=float(qps),
-                     mix=(mix if isinstance(mix, str) else "custom"))
+    return list(recs), loop.time() - t_start
 
 
 def run_load_sync(host, port, qps, n_requests, **kw) -> dict:

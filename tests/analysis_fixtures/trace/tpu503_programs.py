@@ -4,14 +4,10 @@ out-of-range ppermute — and a healthy uniform program as the negative."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from paddle_tpu.analysis.trace import TraceProgram
-
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 
 
 def build_programs():
@@ -38,7 +34,7 @@ def build_programs():
 
     def sm(fn):
         return shard_map(fn, mesh=mesh, in_specs=(P("dp"),),
-                         out_specs=P("dp"), check_rep=False)
+                         out_specs=P("dp"), check_vma=False)
 
     x = jnp.ones((n * 2, 4), jnp.float32)
     declared = {"mesh_axes": {"dp": n}, "kind": "fixture"}

@@ -22,6 +22,7 @@ The role-split contract these tests pin:
   the new mixes drive seeded-reproducible workloads.
 """
 import json
+import time
 import urllib.request
 
 import numpy as np
@@ -375,10 +376,12 @@ def test_handoff_advance_tolerates_mid_loop_retirement(model):
         sched.submit(Request(prompt=rng.integers(0, VOCAB, (24,)),
                              max_new_tokens=3, temperature=0.0))
     sched.admit()
-    for _ in range(50):
-        if len(sched._handoffs) == 2:
-            break
+    # the final chunk's token is POLLED, and XLA:CPU runs the chunk
+    # asynchronously: poll against a deadline, not an iteration count
+    deadline = time.monotonic() + 60.0
+    while len(sched._handoffs) < 2 and time.monotonic() < deadline:
         sched.prefill_once()
+        time.sleep(0.001)
     assert len(sched._handoffs) == 2, "handoffs never got concurrent"
     # simulate the re-entrant retirement: processing the FIRST task's
     # chunk preempts the SECOND mid-handoff slot (what _alloc_dst's

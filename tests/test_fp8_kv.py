@@ -35,8 +35,15 @@ def _tiny_model(scan_layers=False, seed=0):
 
 
 def _full_last_logits(model, ids):
-    x = paddle.to_tensor(np.asarray(ids, np.int32)[None])
-    return model(x).numpy()[0, -1]
+    """Full-forward recompute of the next-token logits for a sequence.
+    Right-padded to a multiple of 16: the model is causal, so the pad
+    cannot reach the logits read, and the eager forward compiles once a
+    bucket instead of once for every length the tests walk."""
+    n = len(ids)
+    width = min(-(-n // 16) * 16, model.config.max_position_embeddings)
+    x = np.zeros((1, width), np.int32)
+    x[0, :n] = np.asarray(ids, np.int32)
+    return model(paddle.to_tensor(x)).numpy()[0, n - 1]
 
 
 def _engine(model=None, **kw):

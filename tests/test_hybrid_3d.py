@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from paddle_tpu.distributed.pipeline import spmd_pipeline_1f1b_hetero
@@ -48,33 +48,6 @@ def head_loss_fn(hp, ep, h, lbl):
     return jnp.mean((logits - lbl) ** 2)
 
 
-import _jax_compat
-
-
-@pytest.mark.skipif(
-    _jax_compat._OLD_JAX,
-    reason="DELIBERATELY RED on jax 0.4.37: this program hits the static "
-           "replication-inference false positive, and the only execution "
-           "path old jax offers (check_rep=False fallback) miscompiles the "
-           "grad-transpose psum placement (grads come out exactly 2x over "
-           "'dp' — measured, see tests/_jax_compat.py).  Newer jax infers "
-           "the replication and runs the CHECKED program; skipping beats "
-           "green-lighting a known-miscompiled gradient.  Re-audited in "
-           "the ISSUE-8 skip sweep: still 0.4.37-red — the strict build "
-           "raises the same static-inference error at trace time and the "
-           "relaxed build still doubles the 'dp' grads, so neither "
-           "execution path is convertible to a live test on this pin.  "
-           "Re-audited again in the ISSUE-18 (flow tier) sweep: the pin "
-           "is unchanged (jax 0.4.37, `from jax import shard_map` still "
-           "ImportErrors so _OLD_JAX holds) and both failure modes are "
-           "version-determined, so the skip stands verbatim.  "
-           "Re-audited in the ISSUE-20 (mp_overlap) sweep: pin still "
-           "0.4.37 / _OLD_JAX still True, and the new decomposed-ring "
-           "paths deliberately sidestep this class of failure (psums "
-           "are replaced by ppermute accumulation with explicit "
-           "custom_vjp transposes, exercised live in "
-           "tests/test_mp_overlap.py), so the only program still "
-           "hitting the 0.4.37 replication-inference bug is this one.")
 def test_dp_mp_pp_one_program():
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 devices")

@@ -212,6 +212,7 @@ def test_hooks():
     assert calls == ["pre", "post"]
 
 
+@pytest.mark.slow   # tier-1 wall budget: runs unfiltered in CI (see ci.yml)
 def test_mha_and_transformer_encoder():
     mha = nn.MultiHeadAttention(16, 4, dropout=0.0)
     x = paddle.randn([2, 6, 16])
@@ -229,6 +230,7 @@ def test_mha_and_transformer_encoder():
     assert p0 is not p1
 
 
+@pytest.mark.slow   # tier-1 wall budget: runs unfiltered in CI (see ci.yml)
 def test_lstm_and_gru():
     lstm = nn.LSTM(8, 16, num_layers=2)
     x = paddle.randn([4, 5, 8])

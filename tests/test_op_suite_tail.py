@@ -20,7 +20,6 @@ import yaml
 import paddle_tpu as paddle
 
 sys.path.insert(0, __file__.rsplit("/", 1)[0])
-import _jax_compat  # noqa: E402,F401  (0.4.37 random.py x64 binomial shim)
 from test_op_suite import (BF16, RNG, Spec, T, _check_grad,  # noqa: E402
                            _check_parity, fmat, fmat2, fpos, with_kw)
 

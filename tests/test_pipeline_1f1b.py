@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from paddle_tpu.distributed.pipeline import spmd_pipeline_1f1b

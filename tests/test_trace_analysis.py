@@ -69,13 +69,13 @@ def test_fixture_matrix(fixture_report):
     assert by["fixture/tpu506_unpriceable"] == [
         ("TPU506", "memory/peak_bytes")]
     dirty = sorted(by["fixture/tpu505_dirty"])
-    assert ("TPU505", "debug_callback.0") in dirty
+    assert ("TPU505", "debug_print.0") in dirty
     assert ("TPU505", "dot_general.0") in dirty     # dead matmul
     assert ("TPU505", "dot_general.2") in dirty     # duplicate matmul
     # callbacks allowed -> only the dead/dup findings remain
     allowed = {r for r, _s in by["fixture/tpu505_callbacks_allowed"]}
     assert allowed == {"TPU505"}
-    assert not any(s.startswith("debug_callback")
+    assert not any(s.startswith(("debug_callback", "debug_print"))
                    for _r, s in by["fixture/tpu505_callbacks_allowed"])
     # negatives are silent
     for neg in ("fixture/tpu501_ok", "fixture/tpu501_unscoped",

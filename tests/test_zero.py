@@ -79,7 +79,7 @@ def test_zero_stage_parity_and_shardings(sdp_mesh, stage):
 
     # params after training match too; compare through the per-name
     # external contract so the test is layout-agnostic.  The gate is
-    # drift-aware: jax 0.4.37's CPU lowering fuses the sharded psum/
+    # drift-aware: XLA:CPU fuses the sharded psum/
     # AdamW-moment chain differently per stage, and after 5 steps a
     # HANDFUL of isolated elements land ~1e-3 apart (observed 1-2 of
     # 8192, varying run to run with fusion order).  Real divergence

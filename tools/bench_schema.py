@@ -2,9 +2,12 @@
 
 Two input shapes:
 
-* **Wrapper files** (``BENCH_r05.json`` etc., written by the bench
-  driver): ``{"n", "cmd", "rc", "tail", "parsed"}`` where ``parsed`` is
-  the bench's JSON line.
+* **Wrapper files** (``BENCH_<series>_<round>.json``, as an earlier
+  driver wrote them): ``{"n", "cmd", "rc", "tail", "parsed"}`` where
+  ``parsed`` is the bench's JSON line.  None is committed today — the
+  round 1-5 records were removed in PR 21 (taken on a retired remote
+  set-up; PERF.md keeps their figures) and the driver now records every
+  run in ``PERF_LEDGER.jsonl``.
 * **Raw lines** (``--line -`` reads stdin, or ``--line '<json>'``): the
   JSON line a bench prints — what the CI bench-smoke pipes in.
 
@@ -29,8 +32,8 @@ are null off-chip (CPU smoke validates SHAPE only, per-backend
 degradation is the costs.py contract).  ``--expect-cost`` makes the
 block mandatory (the CI bench-smoke gate).
 
-Old trajectory files (pre-metrics-block, BENCH_r01..r05) validate clean:
-each block is optional, but WHEN present it must be well-formed
+Lines without the blocks validate clean: each block is optional, but
+WHEN present it must be well-formed
 (percentiles ordered p50<=p95<=p99, non-negative counts).
 
 ``--expect-compile-once ENTRY`` additionally requires the watchdog's
