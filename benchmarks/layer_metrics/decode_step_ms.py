@@ -1,0 +1,9 @@
+"""Median device duration of the engine's decode program (the
+``jit_decode_fn`` module event of the trace), in ms."""
+from benchmarks.lib import trace as trace_mod
+
+
+def read(registry, trace, run):
+    if trace is None or run.get("kind") == "train":
+        return None
+    return trace_mod.module_median_ms(trace, "decode_fn")
