@@ -7,6 +7,10 @@ steps, pjit/GSPMD + shard_map parallelism, Pallas kernels for the hot ops.
 """
 from __future__ import annotations
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()   # read at the bottom: process.import_seconds
+
 __version__ = "0.1.0"
 
 import jax as _jax
@@ -14,6 +18,12 @@ import jax as _jax
 # paddle semantics need real int64 (labels, indices). float defaults stay
 # f32 via our own dtype conversion in core.tensor._to_array.
 _jax.config.update("jax_enable_x64", True)
+
+# compile phases and cache verdicts are counted from here on, under the
+# watched entry that pays them or under "(unwatched)"
+from .observability import watchdog as _watchdog  # noqa: E402
+
+_watchdog.listen()
 
 from .core import (Generator, Parameter, Tensor, enable_grad,
                    get_rng_state, grad, is_grad_enabled, no_grad, seed,
@@ -79,3 +89,8 @@ from .device import get_device, set_device  # noqa: E402
 from .jit import to_static  # noqa: E402
 
 Layer = nn.Layer
+
+from .observability import registry as _obs_registry  # noqa: E402
+
+_obs_registry.gauge("process.import_seconds").set(
+    _time.perf_counter() - _T_IMPORT)

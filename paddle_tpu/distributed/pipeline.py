@@ -24,6 +24,7 @@ import numpy as np
 
 from ..core.tensor import Tensor
 from ..nn.layer.layers import Layer, LayerList
+from ..observability import scopes as _scopes
 
 
 class LayerDesc:
@@ -825,15 +826,15 @@ class _CompiledPipelineStep:
                 grads = jax.tree_util.tree_map(
                     lambda g: g * inv.astype(g.dtype), grads)
                 finite = _grads_finite(grads)
+            with _scopes.scope(_scopes.OPTIMIZER):
                 new_params, new_opt = opt.apply_gradients(
                     params, grads, opt_state, lr)
+            if use_scaler:
                 keep = lambda new, old: jax.tree_util.tree_map(
                     lambda a, b: jnp.where(finite, a, b)
                     if hasattr(a, "dtype") else a, new, old)
                 return (loss, finite, keep(new_params, params),
                         keep(new_opt, opt_state))
-            new_params, new_opt = opt.apply_gradients(
-                params, grads, opt_state, lr)
             return loss, jnp.bool_(True), new_params, new_opt
 
         # recorded for the trace-tier donation audit (TPU502): params and

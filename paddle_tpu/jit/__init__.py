@@ -27,6 +27,8 @@ from ..core.grad_mode import no_grad
 from ..core.tensor import Tensor
 from ..nn.layer.layers import Layer
 from ..observability import liveness as _liveness
+from ..observability import scopes as _scopes
+from ..observability import tracing as _tracing
 from ..robustness.faultpoints import declare as _declare, faultpoint
 
 _declare("train.grads",
@@ -601,8 +603,9 @@ class TrainStep:
         def step_fn(params, buffers, opt_state, lr, rng, batch):
             loss, new_buffers, grads = grads_core(params, buffers, rng,
                                                   batch)
-            new_params, new_opt_state = self.optimizer.apply_gradients(
-                params, grads, opt_state, lr)
+            with _scopes.scope(_scopes.OPTIMIZER):
+                new_params, new_opt_state = self.optimizer.apply_gradients(
+                    params, grads, opt_state, lr)
             if self._param_specs is not None:
                 # ZeRO stage-3: updated params stay sharded
                 from jax.sharding import NamedSharding
@@ -720,9 +723,10 @@ class TrainStep:
         import time as _time
         t0 = _time.perf_counter()
         with self._beacon:   # liveness: a hang inside the step is a stall
-            out = self._step(
-                self.params, self.buffers, self.opt_state, lr, rng,
-                batch_a)
+            with _tracing.annotation("train", "step_call"):
+                out = self._step(
+                    self.params, self.buffers, self.opt_state, lr, rng,
+                    batch_a)
             if self._emit_grad_norm:
                 loss, self.params, self.buffers, self.opt_state, gnorm \
                     = out

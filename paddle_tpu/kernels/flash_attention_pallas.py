@@ -651,6 +651,7 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec):
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
+            name="flash_fwd",
             interpret=interpret,
         )(q3, k3, v3)
         return out, lse
@@ -685,6 +686,7 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec):
             out_specs=[q_spec3, lse_spec],
             out_shape=[out_shape, lse_shape],
             compiler_params=pltpu.CompilerParams(dimension_semantics=sem),
+            name="flash_fwd",
             interpret=interpret,
         )(q3, k3, v3)
         return out, (jnp.swapaxes(lse, 2, 3) if parq else lse)
@@ -711,6 +713,7 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec):
             pltpu.VMEM((block_q, hgd), jnp.float32),
         ],
         compiler_params=_SEQ2,
+        name="flash_fwd",
         interpret=interpret,
     )(q3, k3, v3)
     return out, lse
@@ -916,6 +919,7 @@ def _bwd_dq_call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
         out_shape=_sds((b, s, hd), q3.dtype, q3),
         scratch_shapes=[pltpu.VMEM((block_q, hgd), jnp.float32)],
         compiler_params=_SEQ2,
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q3, k3, v3, do3, lse5, delta5)
 
@@ -954,6 +958,7 @@ def _bwd_dkv_call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
         scratch_shapes=[pltpu.VMEM((block_k, hgd), jnp.float32),
                         pltpu.VMEM((block_k, hgd), jnp.float32)],
         compiler_params=_SEQ2,
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(q3, k3, v3, do3, lse5, delta5)
 
@@ -999,6 +1004,7 @@ def _bwd_merged_call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d,
             pltpu.VMEM((block_k, hgd), jnp.float32),
         ],
         compiler_params=_SEQ2,
+        name="flash_bwd",
         interpret=interpret,
     )(q3, k3, v3, do3, lse5, delta5)
 

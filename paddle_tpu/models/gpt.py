@@ -33,6 +33,7 @@ from ..nn import initializer as I
 from ..nn.layer.common import Dropout, Embedding, Linear
 from ..nn.layer.layers import Layer, LayerList
 from ..nn.layer.norm import LayerNorm
+from ..observability import scopes as _scopes
 
 
 @dataclasses.dataclass
@@ -99,6 +100,8 @@ class GPTConfig:
 
 
 class GPTAttention(Layer):
+    _scope = _scopes.ATTN
+
     def __init__(self, config: GPTConfig):
         super().__init__()
         c = config
@@ -190,6 +193,8 @@ class GPTAttention(Layer):
 
 
 class GPTMLP(Layer):
+    _scope = _scopes.MLP
+
     def __init__(self, config: GPTConfig):
         super().__init__()
         c = config
@@ -273,66 +278,70 @@ def _scan_block_apply(x, p, cfg, *, training, keys=None, cache=None):
         return jnp.where(keep, a / jnp.asarray(1.0 - p_drop, a.dtype),
                          jnp.zeros((), a.dtype))
 
-    h = layer_norm_raw(x, p["ln1_w"], p["ln1_b"], (h_sz,),
-                       cfg.layer_norm_epsilon)
-    static_cache = (cache is not None
-                    and not isinstance(cache, (tuple, list)))
-    if static_cache and _mpo.qkv_viable(nh, hd):
-        # overlapped fused-qkv island (see GPTAttention.forward)
-        q, k, v = _mpo.qkv_heads(h, p["qkv_w"], p["qkv_b"], nh, hd)
-    else:
-        qkv = h @ p["qkv_w"] + p["qkv_b"]
-        # last-dim slices (free) — see GPTAttention.forward for the
-        # measured why
-        q = qkv[..., :h_sz].reshape(b, s, nh, hd)
-        k = qkv[..., h_sz:2 * h_sz].reshape(b, s, nh, hd)
-        v = qkv[..., 2 * h_sz:].reshape(b, s, nh, hd)
-    if static_cache:
-        # static slotted cache view (serving.cache): in-place append +
-        # length-masked attention — no shape growth, no retrace.  Head-
-        # sharded under a tensor-parallel serving mesh (see
-        # GPTAttention.forward; no-op without an 'mp' mesh)
-        q, k, v = shard_heads(q), shard_heads(k), shard_heads(v)
-        out = cache.attend_raw(q, k, v)
-    elif cache is not None:
-        # LEGACY CONCAT SHIM (see GPTForCausalLM.gen_legacy_concat_cache)
-        pk, pv = cache
-        k = jnp.concatenate([pk, k], axis=1)
-        v = jnp.concatenate([pv, v], axis=1)
-        cache = (k, v)
-        out = scaled_dot_product_attention(q, k, v, is_causal=True,
-                                           training=training)
-        if isinstance(out, Tensor):
-            out = out._array
-    else:
-        attn_p = cfg.attention_dropout_prob
-        if attn_p > 0.0 and training and keys is not None:
-            # explicit per-layer key: sdpa's own next_key() would be a
-            # closure constant inside the scan body (same mask every layer)
-            out = sdpa_reference_raw(q, k, v, None, attn_p, True, None,
-                                     keys[0])
+    with _scopes.scope(_scopes.NORM):
+        h = layer_norm_raw(x, p["ln1_w"], p["ln1_b"], (h_sz,),
+                           cfg.layer_norm_epsilon)
+    with _scopes.scope(_scopes.ATTN):
+        static_cache = (cache is not None
+                        and not isinstance(cache, (tuple, list)))
+        if static_cache and _mpo.qkv_viable(nh, hd):
+            # overlapped fused-qkv island (see GPTAttention.forward)
+            q, k, v = _mpo.qkv_heads(h, p["qkv_w"], p["qkv_b"], nh, hd)
         else:
+            qkv = h @ p["qkv_w"] + p["qkv_b"]
+            # last-dim slices (free) — see GPTAttention.forward for the
+            # measured why
+            q = qkv[..., :h_sz].reshape(b, s, nh, hd)
+            k = qkv[..., h_sz:2 * h_sz].reshape(b, s, nh, hd)
+            v = qkv[..., 2 * h_sz:].reshape(b, s, nh, hd)
+        if static_cache:
+            # static slotted cache view (serving.cache): in-place append +
+            # length-masked attention — no shape growth, no retrace.  Head-
+            # sharded under a tensor-parallel serving mesh (see
+            # GPTAttention.forward; no-op without an 'mp' mesh)
+            q, k, v = shard_heads(q), shard_heads(k), shard_heads(v)
+            out = cache.attend_raw(q, k, v)
+        elif cache is not None:
+            # LEGACY CONCAT SHIM (see GPTForCausalLM.gen_legacy_concat_cache)
+            pk, pv = cache
+            k = jnp.concatenate([pk, k], axis=1)
+            v = jnp.concatenate([pv, v], axis=1)
+            cache = (k, v)
             out = scaled_dot_product_attention(q, k, v, is_causal=True,
                                                training=training)
             if isinstance(out, Tensor):
                 out = out._array
-    out = out.reshape(b, s, h_sz)
-    if _mpo.row_viable(h_sz):
-        out = _mpo.row_parallel_matmul(out, p["out_w"], p["out_b"])
-    else:
-        out = out @ p["out_w"] + p["out_b"]
-    out = dropout(out, cfg.hidden_dropout_prob,
-                  None if keys is None else keys[1])
+        else:
+            attn_p = cfg.attention_dropout_prob
+            if attn_p > 0.0 and training and keys is not None:
+                # explicit per-layer key: sdpa's own next_key() would be a
+                # closure constant inside the scan body (same mask every layer)
+                out = sdpa_reference_raw(q, k, v, None, attn_p, True, None,
+                                         keys[0])
+            else:
+                out = scaled_dot_product_attention(q, k, v, is_causal=True,
+                                                   training=training)
+                if isinstance(out, Tensor):
+                    out = out._array
+        out = out.reshape(b, s, h_sz)
+        if _mpo.row_viable(h_sz):
+            out = _mpo.row_parallel_matmul(out, p["out_w"], p["out_b"])
+        else:
+            out = out @ p["out_w"] + p["out_b"]
+        out = dropout(out, cfg.hidden_dropout_prob,
+                      None if keys is None else keys[1])
     x = x + out
-    h2 = layer_norm_raw(x, p["ln2_w"], p["ln2_b"], (h_sz,),
-                        cfg.layer_norm_epsilon)
-    m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"], approximate=True)
-    if _mpo.row_viable(cfg.intermediate_size):
-        m = _mpo.row_parallel_matmul(m, p["fc2_w"], p["fc2_b"])
-    else:
-        m = m @ p["fc2_w"] + p["fc2_b"]
-    m = dropout(m, cfg.hidden_dropout_prob,
-                None if keys is None else keys[2])
+    with _scopes.scope(_scopes.NORM):
+        h2 = layer_norm_raw(x, p["ln2_w"], p["ln2_b"], (h_sz,),
+                            cfg.layer_norm_epsilon)
+    with _scopes.scope(_scopes.MLP):
+        m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"], approximate=True)
+        if _mpo.row_viable(cfg.intermediate_size):
+            m = _mpo.row_parallel_matmul(m, p["fc2_w"], p["fc2_b"])
+        else:
+            m = m @ p["fc2_w"] + p["fc2_b"]
+        m = dropout(m, cfg.hidden_dropout_prob,
+                    None if keys is None else keys[2])
     x = x + m
     x = with_sharding_constraint(x, PartitionSpec("dp", "sep", None))
     return x, cache
@@ -595,8 +604,10 @@ class GPTModel(Layer):
             # overlapped vocab-parallel lookup: masked local gather +
             # psum (activation-sized all-reduce) instead of GSPMD's
             # table-sized all-gather
-            tok = call(lambda ids, w: _mpo.vocab_embed(ids, w),
-                       input_ids, self.wte.weight, name="mp_overlap_embed")
+            with _scopes.scope(_scopes.EMBED):
+                tok = call(lambda ids, w: _mpo.vocab_embed(ids, w),
+                           input_ids, self.wte.weight,
+                           name="mp_overlap_embed")
             x = tok + self.wpe(position_ids)
         else:
             x = self.wte(input_ids) + self.wpe(position_ids)
@@ -644,25 +655,25 @@ class GPTForCausalLM(Layer):
                                   bias_attr=False)
             self.lm_head.weight.pspec = PartitionSpec(None, "mp")
 
+    def _head(self, x):
+        if not self.config.tie_word_embeddings:
+            return self.lm_head(x)
+        if _mpo.lm_viable(self.config.vocab_size):
+            # overlapped LM head: rotate-weights ring over the vocab
+            # shards — each step matmuls the resident shard into its
+            # logits slice while the next is in flight (no monolithic
+            # table all-gather)
+            return call(lambda xr, w: _mpo.lm_head_matmul(xr, w),
+                        x, self.gpt.wte.weight, name="mp_overlap_lm_head")
+        return ops.matmul(x, self.gpt.wte.weight, transpose_y=True)
+
     def forward(self, input_ids, position_ids=None, cache=None):
         if cache is not None:
             x, cache = self.gpt(input_ids, position_ids, cache)
         else:
             x = self.gpt(input_ids, position_ids)
-        if self.config.tie_word_embeddings:
-            if _mpo.lm_viable(self.config.vocab_size):
-                # overlapped LM head: rotate-weights ring over the vocab
-                # shards — each step matmuls the resident shard into its
-                # logits slice while the next is in flight (no monolithic
-                # table all-gather)
-                logits = call(lambda xr, w: _mpo.lm_head_matmul(xr, w),
-                              x, self.gpt.wte.weight,
-                              name="mp_overlap_lm_head")
-            else:
-                logits = ops.matmul(x, self.gpt.wte.weight,
-                                    transpose_y=True)
-        else:
-            logits = self.lm_head(x)
+        with _scopes.scope(_scopes.LM_HEAD):
+            logits = self._head(x)
         if cache is not None:
             return logits, cache
         return logits
@@ -745,6 +756,8 @@ class GPTForCausalLM(Layer):
 class GPTPretrainingCriterion(Layer):
     """Shifted-causal-LM loss (reference analogue: the fleet GPT model's
     criterion)."""
+
+    _scope = _scopes.LOSS
 
     def forward(self, logits, labels, loss_mask=None):
         # shift via the LABELS, not the logits: slicing logits[:, :-1, :]

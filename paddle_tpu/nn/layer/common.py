@@ -7,6 +7,7 @@ from ... import ops
 from ...core.tensor import Parameter, Tensor
 from .. import functional as F
 from .. import initializer as I
+from ...observability import scopes as _scopes
 from .layers import Layer
 
 
@@ -89,6 +90,8 @@ class AlphaDropout(Layer):
 class Embedding(Layer):
     """reference: python/paddle/nn/layer/common.py Embedding
     (num_embeddings, embedding_dim), lookup by int ids."""
+
+    _scope = _scopes.EMBED
 
     def __init__(self, num_embeddings, embedding_dim, padding_idx=None,
                  sparse=False, weight_attr=None, name=None):

@@ -18,6 +18,7 @@ import numpy as np
 
 from ...core import dtype as _dt
 from ...core.tensor import Parameter, Tensor
+from ...observability import scopes as _scopes
 
 
 class HookRemoveHelper:
@@ -33,6 +34,11 @@ class HookRemoveHelper:
 
 
 class Layer:
+    #: the role this layer's work carries in a compiled program's trace
+    #: (a name of ``observability.scopes.VOCABULARY``); None adds no scope.
+    #: A model names its blocks by setting this on their classes
+    _scope: Optional[str] = None
+
     def __init__(self, name_scope=None, dtype="float32"):
         self.training = True
         self._dtype = _dt.convert_dtype(dtype)
@@ -213,7 +219,11 @@ class Layer:
             out = hook(self, inputs)
             if out is not None:
                 inputs = out if isinstance(out, tuple) else (out,)
-        outputs = self.forward(*inputs, **kwargs)
+        if self._scope is None:
+            outputs = self.forward(*inputs, **kwargs)
+        else:
+            with _scopes.scope(self._scope):
+                outputs = self.forward(*inputs, **kwargs)
         for hook in list(self._forward_post_hooks.values()):
             res = hook(self, inputs, outputs)
             if res is not None:

@@ -6,10 +6,13 @@ import numpy as np
 from ...core.tensor import Tensor
 from .. import functional as F
 from .. import initializer as I
+from ...observability import scopes as _scopes
 from .layers import Layer
 
 
 class LayerNorm(Layer):
+    _scope = _scopes.NORM
+
     def __init__(self, normalized_shape, epsilon=1e-5, weight_attr=None,
                  bias_attr=None, name=None):
         super().__init__()
@@ -38,6 +41,8 @@ class LayerNorm(Layer):
 
 
 class RMSNorm(Layer):
+    _scope = _scopes.NORM
+
     def __init__(self, hidden_size, epsilon=1e-6):
         super().__init__()
         self.epsilon = epsilon

@@ -14,7 +14,13 @@ subsystem:
   runtime emission in sync).
 * :mod:`.watchdog` — the recompile watchdog over the compile-once jit
   entries (TrainStep, serving decode/prefill, 1F1B): counts compiles,
-  warns on budget violations, raises under ``PADDLE_TPU_STRICT_COMPILE=1``.
+  warns on budget violations, raises under ``PADDLE_TPU_STRICT_COMPILE=1``;
+  files JAX's trace / lower / backend seconds and cache verdicts under
+  the entry that pays them (``compile.phase_seconds``, ``compile.cache``).
+* :mod:`.scopes` — the program's own names inside its compiled programs:
+  the one vocabulary of ``jax.named_scope`` roles (``attn``, ``mlp``,
+  ``optimizer``, ``decode_attn``, ...) and the index from a compiled
+  program's instructions back to them, by which a device trace is read.
 * :mod:`.exporters` — Prometheus text, JSONL snapshots, chrome-trace
   metric marks injected into the :mod:`paddle_tpu.profiler` stream.
 * :mod:`.tracing` — request-scoped span tracing (ISSUE 9): a trace_id
@@ -61,7 +67,7 @@ import lazily.  See OBSERVABILITY.md for the metric catalog and knobs.
 """
 from __future__ import annotations
 
-from . import aggregate, costs, flight, hbm, liveness
+from . import aggregate, costs, flight, hbm, liveness, scopes
 from .catalog import CATALOG
 from .registry import (NOOP_COUNTER, NOOP_GAUGE, NOOP_HISTOGRAM, Counter,
                        Gauge, Histogram, Registry, counter, default_registry,
@@ -77,5 +83,5 @@ __all__ = [
     "RecompileError", "RecompileWarning", "WatchedEntry", "watch",
     "compile_counts",
     "Tracer", "NOOP_TRACER", "NOOP_SPAN", "default_tracer", "flight",
-    "costs", "hbm", "liveness", "aggregate",
+    "costs", "hbm", "liveness", "aggregate", "scopes",
 ]

@@ -235,6 +235,23 @@ CATALOG = {
         "counter", "XLA compilations per watched jit entry (the recompile "
         "watchdog warns/raises when a compile-once entry exceeds its "
         "budget)", labels=("entry",)),
+    "compile.phase_seconds": _m(
+        "counter", "seconds JAX spent bringing a watched entry's programs "
+        "up, by phase: trace (Python to jaxpr), lower (jaxpr to "
+        "StableHLO), backend (XLA's compile, or on a persistent-cache hit "
+        "the read and deserialisation).  entry='(unwatched)' sums what "
+        "arrived outside any watched call: eager ops, model construction, "
+        "weight loading (nested traces counted again there)",
+        labels=("entry", "phase"), unit="seconds"),
+    "compile.cache": _m(
+        "counter", "persistent compile cache verdicts (result=hit|miss), "
+        "filed under the watched entry being called or under "
+        "entry='(unwatched)'; silent where the cache is off",
+        labels=("entry", "result")),
+    "process.import_seconds": _m(
+        "gauge", "wall time of `import paddle_tpu`, top of the package's "
+        "__init__ to its bottom (jax's own import is outside it when the "
+        "caller imported jax first)", unit="seconds"),
 
     # -- liveness watchdog + cluster view (observability.liveness /
     # .aggregate — armed via PADDLE_TPU_LIVENESS=1) -------------------------

@@ -40,6 +40,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from . import scopes as _scopes
+
 __all__ = [
     "ProgramReport", "cost_analysis_dict", "memory_analysis_dict",
     "report_from_compiled", "compile_program", "report_for_program",
@@ -261,6 +263,23 @@ def collective_stats(compiled) -> Optional[Dict[str, Any]]:
             "by_kind": by_kind}
 
 
+def scope_ops(compiled) -> Optional[Dict[str, int]]:
+    """``{scope: instructions}`` of a compiled program
+    (:func:`.scopes.instruction_scopes` over its HLO text); None when the
+    backend gives no text."""
+    try:
+        text = compiled.as_text()
+    except Exception:
+        return None
+    if not text:
+        return None
+    counts: Dict[str, int] = {}
+    for role in _scopes.instruction_scopes(text)[1].values():
+        role = role or _scopes.UNSCOPED
+        counts[role] = counts.get(role, 0) + 1
+    return counts
+
+
 @dataclasses.dataclass
 class ProgramReport:
     """XLA's cost + memory view of one compiled program.
@@ -296,6 +315,12 @@ class ProgramReport:
     #: overlap ring trades one big launch for many small ones, which
     #: only this view can tell apart from a genuine byte regression
     collective_by_kind: Optional[Dict[str, Dict[str, int]]] = None
+    #: PR 25: instructions that run as operations of their own, counted by
+    #: the program's scope they carry (``observability.scopes``; fused
+    #: instructions left out, ``unscoped`` for those with no role) — which
+    #: names a device trace of this program can be read by.  None when the
+    #: backend exposes no HLO text
+    scope_ops: Optional[Dict[str, int]] = None
 
     def as_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -325,6 +350,7 @@ def report_from_compiled(name: str, compiled, backend: Optional[str] = None,
     mem = memory_analysis_dict(compiled)
     coll = collective_stats(compiled)
     return ProgramReport(
+        scope_ops=scope_ops(compiled),
         name=name, backend=backend, available=True, note=note,
         flops=(float(ca["flops"]) if "flops" in ca else None),
         bytes_accessed=(float(ca["bytes accessed"])
@@ -461,13 +487,16 @@ def format_table(reports: Sequence[ProgramReport]) -> str:
     """Human table for ``python -m paddle_tpu.observability programs``."""
     lines = ["%-42s %10s %10s %10s %10s %10s  %s"
              % ("program", "flops", "hbm_bytes", "peak", "args", "temps",
-                "note")]
+                "scopes (instructions) / note")]
     for r in reports:
+        roles = " ".join("%s:%d" % kv for kv in sorted(
+            (r.scope_ops or {}).items()) if kv[0] != _scopes.UNSCOPED)
+        note = r.note or ("" if r.available else "UNAVAILABLE")
         lines.append("%-42s %10s %10s %10s %10s %10s  %s"
                      % (r.name, _fmt_num(r.flops),
                         _fmt_num(r.bytes_accessed), _fmt_num(r.peak_bytes),
                         _fmt_num(r.argument_bytes), _fmt_num(r.temp_bytes),
-                        r.note or ("" if r.available else "UNAVAILABLE")))
+                        " / ".join(x for x in (roles, note) if x)))
     avail = sum(1 for r in reports if r.available)
     lines.append("%d program(s), %d priced (backend: %s)"
                  % (len(reports), avail, _backend_name()))

@@ -57,10 +57,29 @@ from . import flight as _flight
 
 __all__ = [
     "Span", "NoopSpan", "Tracer", "NoopTracer",
-    "NOOP_SPAN", "NOOP_TRACER",
+    "NOOP_SPAN", "NOOP_TRACER", "annotation",
     "default_tracer", "load_trace", "build_report", "format_report",
     "build_sli", "format_sli", "chrome_events", "write_chrome",
 ]
+
+_TraceAnnotation = None     # jax.profiler.TraceAnnotation, bound on first use
+
+
+def annotation(layer: str, phase: str):
+    """A host span on the device's clock: a ``jax.profiler.TraceAnnotation``
+    named ``pt.<layer>.<phase>``, to use as a context manager where the
+    host can hold the chip back (a dispatch, the wait for a result, the
+    bookkeeping between two steps).  Inside a profiler session it lands in
+    the xplane's host plane beside the device's operations, so an idle gap
+    of the device can be read against what the host was doing; with no
+    session it is a flag test in C++ and records nothing.  Unlike a
+    :class:`Span` it has no lane, no attrs and no report: where both name
+    one interval, open both (the engine's dispatch span does)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation("pt.%s.%s" % (layer, phase))
 
 #: default bound on buffered spans+events per tracer (drop-oldest past it)
 TRACE_CAP_DEFAULT = 200_000

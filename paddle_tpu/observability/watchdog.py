@@ -19,9 +19,23 @@ program-cache size after every call:
 ``watch()`` wraps the jitted callable transparently: attribute access
 (``_cache_size``, ``lower``, ...) is delegated, so existing audit hooks
 and compile-count properties keep working on a watched entry.
+
+The same wrapper names what set-up pays for.  JAX publishes the seconds of
+every trace, lowering and backend compile (or cache load) with the
+function's name; a watched entry marks itself as the thread's current entry
+for the length of a call, and one listener files each duration under it as
+``compile.phase_seconds{entry, phase}`` and each persistent-cache verdict
+as ``compile.cache{entry, result}``.  What arrives outside any watched call
+(eager ops, model construction, weight loading) is filed under
+``entry="(unwatched)"``.  And a watched entry remembers each program it
+compiled (:class:`Program`), so that the program's text can be read later
+for which instruction carries which of the program's scopes
+(:mod:`.scopes`).
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import json
 import os
 import threading
@@ -32,7 +46,79 @@ from typing import Callable, Dict, Optional
 from . import registry as _registry
 
 __all__ = ["RecompileWarning", "RecompileError", "WatchedEntry", "watch",
-           "compile_counts", "resync_counter", "strict_mode"]
+           "Program", "compile_counts", "listen", "live_entries",
+           "programs", "resync_counter", "strict_mode", "UNWATCHED"]
+
+#: the ``entry`` label of compile events that arrive outside any watched call
+UNWATCHED = "(unwatched)"
+
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # on a persistent-cache hit this is the read and deserialisation
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_RESULTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+# .entry: the WatchedEntry being called (or the Program being re-read)
+_current = threading.local()
+_listening = False
+
+
+@contextlib.contextmanager
+def _filing_under(owner):
+    """Compile events of this thread are ``owner``'s inside the block (the
+    cold paths' form; ``WatchedEntry.__call__`` spells it out)."""
+    outer = getattr(_current, "entry", None)
+    _current.entry = owner
+    try:
+        yield
+    finally:
+        _current.entry = outer
+
+
+def _on_duration(event, seconds, fun_name=None, **_):
+    phase = _PHASES.get(event)
+    if phase is None:
+        return
+    entry = getattr(_current, "entry", None)
+    if entry is None:
+        name = UNWATCHED
+    elif _same_function(entry.fn_name, fun_name):
+        name = entry.entry_name
+    else:
+        # a jit traced inside the entry's own trace, or an eager op on a
+        # constant: its seconds are already inside the entry's trace phase
+        return
+    _registry.counter("compile.phase_seconds", ("entry", "phase")).labels(
+        entry=name, phase=phase).inc(seconds)
+
+
+def _on_event(event, **_):
+    result = _CACHE_RESULTS.get(event)
+    if result is None:
+        return
+    entry = getattr(_current, "entry", None)
+    _registry.counter("compile.cache", ("entry", "result")).labels(
+        entry=UNWATCHED if entry is None else entry.entry_name,
+        result=result).inc()
+
+
+def listen():
+    """Register the two listeners, once a process.  ``import paddle_tpu``
+    calls it, so that the eager programs of model construction are counted
+    from the first; ``watch`` calls it for a process that imported this
+    package alone.  Imports jax, starts no backend."""
+    global _listening
+    with _ENTRIES_LOCK:
+        if _listening:
+            return
+        _listening = True
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
 
 
 class RecompileWarning(UserWarning):
@@ -74,9 +160,11 @@ class WatchedEntry:
         self._name = name
         self._fn = fn
         self._expected = expected
+        self.fn_name = getattr(fn, "__name__", "")
         self._seen = self._raw_cache_size()
         self._counter = _registry.counter("compile.count", ("entry",))
         self._lock = threading.Lock()
+        self._programs: list = []     # one Program a compile
         with _ENTRIES_LOCK:
             refs = _ENTRIES.setdefault(name, [])
             refs[:] = [r for r in refs if r() is not None]
@@ -110,19 +198,41 @@ class WatchedEntry:
     # -- the metered call --------------------------------------------------
 
     def __call__(self, *args, **kwargs):
-        out = self._fn(*args, **kwargs)
+        outer = getattr(_current, "entry", None)
+        _current.entry = self
+        try:
+            out = self._fn(*args, **kwargs)
+        finally:
+            _current.entry = outer
         n = self._raw_cache_size()
         if n != self._seen:
-            self._on_growth(n)
+            self._on_growth(n, args, kwargs)
         return out
 
-    def _on_growth(self, n: int):
+    def _remember(self, args, kwargs):
+        """Keep the program this call compiled (:class:`Program`).  The
+        call has already run, on donated buffers: whatever goes wrong here
+        costs the scope index one program, never the caller its step."""
+        try:
+            sig_args, sig_kwargs = _abstract((args, kwargs))
+            with _filing_under(self):
+                traced = self._fn.trace(*sig_args, **sig_kwargs)
+        except Exception:
+            return
+        program = Program(self._name, self.fn_name, traced)
+        with _ENTRIES_LOCK:
+            self._programs.append(program)
+            _PROGRAMS.setdefault(self._name, collections.deque(
+                maxlen=_PROGRAMS_KEPT)).append(program)
+
+    def _on_growth(self, n: int, args, kwargs):
         with self._lock:
             grew = n - self._seen
             if grew <= 0:       # cache cleared/shrunk: resync, no event
                 self._seen = n
                 return
             self._seen = n
+        self._remember(args, kwargs)
         self._counter.labels(entry=self._name).inc(grew)
         from . import flight as _flight
         _flight.record("recompile", entry=self._name, compile_count=n,
@@ -151,23 +261,117 @@ class WatchedEntry:
                 RecompileWarning, stacklevel=3)
 
 
+    # -- which instruction carries which scope (cold path) ------------------
+
+    def instruction_scopes(self) -> Dict[str, dict]:
+        """``{HLO module name: {instruction name: scope or None}}`` of the
+        programs this entry compiled (:meth:`Program.instruction_scopes`)."""
+        with self._lock:
+            programs = list(self._programs)
+        found: Dict[str, dict] = {}
+        for program in programs:
+            module, table = program.instruction_scopes()
+            found.setdefault(module, {}).update(table)
+        return found
+
+
+class Program:
+    """One program a watched entry compiled, kept so that its HLO text can
+    be read later: JAX will not hand out the text of the executable a jit
+    call built, so the traced program (the jaxpr, found again in JAX's
+    trace cache: no Python is re-run) is lowered and compiled once more.
+    While the jaxpr lives JAX still holds that lowering and its executable,
+    so the second compile is a look-up; where they have gone it is a
+    persistent-cache read, or a compile.  A cold path all the same: after a
+    measurement, never inside one.
+
+    The newest few of each entry name are held strongly at module level
+    (``_PROGRAMS``): a jaxpr holds no model state, and a reader that comes
+    after the step object has gone, as the benchmark's do, still finds its
+    program."""
+
+    __slots__ = ("entry_name", "fn_name", "_traced", "_scopes")
+
+    def __init__(self, entry_name, fn_name, traced):
+        self.entry_name = entry_name
+        self.fn_name = fn_name
+        self._traced = traced
+        self._scopes = None
+
+    def instruction_scopes(self):
+        """``(HLO module name, {instruction name: scope or None})``
+        (:func:`.scopes.instruction_scopes`); kept, so a second call
+        compiles nothing."""
+        if self._scopes is None:
+            from . import scopes as _scopes
+            with _filing_under(self):   # its compile events are the entry's
+                text = self._traced.lower().compile().as_text()
+            self._scopes = _scopes.instruction_scopes(text)
+        return self._scopes
+
+
+#: how many programs of one entry name stay readable after their entries
+#: died (the bucketed prefill compiles one a bucket; replicas share a name)
+_PROGRAMS_KEPT = 8
+_PROGRAMS: Dict[str, "collections.deque[Program]"] = {}
+
+
+def _same_function(fn_name: str, fun_name: Optional[str]) -> bool:
+    """Whether a JAX compile event's ``fun_name`` names the function
+    ``fn_name``: the trace event says ``step_fn``, the lowering and the
+    backend ``jit(step_fn)``."""
+    if not fun_name or not fn_name:
+        return False
+    return fun_name in (fn_name, "jit(%s)" % fn_name, "pjit(%s)" % fn_name,
+                        "jit_" + fn_name)
+
+
+def _abstract(tree):
+    """The call's arguments with every array replaced by its shape, dtype
+    and (where it was committed to one) sharding: what ``jit.trace`` needs
+    to find the same program, holding no buffer.  Read after the call, so a
+    donated array is already deleted; its aval and sharding still answer."""
+    import jax
+
+    def leaf(a):
+        if not isinstance(a, jax.Array) or isinstance(a, jax.core.Tracer):
+            return a
+        sharding = a.sharding if getattr(a, "committed", True) else None
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding,
+                                    weak_type=getattr(a, "weak_type", False))
+    return jax.tree_util.tree_map(leaf, tree)
+
+
+def programs() -> list:
+    """Every remembered :class:`Program`, in entry-name order."""
+    with _ENTRIES_LOCK:
+        return [p for _, kept in sorted(_PROGRAMS.items()) for p in kept]
+
+
 def watch(name: str, fn: Callable,
           expected: Optional[int] = None) -> WatchedEntry:
     """Wrap a jitted callable as a watched entry.  ``expected`` is the
     compile budget (1 for compile-once entries, ``len(buckets)`` for the
     bucketed prefill, None to meter without a budget)."""
+    listen()
     return WatchedEntry(name, fn, expected)
+
+
+def live_entries() -> list:
+    """Every watched entry still alive, in name order."""
+    with _ENTRIES_LOCK:
+        return [e for _, refs in sorted(_ENTRIES.items())
+                for e in (r() for r in refs) if e is not None]
 
 
 def compile_counts() -> Dict[str, int]:
     """{entry name: total programs held} across every live watched entry
     in the process — what bench.py / bench_decode.py attach to their JSON
     lines."""
-    with _ENTRIES_LOCK:
-        items = [(name, [e for e in (r() for r in refs) if e is not None])
-                 for name, refs in sorted(_ENTRIES.items())]
-    return {name: sum(e.compile_count for e in entries)
-            for name, entries in items if entries}
+    counts: Dict[str, int] = {}
+    for e in live_entries():
+        counts[e.entry_name] = counts.get(e.entry_name, 0) + e.compile_count
+    return counts
 
 
 def resync_counter():

@@ -98,6 +98,7 @@ import numpy as np
 # cache's writes and the kernels' reads can never drift); re-exported
 # here as serving API
 from ..kernels.decode_attention import dequantize_kv, quantize_kv
+from ..observability import scopes as _scopes
 
 __all__ = ["SlottedKVCache", "DecodeView", "PrefillView", "PagedKVCache",
            "PagedDecodeView", "PagedPrefillChunkView", "is_cache_view",
@@ -343,6 +344,13 @@ def paged_scatter(kc, vc, layer, table, pos, valid, k_new, v_new,
     the ``ksc/vsc`` scale pools through the SAME routed indices.
     Returns ``(kc, vc, ksc, vsc)`` (scale pools pass through as None
     when unquantized)."""
+    with _scopes.scope(_scopes.KV_WRITE):
+        return _paged_scatter(kc, vc, layer, table, pos, valid, k_new,
+                              v_new, ksc, vsc, ks_new, vs_new)
+
+
+def _paged_scatter(kc, vc, layer, table, pos, valid, k_new, v_new, ksc, vsc,
+                   ks_new, vs_new):
     P = int(kc.shape[2])
     max_pages = int(table.shape[1])
     num_pages = int(kc.shape[0])
@@ -378,6 +386,9 @@ class _CacheView:
     #: layout-specific carry fields between the scale pools and lengths
     #: (the paged views add "page_table")
     _extra_fields = ()
+    #: the role of the view's attention in a trace (observability.scopes);
+    #: the append is ``kv_write`` in every view
+    _attn_scope = _scopes.DECODE_ATTN
 
     def __init__(self, cache, track_quant_err=False):
         self.k = _unwrap(cache.k)
@@ -498,10 +509,11 @@ class _CacheView:
         """Quantize fresh K/V rows and fold their dequant error into the
         carried accumulator; returns (kq, ks, vq, vs, new_err)."""
         # the pool's dtype IS the grid selector (int8 or e4m3)
-        kq, ks = quantize_kv(k_new, c["k"].dtype)
-        vq, vs = quantize_kv(v_new, c["v"].dtype)
-        err = _append_quant_err(c.get("quant_err"),
-                                ((k_new, kq, ks), (v_new, vq, vs)))
+        with _scopes.scope(_scopes.KV_WRITE):
+            kq, ks = quantize_kv(k_new, c["k"].dtype)
+            vq, vs = quantize_kv(v_new, c["v"].dtype)
+            err = _append_quant_err(c.get("quant_err"),
+                                    ((k_new, kq, ks), (v_new, vq, vs)))
         return kq, ks, vq, vs, err
 
 
@@ -542,19 +554,23 @@ class DecodeView(_CacheView):
         # Rows past max_len (a slot the scheduler failed to evict) drop.
         if self.quantized:
             kq, ks, vq, vs, err = self._quantize_new(c, k_new, v_new)
-            kc = kc.at[b_idx, layer, t_idx].set(kq)
-            vc = vc.at[b_idx, layer, t_idx].set(vq)
-            ksc = c["k_scale"].at[b_idx, layer, t_idx].set(ks)
-            vsc = c["v_scale"].at[b_idx, layer, t_idx].set(vs)
-            out = decode_attention(q, kc[:, layer], vc[:, layer], lengths,
-                                   scale=scale, k_scales=ksc[:, layer],
-                                   v_scales=vsc[:, layer])
+            with _scopes.scope(_scopes.KV_WRITE):
+                kc = kc.at[b_idx, layer, t_idx].set(kq)
+                vc = vc.at[b_idx, layer, t_idx].set(vq)
+                ksc = c["k_scale"].at[b_idx, layer, t_idx].set(ks)
+                vsc = c["v_scale"].at[b_idx, layer, t_idx].set(vs)
+            with _scopes.scope(self._attn_scope):
+                out = decode_attention(
+                    q, kc[:, layer], vc[:, layer], lengths, scale=scale,
+                    k_scales=ksc[:, layer], v_scales=vsc[:, layer])
             mut = (kc, vc, ksc, vsc) + (() if err is None else (err,))
             return (out,) + mut
-        kc = kc.at[b_idx, layer, t_idx].set(k_new.astype(kc.dtype))
-        vc = vc.at[b_idx, layer, t_idx].set(v_new.astype(vc.dtype))
-        out = decode_attention(q, kc[:, layer], vc[:, layer], lengths,
-                               scale=scale)
+        with _scopes.scope(_scopes.KV_WRITE):
+            kc = kc.at[b_idx, layer, t_idx].set(k_new.astype(kc.dtype))
+            vc = vc.at[b_idx, layer, t_idx].set(v_new.astype(vc.dtype))
+        with _scopes.scope(self._attn_scope):
+            out = decode_attention(q, kc[:, layer], vc[:, layer], lengths,
+                                   scale=scale)
         return out, kc, vc
 
     def finalize(self, advance=None) -> SlottedKVCache:
@@ -579,6 +595,8 @@ class PrefillView(_CacheView):
     caches quantize the written rows; the block attention itself runs on
     the exact pre-quantization K/V (nothing prior to attend to)."""
 
+    _attn_scope = _scopes.PREFILL_ATTN
+
     def __init__(self, cache: SlottedKVCache, slot, true_len):
         super().__init__(cache)
         self.slot = jnp.asarray(_unwrap(slot), jnp.int32)
@@ -599,25 +617,29 @@ class PrefillView(_CacheView):
         start = (self.slot, jnp.asarray(layer, jnp.int32), zero, zero, zero)
         if self.quantized:
             kq, ks, vq, vs, _err = self._quantize_new(c, k_new, v_new)
-            kc = jax.lax.dynamic_update_slice(kc, kq[:, None], start)
-            vc = jax.lax.dynamic_update_slice(vc, vq[:, None], start)
-            ksc = jax.lax.dynamic_update_slice(
-                c["k_scale"], ks[:, None], start[:-1])
-            vsc = jax.lax.dynamic_update_slice(
-                c["v_scale"], vs[:, None], start[:-1])
-        else:
-            kc = jax.lax.dynamic_update_slice(
-                kc, k_new.astype(kc.dtype)[:, None], start)
-            vc = jax.lax.dynamic_update_slice(
-                vc, v_new.astype(vc.dtype)[:, None], start)
+        with _scopes.scope(_scopes.KV_WRITE):
+            if self.quantized:
+                kc = jax.lax.dynamic_update_slice(kc, kq[:, None], start)
+                vc = jax.lax.dynamic_update_slice(vc, vq[:, None], start)
+                ksc = jax.lax.dynamic_update_slice(
+                    c["k_scale"], ks[:, None], start[:-1])
+                vsc = jax.lax.dynamic_update_slice(
+                    c["v_scale"], vs[:, None], start[:-1])
+            else:
+                kc = jax.lax.dynamic_update_slice(
+                    kc, k_new.astype(kc.dtype)[:, None], start)
+                vc = jax.lax.dynamic_update_slice(
+                    vc, v_new.astype(vc.dtype)[:, None], start)
         # fresh slot: nothing precedes the block — attention is plain
         # causal over the bucket (bucket^2 logits, not bucket*max_len),
         # through the Pallas flash kernel when the shapes support it
-        if fa.supported(q, k_new):
-            out = fa.flash_attention_bshd(q, k_new, v_new, causal=True,
-                                          scale=scale)
-        else:
-            out = sdpa_reference_raw(q, k_new, v_new, None, 0.0, True, scale)
+        with _scopes.scope(self._attn_scope):
+            if fa.supported(q, k_new):
+                out = fa.flash_attention_bshd(q, k_new, v_new, causal=True,
+                                              scale=scale)
+            else:
+                out = sdpa_reference_raw(q, k_new, v_new, None, 0.0, True,
+                                         scale)
         if self.quantized:
             return out, kc, vc, ksc, vsc
         return out, kc, vc
@@ -681,16 +703,19 @@ class PagedDecodeView(_CacheView):
             kc, vc, ksc, vsc = paged_scatter(
                 kc, vc, layer, table, pos, valid, kq, vq,
                 ksc=c["k_scale"], vsc=c["v_scale"], ks_new=ks, vs_new=vs)
-            out = paged_decode_attention(
-                q, kc[:, layer], vc[:, layer], table, lengths, scale=scale,
-                k_scales=ksc[:, layer], v_scales=vsc[:, layer],
-                tp=self.tp)
+            with _scopes.scope(self._attn_scope):
+                out = paged_decode_attention(
+                    q, kc[:, layer], vc[:, layer], table, lengths,
+                    scale=scale, k_scales=ksc[:, layer],
+                    v_scales=vsc[:, layer], tp=self.tp)
             mut = (kc, vc, ksc, vsc) + (() if err is None else (err,))
             return (out,) + mut
         kc, vc, _, _ = paged_scatter(kc, vc, layer, table, pos, valid,
                                      k_new, v_new)
-        out = paged_decode_attention(q, kc[:, layer], vc[:, layer], table,
-                                     lengths, scale=scale, tp=self.tp)
+        with _scopes.scope(self._attn_scope):
+            out = paged_decode_attention(q, kc[:, layer], vc[:, layer],
+                                         table, lengths, scale=scale,
+                                         tp=self.tp)
         return out, kc, vc
 
     def finalize(self, advance=None) -> PagedKVCache:
@@ -725,6 +750,7 @@ class PagedPrefillChunkView(_CacheView):
     own quantized rows — the same values every later decode step sees)."""
 
     _extra_fields = ("page_table",)
+    _attn_scope = _scopes.PREFILL_ATTN
 
     def __init__(self, cache: PagedKVCache, slot, n_before, n_valid,
                  tp=1):
@@ -760,16 +786,19 @@ class PagedPrefillChunkView(_CacheView):
             kc, vc, ksc, vsc = paged_scatter(
                 kc, vc, layer, row_tab, pos, valid, kq, vq,
                 ksc=c["k_scale"], vsc=c["v_scale"], ks_new=ks, vs_new=vs)
-            out = paged_decode_attention(
-                q, kc[:, layer], vc[:, layer], row_tab, self.n_before[None],
-                scale=scale, k_scales=ksc[:, layer], v_scales=vsc[:, layer],
-                tp=self.tp)
+            with _scopes.scope(self._attn_scope):
+                out = paged_decode_attention(
+                    q, kc[:, layer], vc[:, layer], row_tab,
+                    self.n_before[None], scale=scale,
+                    k_scales=ksc[:, layer], v_scales=vsc[:, layer],
+                    tp=self.tp)
             return out, kc, vc, ksc, vsc
         kc, vc, _, _ = paged_scatter(kc, vc, layer, row_tab, pos, valid,
                                      k_new, v_new)
-        out = paged_decode_attention(q, kc[:, layer], vc[:, layer],
-                                     row_tab, self.n_before[None],
-                                     scale=scale, tp=self.tp)
+        with _scopes.scope(self._attn_scope):
+            out = paged_decode_attention(q, kc[:, layer], vc[:, layer],
+                                         row_tab, self.n_before[None],
+                                         scale=scale, tp=self.tp)
         return out, kc, vc
 
     def finalize(self) -> PagedKVCache:

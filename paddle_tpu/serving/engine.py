@@ -149,6 +149,31 @@ __all__ = ["DecodeEngine", "InflightDecode", "PagePoolExhausted",
            "PrefillTask", "prefill_buckets_for"]
 
 
+class _DispatchSpan:
+    """See :meth:`DecodeEngine._dispatch_span`."""
+
+    __slots__ = ("_annotation", "_tracer", "_phase", "_entry", "_span",
+                 "_c0")
+
+    def __init__(self, tracer, phase, entry):
+        self._annotation = _tracing.annotation("engine", phase)
+        self._tracer, self._phase, self._entry = tracer, phase, entry
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        # NOOP_SPAN by identity while the tracer is off
+        self._span = self._tracer.span("engine." + self._phase)
+        if self._span is not _tracing.NOOP_SPAN:
+            self._c0 = int(self._entry.compile_count)
+        return self
+
+    def __exit__(self, *exc):
+        if self._span is not _tracing.NOOP_SPAN:
+            c1 = int(self._entry.compile_count)
+            self._span.end(compile_count=c1, compiles=c1 - self._c0)
+        return self._annotation.__exit__(*exc)
+
+
 def prefill_buckets_for(max_len, min_bucket=16):
     """Power-of-two prefill buckets up to ``max_len`` (slotted mode); a
     non-power-of-two ``max_len`` is appended as the final bucket so every
@@ -1012,14 +1037,15 @@ class DecodeEngine:
             # train.grad_norm gauge)
             self._m_qerr.set(float(np.asarray(qerr)))
 
-    def _dispatch_span(self, name, entry, t0_ns, c0):
-        """Engine-lane span for one compiled-entry dispatch, carrying the
+    def _dispatch_span(self, phase, entry):
+        """Context around one compiled-entry dispatch: a host span on the
+        device's clock (``pt.engine.<phase>``,
+        :func:`observability.tracing.annotation`) and, when the request
+        tracer is on, the engine-lane span ``engine.<phase>`` carrying the
         watchdog's compile-count delta: a nonzero ``compiles`` attr on a
-        steady-state step IS the silent-retrace bug class, now visible
-        at the exact call in the trace timeline."""
-        c1 = int(entry.compile_count)
-        self._tracer.add_span(name, t0_ns, time.perf_counter_ns(),
-                              compile_count=c1, compiles=c1 - c0)
+        steady-state step IS the silent-retrace bug class, visible at the
+        exact call in the trace timeline."""
+        return _DispatchSpan(self._tracer, phase, entry)
 
     # -- paged page bookkeeping (host side) --------------------------------
 
@@ -1061,17 +1087,11 @@ class DecodeEngine:
         try:
             old_pid = int(self._alloc.table[int(slot), int(idx)])
             c = self.cache
-            tr_on = self._tracer.enabled
-            if tr_on:
-                c0 = self._cow.compile_count
-                t0_ns = time.perf_counter_ns()
-            with x64_scope(False), self._trace_scope():
+            with self._dispatch_span("cow_copy", self._cow), \
+                    x64_scope(False), self._trace_scope():
                 k, v, ks, vs = self._cow(c.k, c.v, c.k_scale, c.v_scale,
                                          jnp.asarray(old_pid, jnp.int32),
                                          jnp.asarray(new_pid, jnp.int32))
-            if tr_on:
-                self._dispatch_span("engine.cow_copy", self._cow, t0_ns,
-                                    c0)
         except Exception:
             # a torn COW dispatch must not strand the fresh page: the
             # pool outlives the failed step (the scheduler's tear paths
@@ -1186,16 +1206,13 @@ class DecodeEngine:
         final = task.pos + n_valid >= n
         key = (self._next_key() if final
                else jax.random.fold_in(self._base_key, 0))
-        tr_on = self._tracer.enabled
-        if tr_on:
-            c0 = self._prefill_chunk.compile_count
-            t0_ns = time.perf_counter_ns()
         # x64_scope(False) covers the (first-call) TRACE: the serving
         # programs carry no s64/f64 — jax.random's counters and gather
         # index widening follow the global x64 default otherwise (same
         # discipline as the Pallas kernel entries; asserted over the
         # compiled HLO by tests/test_serving.py)
-        with x64_scope(False), _eval_scope(self.model), \
+        with self._dispatch_span("prefill_chunk", self._prefill_chunk), \
+                x64_scope(False), _eval_scope(self.model), \
                 self._trace_scope():
             tok, logits, k, v, ks, vs, lengths = self._prefill_chunk(
                 self.state, jnp.asarray(padded),
@@ -1207,9 +1224,6 @@ class DecodeEngine:
                 jnp.asarray(task.temperature, jnp.float32),
                 jnp.asarray(min(task.top_k, self.top_k_max), jnp.int32),
                 jnp.asarray(task.top_p, jnp.float32))
-        if tr_on:
-            self._dispatch_span("engine.prefill_chunk",
-                                self._prefill_chunk, t0_ns, c0)
         self.cache = PagedKVCache(k, v, self._alloc.device_table(),
                                   lengths, k_scale=ks, v_scale=vs)
         task.pos += n_valid
@@ -1253,12 +1267,9 @@ class DecodeEngine:
         bucket = self.bucket_for(n)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = ids
-        tr_on = self._tracer.enabled
-        if tr_on:
-            c0 = self._prefill.compile_count
-            t0_ns = time.perf_counter_ns()
         # x64/eval scopes: see prefill_step()
-        with x64_scope(False), _eval_scope(self.model), \
+        with self._dispatch_span("prefill", self._prefill), \
+                x64_scope(False), _eval_scope(self.model), \
                 self._trace_scope():
             tok, logits, k, v, ks, vs, lengths = self._prefill(
                 self.state, jnp.asarray(padded),
@@ -1269,8 +1280,6 @@ class DecodeEngine:
                 jnp.asarray(temperature, jnp.float32),
                 jnp.asarray(min(int(top_k), self.top_k_max), jnp.int32),
                 jnp.asarray(top_p, jnp.float32))
-        if tr_on:
-            self._dispatch_span("engine.prefill", self._prefill, t0_ns, c0)
         self.cache = SlottedKVCache(k, v, lengths, k_scale=ks, v_scale=vs)
         return int(tok), logits
 
@@ -1312,13 +1321,10 @@ class DecodeEngine:
                 raise PagePoolExhausted(
                     "no free page for slot %d's append — evict a slot "
                     "(the scheduler does this refcount-aware)" % blocked)
-        tr_on = self._tracer.enabled
-        if tr_on:
-            c0 = self._decode.compile_count
-            t0_ns = time.perf_counter_ns()
         # x64/eval scopes: see prefill_step() — keep the traced program
         # s64/f64-free and the caller's train/eval mode untouched
-        with x64_scope(False), _eval_scope(self.model), \
+        with self._dispatch_span("decode", self._decode), \
+                x64_scope(False), _eval_scope(self.model), \
                 self._trace_scope():
             # both layouts share one call shape; paged inserts the page
             # table after lengths (donated argnums are identical)
@@ -1353,8 +1359,6 @@ class DecodeEngine:
             # the slotted read bound IS the flat slots*max_len sweep
             self.cache = SlottedKVCache(k, v, lengths,
                                         k_scale=ks, v_scale=vs)
-        if tr_on:
-            self._dispatch_span("engine.decode", self._decode, t0_ns, c0)
         if self._track_coll:
             # per-step collective bytes over the mesh (opt-in; priced
             # once from the compiled sharded program, then a constant)
@@ -1427,11 +1431,8 @@ class DecodeEngine:
             step_toks = np.concatenate([toks, drafts_np], axis=1)
             if self.mesh is not None:           # see _token_operand
                 step_toks = jax.device_put(step_toks, self._sh())
-        tr_on = self._tracer.enabled
-        if tr_on:
-            c0 = self._verify.compile_count
-            t0_ns = time.perf_counter_ns()
-        with x64_scope(False), _eval_scope(self.model), \
+        with self._dispatch_span("spec_verify", self._verify), \
+                x64_scope(False), _eval_scope(self.model), \
                 self._trace_scope():
             emitted, counts, logits, kk, v, ks, vs, lengths, qerr = \
                 self._verify(
@@ -1446,9 +1447,6 @@ class DecodeEngine:
                     jnp.asarray(np.asarray(top_p, np.float32)))
             self.cache = PagedKVCache(kk, v, self._alloc.device_table(),
                                       lengths, k_scale=ks, v_scale=vs)
-        if tr_on:
-            self._dispatch_span("engine.spec_verify", self._verify,
-                                t0_ns, c0)
         if self._track_coll:
             self._m_coll.inc(
                 self._collective_price("serving.spec_verify"))
@@ -1594,17 +1592,11 @@ class DecodeEngine:
         buf = getattr(self, buf_attr)
         if buf is None:
             buf = self._new_handoff_buf()
-        tr_on = self._tracer.enabled
-        if tr_on:
-            c0 = self._kv_export.compile_count
-            t0_ns = time.perf_counter_ns()
-        with x64_scope(False), self._trace_scope():
+        with self._dispatch_span("kv_export", self._kv_export), \
+                x64_scope(False), self._trace_scope():
             out = self._kv_export(self.cache.k, self.cache.v,
                                   *self._cache_scale_args(),
                                   *buf, jnp.asarray(ids))
-        if tr_on:
-            self._dispatch_span("engine.kv_export", self._kv_export,
-                                t0_ns, c0)
         setattr(self, buf_attr, list(out))
         return tuple(out)
 
@@ -1652,17 +1644,11 @@ class DecodeEngine:
         ids = np.full((self.handoff_pages,), self.num_pages, np.int32)
         ids[:n] = np.asarray(dst_page_ids, np.int32)
         c = self.cache
-        tr_on = self._tracer.enabled
-        if tr_on:
-            c0 = self._kv_import.compile_count
-            t0_ns = time.perf_counter_ns()
-        with x64_scope(False), self._trace_scope():
+        with self._dispatch_span("kv_import", self._kv_import), \
+                x64_scope(False), self._trace_scope():
             k, v, ks, vs = self._kv_import(
                 c.k, c.v, *self._cache_scale_args(), *bufs,
                 jnp.asarray(ids))
-        if tr_on:
-            self._dispatch_span("engine.kv_import", self._kv_import,
-                                t0_ns, c0)
         self.cache = PagedKVCache(k, v, c.page_table, c.lengths,
                                   k_scale=ks, v_scale=vs)
 

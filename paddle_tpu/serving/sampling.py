@@ -44,6 +44,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..observability import scopes as _scopes
+
 __all__ = ["sample", "apply_temperature", "apply_top_k", "apply_top_p",
            "filter_logits", "spec_accept", "TOP_K_MAX"]
 
@@ -121,6 +123,11 @@ def sample(logits, key, temperature, top_k, top_p, k_max=TOP_K_MAX):
     step; temperature/top_p: (slots,) float; top_k: (slots,) int32
     (<= 0 disables).  Returns (slots,) int32 token ids.
     """
+    with _scopes.scope(_scopes.SAMPLE):
+        return _sample(logits, key, temperature, top_k, top_p, k_max)
+
+
+def _sample(logits, key, temperature, top_k, top_p, k_max):
     greedy_tok = _int32_argmax(logits)
     filtered = filter_logits(logits, temperature, top_k, top_p, k_max)
     # Gumbel-max categorical: argmax(logits + G) ~ softmax(logits); the

@@ -930,102 +930,103 @@ class ContinuousBatchingScheduler:
         retirements may free pages, and an eviction victim must never
         carry an undrained step), then evicts refcount-aware.  Returns
         the in-flight record, or None when nothing is active."""
-        spec_k = int(getattr(self.engine, "spec_k", 0))
-        active = self._active_mask()
-        if not any(active):
-            return None
-        if self.engine.paged:
-            # pre-step page bookkeeping: every append (k+1 of them per
-            # slot for a verify step) needs a mapped private page.  A
-            # verify step's advance is data-dependent, so while one is
-            # unconsumed the engine mirror lags it — cover BOTH steps'
-            # worst case (non-spec steps advance the mirror at dispatch:
-            # no slack needed).
-            while True:
-                slack = (spec_k + 1
-                         if spec_k and self._inflight is not None else 0)
-                blocked = self.engine.ensure_decode_ready(
-                    active, steps=spec_k + 1 + slack)
-                if blocked is None:
-                    break
-                if self._drain_inflight():
-                    active = self._active_mask()
+        with _tracing.annotation("sched", "decode_dispatch"):
+            spec_k = int(getattr(self.engine, "spec_k", 0))
+            active = self._active_mask()
+            if not any(active):
+                return None
+            if self.engine.paged:
+                # pre-step page bookkeeping: every append (k+1 of them per
+                # slot for a verify step) needs a mapped private page.  A
+                # verify step's advance is data-dependent, so while one is
+                # unconsumed the engine mirror lags it — cover BOTH steps'
+                # worst case (non-spec steps advance the mirror at dispatch:
+                # no slack needed).
+                while True:
+                    slack = (spec_k + 1
+                             if spec_k and self._inflight is not None else 0)
+                    blocked = self.engine.ensure_decode_ready(
+                        active, steps=spec_k + 1 + slack)
+                    if blocked is None:
+                        break
+                    if self._drain_inflight():
+                        active = self._active_mask()
+                    else:
+                        self._evict_for_pages(blocked)
+                        active = self._active_mask()
+                    if not any(active):
+                        return None
+            S = self.engine.num_slots
+            tokens = np.zeros((S,), np.int32)
+            fresh = np.zeros((S,), bool)
+            temps = np.ones((S,), np.float32)
+            top_ks = np.zeros((S,), np.int32)
+            top_ps = np.ones((S,), np.float32)
+            drafts = np.zeros((S, max(spec_k, 1)), np.int32)
+            prev = self._inflight
+            if prev is not None and prev.rec.consumed:
+                prev = None
+            for i, act in enumerate(self.slots):
+                if not active[i]:
+                    continue
+                if (prev is None or not prev.rec.active[i]
+                        or prev.lane_acts[i] is not act):
+                    # no in-flight step holds this lane's next token: feed
+                    # the host-known last token (first dispatch, a fresh
+                    # prefill, or a drained pipeline)
+                    tokens[i] = act.generated[-1]
+                    fresh[i] = True
+                temps[i] = act.req.temperature
+                top_ks[i] = act.req.top_k
+                top_ps[i] = act.req.top_p
+                if spec_k:
+                    # self-speculative prompt-lookup draft over the slot's
+                    # OWN history — host-side, zero model FLOPs; a miss just
+                    # pads (the verify step then emits one token, like
+                    # decode).  With a step in flight the history lags by
+                    # its unconsumed emit — draft quality moves throughput,
+                    # never correctness (greedy accept is history-free).
+                    hist = np.concatenate(
+                        [act.req.prompt,
+                         np.asarray(act.generated, np.int32)])
+                    drafts[i], _hit = _propose_draft(
+                        hist, spec_k, getattr(self.engine, "spec_ngram", 3))
+            if prev is not None and not bool(fresh.all()):
+                # thread the in-flight step's sampled tokens on DEVICE: for
+                # a verify step the last committed token of lane i is
+                # emitted[i, counts[i]-1] (an eager gather on futures)
+                import jax.numpy as jnp
+                if prev.rec.kind == "spec":
+                    prev_last = jnp.take_along_axis(
+                        prev.rec.emitted,
+                        jnp.maximum(prev.rec.counts, 1)[:, None] - 1,
+                        axis=1)[:, 0]
                 else:
-                    self._evict_for_pages(blocked)
-                    active = self._active_mask()
-                if not any(active):
-                    return None
-        S = self.engine.num_slots
-        tokens = np.zeros((S,), np.int32)
-        fresh = np.zeros((S,), bool)
-        temps = np.ones((S,), np.float32)
-        top_ks = np.zeros((S,), np.int32)
-        top_ps = np.ones((S,), np.float32)
-        drafts = np.zeros((S, max(spec_k, 1)), np.int32)
-        prev = self._inflight
-        if prev is not None and prev.rec.consumed:
-            prev = None
-        for i, act in enumerate(self.slots):
-            if not active[i]:
-                continue
-            if (prev is None or not prev.rec.active[i]
-                    or prev.lane_acts[i] is not act):
-                # no in-flight step holds this lane's next token: feed
-                # the host-known last token (first dispatch, a fresh
-                # prefill, or a drained pipeline)
-                tokens[i] = act.generated[-1]
-                fresh[i] = True
-            temps[i] = act.req.temperature
-            top_ks[i] = act.req.top_k
-            top_ps[i] = act.req.top_p
-            if spec_k:
-                # self-speculative prompt-lookup draft over the slot's
-                # OWN history — host-side, zero model FLOPs; a miss just
-                # pads (the verify step then emits one token, like
-                # decode).  With a step in flight the history lags by
-                # its unconsumed emit — draft quality moves throughput,
-                # never correctness (greedy accept is history-free).
-                hist = np.concatenate(
-                    [act.req.prompt,
-                     np.asarray(act.generated, np.int32)])
-                drafts[i], _hit = _propose_draft(
-                    hist, spec_k, getattr(self.engine, "spec_ngram", 3))
-        if prev is not None and not bool(fresh.all()):
-            # thread the in-flight step's sampled tokens on DEVICE: for
-            # a verify step the last committed token of lane i is
-            # emitted[i, counts[i]-1] (an eager gather on futures)
-            import jax.numpy as jnp
-            if prev.rec.kind == "spec":
-                prev_last = jnp.take_along_axis(
-                    prev.rec.emitted,
-                    jnp.maximum(prev.rec.counts, 1)[:, None] - 1,
-                    axis=1)[:, 0]
+                    prev_last = prev.rec.tok
+                tok_in = (jnp.where(jnp.asarray(fresh), jnp.asarray(tokens),
+                                    prev_last)
+                          if bool(fresh.any()) else prev_last)
             else:
-                prev_last = prev.rec.tok
-            tok_in = (jnp.where(jnp.asarray(fresh), jnp.asarray(tokens),
-                                prev_last)
-                      if bool(fresh.any()) else prev_last)
-        else:
-            tok_in = tokens
-        # host-gap accounting: with nothing in flight, the whole window
-        # since the last fetch starved the device (the sync loop pays
-        # this every step; the overlapped loop only on true bubbles)
-        t0_ns = time.perf_counter_ns()
-        if self._outstanding == 0 and self._last_fetch_ns is not None:
-            self.host_gap_seconds += (t0_ns - self._last_fetch_ns) * 1e-9
-        if spec_k:
-            rec = self.engine.decode_spec_submit(
-                tok_in, drafts, active, temps, top_ks, top_ps,
-                pages_ready=True)
-        else:
-            rec = self.engine.decode_submit(tok_in, active, temps,
-                                            top_ks, top_ps,
-                                            pages_ready=True)
-        self._outstanding += 1
-        return _Inflight(rec=rec,
-                         lane_acts=[self.slots[i] if active[i] else None
-                                    for i in range(S)],
-                         t0_ns=t0_ns)
+                tok_in = tokens
+            # host-gap accounting: with nothing in flight, the whole window
+            # since the last fetch starved the device (the sync loop pays
+            # this every step; the overlapped loop only on true bubbles)
+            t0_ns = time.perf_counter_ns()
+            if self._outstanding == 0 and self._last_fetch_ns is not None:
+                self.host_gap_seconds += (t0_ns - self._last_fetch_ns) * 1e-9
+            if spec_k:
+                rec = self.engine.decode_spec_submit(
+                    tok_in, drafts, active, temps, top_ks, top_ps,
+                    pages_ready=True)
+            else:
+                rec = self.engine.decode_submit(tok_in, active, temps,
+                                                top_ks, top_ps,
+                                                pages_ready=True)
+            self._outstanding += 1
+            return _Inflight(rec=rec,
+                             lane_acts=[self.slots[i] if active[i] else None
+                                        for i in range(S)],
+                             t0_ns=t0_ns)
 
     def _consume_inflight(self, infl: _Inflight) -> int:
         """Consume one dispatched step: fetch its sampled tokens (the
@@ -1039,79 +1040,82 @@ class ContinuousBatchingScheduler:
         stays exact without a rollback program."""
         rec = infl.rec
         spec_k = self.engine.spec_k if rec.kind == "spec" else 0
-        if rec.kind == "spec":
-            emitted, counts, _logits = self.engine.decode_spec_fetch(rec)
-        else:
-            next_tok, _logits = self.engine.decode_fetch(rec)
-        t1_ns = time.perf_counter_ns()
-        self._outstanding -= 1
-        self._last_fetch_ns = t1_ns
-        self.decode_steps_total += 1
-        # the step interval: clipped at the previous consume so
-        # consecutive overlapped steps never double-charge wall time
-        # (per-request decode_s must sum to drain wall, not 2x it);
-        # feeds the histogram AND every involved request's trace span,
-        # so trace-report TPOT reproduces the metric exactly
-        t0_ns = (infl.t0_ns if self._last_step_end_ns is None
-                 else max(infl.t0_ns, self._last_step_end_ns))
-        self._last_step_end_ns = t1_ns
-        step_s = (t1_ns - t0_ns) * 1e-9
-        t1 = t1_ns * 1e-9                      # last_t bookkeeping
-        n = 0
-        spec_prop = spec_acc = 0               # per-ITERATION counter incs
-        for i, act in enumerate(self.slots):
-            if (not rec.active[i] or act is None
-                    or infl.lane_acts[i] is not act):
-                continue               # retired/preempted/cancelled since
-            if spec_k:
-                raw = int(counts[i])
-                emit = [int(t) for t in emitted[i, :raw]]
-                act.spec_proposed += spec_k
-                act.spec_accepted += len(emit) - 1
-                spec_prop += spec_k
-                spec_acc += len(emit) - 1
-                # mirror the program's finalize: the device committed
-                # `raw` rows for this lane (clamped in-program)
-                act.cache_len = min(act.cache_len + raw,
-                                    self.engine.max_len)
-                # truncate at the budget and at EOS — both retire the
-                # slot in _check_finished, so a truncated host token
-                # list never belongs to a live (still-decoding) slot
-                room = act.req.max_new_tokens - len(act.generated)
-                emit = emit[:max(room, 0)]
-                if act.req.eos_token_id is not None:
-                    eos = int(act.req.eos_token_id)
-                    if eos in emit:
-                        emit = emit[:emit.index(eos) + 1]
+        # the wait that ends host_gap_seconds
+        with _tracing.annotation("sched", "fetch"):
+            if rec.kind == "spec":
+                emitted, counts, _logits = self.engine.decode_spec_fetch(rec)
             else:
-                emit = [int(next_tok[i])]
-                act.cache_len = min(act.cache_len + 1,
-                                    self.engine.max_len)
-            act.generated.extend(emit)
-            act.decode_s += step_s
-            act.decode_steps += len(emit)   # TPOT = secs per token
-            act.last_t = t1
-            n += len(emit)
-            self._notify_tokens(act.req.rid, emit)
-            if self._tron:
-                # one span per involved request per iteration, stamped
-                # with the shared step interval; `tokens` is the
-                # decode-committed count (post-truncation), matching the
-                # TPOT accounting exactly
-                self._tracer.add_span(
-                    "spec_verify" if spec_k else "decode", t0_ns, t1_ns,
-                    parent=self._req_spans.get(act.req.rid),
-                    tokens=len(emit))
-            self._check_finished(i)
-        # per-ITERATION metrics (not per token): one histogram observe,
-        # one counter inc, one gauge set per batched step
-        self._m_decode_step.observe(step_s)
-        self._m_tokens.inc(n)
-        if spec_prop:
-            self._m_spec_prop.inc(spec_prop)
-            self._m_spec_acc.inc(spec_acc)
-        self._m_occupancy.set(sum(a is not None for a in self.slots))
-        return n
+                next_tok, _logits = self.engine.decode_fetch(rec)
+        t1_ns = time.perf_counter_ns()
+        with _tracing.annotation("sched", "deliver"):
+            self._outstanding -= 1
+            self._last_fetch_ns = t1_ns
+            self.decode_steps_total += 1
+            # the step interval: clipped at the previous consume so
+            # consecutive overlapped steps never double-charge wall time
+            # (per-request decode_s must sum to drain wall, not 2x it);
+            # feeds the histogram AND every involved request's trace span,
+            # so trace-report TPOT reproduces the metric exactly
+            t0_ns = (infl.t0_ns if self._last_step_end_ns is None
+                     else max(infl.t0_ns, self._last_step_end_ns))
+            self._last_step_end_ns = t1_ns
+            step_s = (t1_ns - t0_ns) * 1e-9
+            t1 = t1_ns * 1e-9                      # last_t bookkeeping
+            n = 0
+            spec_prop = spec_acc = 0               # per-ITERATION counter incs
+            for i, act in enumerate(self.slots):
+                if (not rec.active[i] or act is None
+                        or infl.lane_acts[i] is not act):
+                    continue               # retired/preempted/cancelled since
+                if spec_k:
+                    raw = int(counts[i])
+                    emit = [int(t) for t in emitted[i, :raw]]
+                    act.spec_proposed += spec_k
+                    act.spec_accepted += len(emit) - 1
+                    spec_prop += spec_k
+                    spec_acc += len(emit) - 1
+                    # mirror the program's finalize: the device committed
+                    # `raw` rows for this lane (clamped in-program)
+                    act.cache_len = min(act.cache_len + raw,
+                                        self.engine.max_len)
+                    # truncate at the budget and at EOS — both retire the
+                    # slot in _check_finished, so a truncated host token
+                    # list never belongs to a live (still-decoding) slot
+                    room = act.req.max_new_tokens - len(act.generated)
+                    emit = emit[:max(room, 0)]
+                    if act.req.eos_token_id is not None:
+                        eos = int(act.req.eos_token_id)
+                        if eos in emit:
+                            emit = emit[:emit.index(eos) + 1]
+                else:
+                    emit = [int(next_tok[i])]
+                    act.cache_len = min(act.cache_len + 1,
+                                        self.engine.max_len)
+                act.generated.extend(emit)
+                act.decode_s += step_s
+                act.decode_steps += len(emit)   # TPOT = secs per token
+                act.last_t = t1
+                n += len(emit)
+                self._notify_tokens(act.req.rid, emit)
+                if self._tron:
+                    # one span per involved request per iteration, stamped
+                    # with the shared step interval; `tokens` is the
+                    # decode-committed count (post-truncation), matching the
+                    # TPOT accounting exactly
+                    self._tracer.add_span(
+                        "spec_verify" if spec_k else "decode", t0_ns, t1_ns,
+                        parent=self._req_spans.get(act.req.rid),
+                        tokens=len(emit))
+                self._check_finished(i)
+            # per-ITERATION metrics (not per token): one histogram observe,
+            # one counter inc, one gauge set per batched step
+            self._m_decode_step.observe(step_s)
+            self._m_tokens.inc(n)
+            if spec_prop:
+                self._m_spec_prop.inc(spec_prop)
+                self._m_spec_acc.inc(spec_acc)
+            self._m_occupancy.set(sum(a is not None for a in self.slots))
+            return n
 
     def _drain_inflight(self) -> bool:
         """Consume the in-flight step now, if any (page pressure, a
@@ -1157,9 +1161,11 @@ class ContinuousBatchingScheduler:
 
     def _step_inner(self) -> int:
         self._drained_n = 0
-        self.admit()
-        self._fetch_advance()
-        self.prefill_once()
+        with _tracing.annotation("sched", "admit"):
+            self.admit()
+            self._fetch_advance()
+        with _tracing.annotation("sched", "prefill_dispatch"):
+            self.prefill_once()
         if self.overlap:
             prev = self._inflight
             nxt = self._dispatch_decode()   # threads prev's device toks
