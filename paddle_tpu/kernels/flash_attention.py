@@ -44,6 +44,20 @@ _BATCH_AXES = ("dp", "sdp")
 _HEAD_AXES = ("mp",)
 
 
+def note_score_elements(computed: int, causal: int) -> None:
+    """Drive ``flash.score_elements{which}`` at trace time — one inc per
+    causal kernel call traced, valued over all its heads (a compile-once
+    program contributes once, like ``mp.overlap_chunks``).  Called by the
+    Pallas module, which must stay registry-free itself."""
+    try:
+        from ..observability import registry as _reg
+        ctr = _reg.counter("flash.score_elements", ("which",))
+        ctr.labels(which="computed").inc(int(computed))
+        ctr.labels(which="causal").inc(int(causal))
+    except Exception:
+        pass
+
+
 def _active_mesh():
     """The global mesh the GSPMD program is being traced for, or None for
     a one-device program and inside a shard_map (manual axes: the caller

@@ -10,14 +10,30 @@ viewed as (B, S, H*D).  Head groups are a GRID dimension over the folded
 H*D axis (`hg` heads per cell so hg*D is lane-aligned, i.e. % 128), and the
 per-head attention math runs as a static loop inside the cell.  This
 removes the six (B,S,H,D) <-> (B,H,S,D) transposes per layer that a
-head-major kernel forces around every call — measured ~9 ms/step of pure
-HBM copies on the GPT-2 345M bench (PERF.md).
+head-major kernel forces around every call (history, retired set-up:
+about 9 ms a step of HBM copies on GPT-2 345M).
 
 Forward: grid (B, n_hg, nq); the whole K/V sequence stays VMEM-resident and
-is scanned with fori loops (measured faster at these shapes than streaming
-K/V blocks through the grid — the extra grid steps only added overhead).
-Causal q-blocks split the scan into mask-free fully-visible blocks and the
-masked diagonal band.
+is scanned with a fori loop over the fully-visible k blocks (no mask
+arithmetic), the causal band behind it.  Sequences whose K/V do not fit
+take the grid-streamed forward; ``pipelined`` streams K/V itself.
+
+The causal band (PR 26).  A diagonal block is never computed whole: it is
+cut into static ``t x t`` sub-tiles (``_band_tile``: 256, or what divides
+the block) and walked with Python-static loops and static ref slices
+(``_live_tiles`` is the one definition of which tiles are live,
+``_band_runs`` the walk every causal kernel shares).  Sub-tiles strictly
+above the diagonal emit nothing; each t-row sub-block takes its visible
+prefix and its own diagonal tile in ONE step, the triangle applied to the
+step's last t columns only (``_mask_tail``).  Row and column offsets
+cancel on the diagonal, so that triangle is one constant of the kernel:
+no mask depends on ``program_id``.  When block_q == block_k == t the walk
+is one masked step, the band as it was.  Non-causal calls and fully
+visible blocks lower to the same kernel body as before.  What the chip
+said (v5e, 16 x 1,024 x 16 heads of 64, PERF.md section 6): steps cost
+beside elements — t = 256 beats t = 128 though it computes more — the
+forward is a third shorter, the merged backward unchanged: its diagonal
+cells take the same time for three quarters of the elements.
 
 Backward is ONE merged kernel producing dQ, dK and dV: the textbook
 two-kernel FlashAttention-2 split recomputes the logits and dP matmuls
@@ -25,26 +41,28 @@ twice; merging halves that recompute and saves a launch per layer.
 Grid = (B, n_hg, nk, nq) with both inner dims sequential: dK/dV accumulate
 per key block in scratch (reset at qi==0), dQ accumulates across the whole
 (nk, nq) sweep in a full-sequence f32 scratch written at the final step.
-Causal masking skips fully-masked blocks via pl.when (no MXU/VPU work; the
-static grid still streams the prefetch, which is the price of pipelining).
-A fori-style backward (K/V outer, q scanned inside) was measured SLOWER
-(47.6k vs 49.6k tokens/s on the 345M bench) — fwd and bwd optimum differ.
+``_causal_cells`` classifies a grid cell: strictly future (no MXU/VPU
+work; the static grid still streams the prefetch, which is the price of
+pipelining), fully visible (whole, unmasked) or one of the block_q //
+block_k band cells (its live runs of sub-tiles).  Sequences whose dq
+scratch does not fit take the split dq / dkv kernels, same walk.
+(History, retired set-up: a fori-style backward, K/V outer and q scanned
+inside, was slower, 47.6k against 49.6k tokens/s on the 345M bench.)
 
-Variants (round 6): every kernel family is registered with the autotuner
-(kernels/autotune.py) and the softmax/mask/pipeline machinery is variant-
-selectable — the hand-tuned round-5 configuration is the "base" variant and
+Variants: every kernel family is registered with the autotuner
+(kernels/autotune.py) and the softmax/pipeline machinery is variant-
+selectable — the hand-tuned configuration is the "base" variant and
 the default, so nothing changes until tuning runs or a config is pinned:
 
 - ``bf16chain``: the streaming-softmax elementwise chain (mask select,
-  running max, exp2, p) runs in bf16 — the VPU's 2x-throughput dtype — with
-  the max/sum-exp2/correction STATISTICS still accumulated in f32, and p
-  feeding the MXU in bf16 without the separate f32->bf16 cast.  Targets
-  the 39 ms attention VPU chain directly (PERF.md "structural" item 1).
-- ``iotafree``: causal band blocks classify visibility with ONE compare of
-  a compile-time (BQ, BK) column-minus-row constant against the scalar
-  block offset, replacing the two per-element broadcasted_iota builds +
-  adds + compare — extends the round-5 causal-split win (which removed
-  mask arithmetic from fully-visible blocks) into the band blocks.
+  running max, exp2, p) runs in bf16 with the max/sum-exp2/correction
+  STATISTICS still accumulated in f32, and p feeding the MXU in bf16
+  without the separate f32->bf16 cast.  (The v5e's VPU has no bf16
+  arithmetic to make it pay; it lowers the chain's precision.)
+- ``iotafree``: absorbed by the sub-tiled band — it made the band mask
+  one compare of a constant matrix against the block offset; the band
+  mask is now a constant outright.  The name is still accepted (pins,
+  caches, the autotuner's candidate lists) and selects nothing.
 - ``parq`` (fwd, resident path): per-q-block lse output blocks instead of
   the revisited whole-sequence lse slice, which lets all three grid dims
   carry "parallel" dimension_semantics.
@@ -55,7 +73,9 @@ the default, so nothing changes until tuning runs or a config is pinned:
   granularity.
 
 All variants have interpret-mode parity tests vs the O(S^2) reference
-(tests/test_flash_variants.py).
+(tests/test_flash_variants.py); tests/test_flash_tpu_compile.py compiles
+the main path for a described v5e (Mosaic refuses what the interpreter
+accepts).
 """
 from __future__ import annotations
 
@@ -91,6 +111,10 @@ def _block_env(name, default):
 DEFAULT_BLOCK_Q = _block_env("PADDLE_TPU_FLASH_BLOCK_Q", 512)
 DEFAULT_BLOCK_K = _block_env("PADDLE_TPU_FLASH_BLOCK_K", 512)
 _NEG_INF = -1e30
+#: largest edge of the causal band's sub-tiles (_band_tile is the rule):
+#: 256 against 128 measured on the v5e at (s, d) = (1,024, 64) and (2,048,
+#: 128) — fewer, wider steps beat fewer score elements (PERF.md, PR 26)
+_BAND_TILE = 256
 # The streaming softmax runs in BASE 2: folding log2(e) into the logits
 # scale turns every exp into the VPU's native exp2 (jnp.exp lowers to
 # exp2 + a multiply per element, and the softmax exp over b*h*s^2 logits
@@ -101,10 +125,6 @@ _LOG2E = 1.4426950408889634
 
 _SEQ2 = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"))
-
-#: A/B flag: mask the causal band by multiplying p after exp2 (max over
-#: unmasked logits) instead of the -inf select before it
-_BAND_MUL = os.getenv("PADDLE_TPU_FLASH_BANDMUL", "0") == "1"
 
 #: variant features understood by the forward / backward kernels
 _FWD_FEATURES = frozenset({"bf16chain", "iotafree", "parq", "pipelined"})
@@ -264,38 +284,115 @@ def max_supported_seq(h: int, d: int) -> int:
 # shared per-block math (variant-selectable)
 # ---------------------------------------------------------------------------
 
-def _band_diff(block_q: int, block_k: int):
-    """(BQ, BK) column-minus-row index matrix for the iotafree band mask:
-    vis[i, j] = (col0 + j <= row0 + i) = (j - i <= row0 - col0), so a band
-    block's whole mask is ONE compare of this (block-independent) matrix
-    against the scalar block offset.  Built from in-kernel iotas — Pallas
-    rejects captured host constants — but hoisted out of
-    the per-k-block loop by the callers (and loop-invariant for Mosaic),
-    unlike the base path's per-block row_ids/col_ids builds."""
-    return jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) - \
-        jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+def _band_tile(block_k: int) -> int:
+    """Edge ``t`` of the square sub-tiles a causal band cell is cut into —
+    THE one rule.  ``t`` divides ``block_k`` (and so ``block_q``: causal
+    blocks have block_q % block_k == 0); a block no candidate divides
+    stays whole (one masked tile, the band before it was sub-tiled).  Head
+    size and dtype do not enter: 256 won at d = 64 and at d = 128."""
+    for t in (_BAND_TILE, 128):
+        if block_k % t == 0:
+            return t
+    return block_k
 
 
-def _cell_vis(row0, col0, block_q, block_k, iotafree):
-    """Causal visibility mask for the (row0, col0) block (scalars are the
-    absolute first row/col of the block)."""
-    if iotafree:
-        return _band_diff(block_q, block_k) <= (row0 - col0)
-    row_ids = row0[None, None] + \
-        jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    col_ids = col0[None, None] + \
-        jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    return col_ids <= row_ids
+def _live_tiles(block_q: int, block_k: int, t: int, off: int):
+    """``[(r, c, masked)]``: the ``t x t`` sub-tiles of a (block_q,
+    block_k) causal cell that hold ANY visible score, where the cell's
+    first row sits ``off = row0 - col0`` columns right of its first column
+    (vis[i, j] = j <= i + off).  THE one definition of which tiles are
+    live: tiles strictly above the diagonal are absent, tiles strictly
+    below are unmasked, tiles the diagonal crosses are ``masked``.  With
+    ``off`` a multiple of ``t`` (every caller's case) a masked tile's mask
+    is the same lower triangle whatever the block or grid cell."""
+    assert off % t == 0 and block_q % t == 0 and block_k % t == 0
+    out = []
+    for r in range(block_q // t):
+        first_row, last_row = r * t + off, r * t + t - 1 + off
+        for c in range(block_k // t):
+            if c * t > last_row:
+                continue                  # every column is in the future
+            out.append((r, c, c * t + t - 1 > first_row))
+    return out
 
 
-def _online_step(q, k, v, m, l, acc, vis, scale, bf16chain, band_mul=False):
+def _band_runs(block_q: int, block_k: int, t: int, off: int):
+    """:func:`_live_tiles` as ``[(rows, cols, masked)]`` static slices
+    into the cell: the live tiles of one tile row merged into a single
+    ``t x (n*t)`` rectangle — one wide matmul and one rescale a row, the
+    step count being what the kernels pay for beside the elements —
+    ``masked`` when its LAST ``t`` columns are the row's diagonal tile
+    (:func:`_mask_tail` applies the triangle there)."""
+    last = {}       # a row's tiles come in column order: keep the last
+    for r, c, masked in _live_tiles(block_q, block_k, t, off):
+        last[r] = (c, masked)
+    return [(slice(r * t, (r + 1) * t), slice(0, (c + 1) * t), masked)
+            for r, (c, masked) in last.items()]
+
+
+def _mask_tail(x, vis, fill):
+    """``x`` (R, W) with its last ``t`` columns under the (R, t) triangle
+    ``vis``: hidden elements become ``fill``, the columns before the
+    diagonal tile pass untouched (no mask arithmetic there)."""
+    t = vis.shape[1]
+    if x.shape[1] == t:
+        return jnp.where(vis, x, fill)
+    return jnp.concatenate(
+        [x[:, :-t], jnp.where(vis, x[:, -t:], fill)], axis=1)
+
+
+def _tri_vis(t: int):
+    """The masked tile's visibility, ``col <= row`` on a ``t x t`` tile: a
+    constant of the kernel (row and column offsets cancel on the
+    diagonal), built from in-kernel iotas because Pallas rejects captured
+    host constants."""
+    return jax.lax.broadcasted_iota(jnp.int32, (t, t), 1) <= \
+        jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+
+
+def _causal_cells(compute, causal, qi, ki, block_q, block_k, t):
+    """Run ``compute(rows, cols, vis)`` over what grid cell (qi, ki) must
+    attend: the whole cell unmasked (non-causal, or strictly below the
+    diagonal), nothing (strictly future), or — in one of the block_q //
+    block_k band cells — each live run of sub-tiles, ``vis`` the constant
+    triangle on the diagonal tiles and None elsewhere.  The band cell's
+    offset is static per branch, so the walk is Python-static."""
+    whole = (slice(0, block_q), slice(0, block_k))
+    if not causal:
+        compute(*whole, None)
+        return
+    ratio = block_q // block_k
+    # < 0: fully visible; [0, ratio): the band; >= ratio: strictly future
+    j = ki - jax.lax.mul(qi, _i32(ratio))
+    pl.when(j < 0)(lambda: compute(*whole, None))
+    for jj in range(ratio):
+        def band(jj=jj):
+            tri = _tri_vis(t)
+            for rows, cols, masked in _band_runs(block_q, block_k, t,
+                                                 -jj * block_k):
+                compute(rows, cols, tri if masked else None)
+        pl.when(j == jj)(band)
+
+
+def score_elements(s: int, block_q: int, t: int):
+    """(computed, causal) score elements a head of one causal call costs:
+    what the forward kernel's block and sub-tile walk computes — whole
+    blocks below the diagonal plus the live sub-tiles of each diagonal
+    block — against the s*(s+1)/2 the mask needs."""
+    nq = s // block_q
+    live = len(_live_tiles(block_q, block_q, t, 0))
+    return (nq * (nq - 1) // 2 * block_q * block_q + nq * live * t * t,
+            s * (s + 1) // 2)
+
+
+def _online_step(q, k, v, m, l, acc, vis, scale, bf16chain):
     """One streaming-softmax accumulation over a K/V block.
 
     (m, l, acc) are the running f32 statistics; ``vis`` is None (unmasked
-    block) or the (BQ, BK) visibility mask; ``band_mul`` applies vis by
-    multiplying p AFTER the exp2 instead of the -inf select before it.
-    bf16chain runs the elementwise chain (select, exp2, p) in bf16 with
-    f32 statistics — p then feeds the MXU without a separate cast.
+    block) or the visibility mask of the block's last ``vis.shape[1]``
+    columns (:func:`_mask_tail`).  bf16chain runs the
+    elementwise chain (select, exp2, p) in bf16 with f32 statistics — p
+    then feeds the MXU without a separate cast.
     """
     # bf16 x bf16 -> f32 is the MXU's native mode; upcasting operands
     # first quarters matmul throughput
@@ -304,23 +401,16 @@ def _online_step(q, k, v, m, l, acc, vis, scale, bf16chain, band_mul=False):
         preferred_element_type=jnp.float32) * jnp.float32(scale * _LOG2E)
     if bf16chain:
         lb = logits.astype(jnp.bfloat16)
-        if vis is not None and not band_mul:
-            lb = jnp.where(vis, lb, jnp.bfloat16(_NEG_INF))
-        # band_mul: run the max over UNMASKED logits (an over-estimate only
-        # shrinks p — lse stays exact) and zero the future columns AFTER
-        # the exp2 with one multiply, replacing the -inf select
+        if vis is not None:
+            lb = _mask_tail(lb, vis, jnp.bfloat16(_NEG_INF))
         new_m = jnp.maximum(m, jnp.max(lb, axis=-1).astype(jnp.float32))
         p = jnp.exp2(lb - new_m.astype(jnp.bfloat16)[:, None])
-        if vis is not None and band_mul:
-            p = p * vis.astype(jnp.bfloat16)
         psum = jnp.sum(p, axis=-1, dtype=jnp.float32)
     else:
-        if vis is not None and not band_mul:
-            logits = jnp.where(vis, logits, jnp.float32(_NEG_INF))
+        if vis is not None:
+            logits = _mask_tail(logits, vis, jnp.float32(_NEG_INF))
         new_m = jnp.maximum(m, jnp.max(logits, axis=-1))
         p = jnp.exp2(logits - new_m[:, None])
-        if vis is not None and band_mul:
-            p = p * vis.astype(jnp.float32)
         psum = jnp.sum(p, axis=-1)
     correction = jnp.exp2(m - new_m)
     new_l = l * correction + psum
@@ -342,11 +432,11 @@ def _bwd_head_math(q, k, v, do, lse, delta, vis, scale, bf16chain,
     if bf16chain:
         p = jnp.exp2((logits - lse[:, None]).astype(jnp.bfloat16))
         if vis is not None:
-            p = jnp.where(vis, p, jnp.bfloat16(0.0))
+            p = _mask_tail(p, vis, jnp.bfloat16(0.0))
     else:
         p = jnp.exp2(logits - lse[:, None])
         if vis is not None:
-            p = jnp.where(vis, p, jnp.float32(0.0))
+            p = _mask_tail(p, vis, jnp.float32(0.0))
     out = {}
     if want_dkv:
         pc = p.astype(do.dtype)
@@ -378,79 +468,100 @@ def _bwd_head_math(q, k, v, do, lse, delta, vis, scale, bf16chain,
 # forward
 # ---------------------------------------------------------------------------
 
+def _split_rows(m, l, acc, t):
+    """Whole-block (m, l, acc) -> ``{first_row: (m, l, acc)}`` per t-row
+    sub-block: the band advances each sub-block on its own."""
+    return {r0: (m[r0:r0 + t], l[r0:r0 + t], acc[r0:r0 + t])
+            for r0 in range(0, m.shape[0], t)}
+
+
+def _band_steps(st, q_of, kv_of, runs, tri, scale, bf16chain):
+    """Advance the per-sub-block states ``st`` over the live ``runs`` of a
+    band cell; ``q_of(rows)`` / ``kv_of(cols)`` load the operands."""
+    for rows, cols, masked in runs:
+        k, v = kv_of(cols)
+        st[rows.start] = _online_step(
+            q_of(rows), k, v, *st[rows.start], tri if masked else None,
+            scale, bf16chain)
+
+
+def _fwd_finish(st, o_ref, sl, lse_ref, lse_idx):
+    """Normalise the finished sub-block states of one head into its
+    output rows and its lse row (base-2 units: m is already log2-scaled).
+    The lse pieces leave in ONE store: Mosaic has no (1, t) store at a
+    lane offset into a dynamically indexed row."""
+    lse_rows = []
+    for r0, (m, l, acc) in st.items():
+        l_safe = jnp.maximum(l, jnp.float32(1e-30))
+        o_ref[0, r0:r0 + m.shape[0], sl] = \
+            (acc / l_safe[:, None]).astype(o_ref.dtype)
+        lse_rows.append(
+            (m + jnp.log(l_safe) * jnp.float32(_LOG2E))[None, :])
+    lse_ref[lse_idx] = lse_rows[0] if len(lse_rows) == 1 else \
+        jnp.concatenate(lse_rows, axis=1)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale, hg,
-                d, block_k, bf16chain=False, iotafree=False, parq=False):
+                d, block_k, tile, bf16chain=False, parq=False):
     # q/o: (1, BQ, HG*D); k/v: (1, S, HG*D) — the WHOLE sequence resident
-    # in VMEM, scanned with a fori loop (measured faster than grid-streamed
-    # K/V blocks at these shapes: the pipeline only added grid overhead);
+    # in VMEM, scanned with a fori loop (faster than grid-streamed K/V
+    # blocks at the bench shapes: the pipeline only added grid overhead);
     # lse: (1, 1, HG, NQ, BQ) — or per-q-block (1, 1, 1, HG, BQ) under parq
     # (q-block-major, so the block's last two dims are the whole (HG, BQ)
     # tile Mosaic requires; the wrapper swaps it back).
     block_q = q_ref.shape[1]
     s = k_ref.shape[1]
     qi = _pid(2)
-    row0 = jax.lax.mul(qi, _i32(block_q))
-
-    if causal and not iotafree:
-        row_ids = row0[None, None] + \
-            jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    if causal and iotafree:
-        diff = _band_diff(block_q, block_k)
+    if causal:
+        assert block_q % block_k == 0
+        ratio = block_q // block_k
+        band0 = jax.lax.mul(qi, _i32(block_q))
+        tri = _tri_vis(tile)
 
     for hh in range(hg):
         sl = slice(hh * d, (hh + 1) * d)
         q = q_ref[0, :, sl]                                   # (BQ, D)
 
-        def make_body(masked):
-            def body(kb, carry):
-                m, l, acc = carry
-                start = jax.lax.mul(kb, _i32(block_k))
-                k = k_ref[0, pl.ds(start, block_k), sl]
-                v = v_ref[0, pl.ds(start, block_k), sl]
-                vis = None
-                if masked:
-                    if iotafree:
-                        vis = diff <= (row0 - start)
-                    else:
-                        col_ids = start[None, None] + \
-                            jax.lax.broadcasted_iota(
-                                jnp.int32, (block_q, block_k), 1)
-                        vis = col_ids <= row_ids
-                return _online_step(q, k, v, m, l, acc, vis, scale,
-                                    bf16chain,
-                                    band_mul=masked and _BAND_MUL)
-            return body
+        def body(kb, carry):
+            m, l, acc = carry
+            start = jax.lax.mul(kb, _i32(block_k))
+            k = k_ref[0, pl.ds(start, block_k), sl]
+            v = v_ref[0, pl.ds(start, block_k), sl]
+            return _online_step(q, k, v, m, l, acc, None, scale, bf16chain)
 
         init = (jnp.full((block_q,), jnp.float32(_NEG_INF), jnp.float32),
                 jnp.zeros((block_q,), jnp.float32),
                 jnp.zeros((block_q, d), jnp.float32))
         if causal:
-            # fully-visible blocks skip the mask arithmetic; the diagonal
-            # band (block_q // block_k blocks) applies it
-            assert block_q % block_k == 0
-            ratio = _i32(block_q // block_k)
-            num_full = jax.lax.mul(qi, ratio)
-            carry = jax.lax.fori_loop(_i32(0), num_full, make_body(False),
-                                      init)
-            m, l, acc = jax.lax.fori_loop(num_full,
-                                          jax.lax.add(num_full, ratio),
-                                          make_body(True), carry)
+            # the k blocks before the diagonal are fully visible: whole
+            # block_q rows, no mask arithmetic.  The block_q // block_k k
+            # blocks on it are the band: each t-row sub-block takes its
+            # visible prefix of a band block and its own t x t tile under
+            # the constant triangle in one step; the sub-tiles above the
+            # diagonal are never computed
+            num_full = jax.lax.mul(qi, _i32(ratio))
+            st = _split_rows(*jax.lax.fori_loop(_i32(0), num_full, body,
+                                                init), tile)
+            for jj in range(ratio):
+                _band_steps(
+                    st, lambda rows: q_ref[0, rows, sl],
+                    lambda cols: tuple(
+                        ref[0, pl.ds(band0 + _i32(jj * block_k + cols.start),
+                                     cols.stop - cols.start), sl]
+                        for ref in (k_ref, v_ref)),
+                    _band_runs(block_q, block_k, tile, -jj * block_k),
+                    tri, scale, bf16chain)
         else:
-            m, l, acc = jax.lax.fori_loop(_i32(0), _i32(s // block_k),
-                                          make_body(False), init)
-        l_safe = jnp.maximum(l, jnp.float32(1e-30))
-        o_ref[0, :, sl] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-        # lse in base-2 units: m is already log2-scaled
-        lse_row = (m + jnp.log(l_safe) * jnp.float32(_LOG2E))[None, :]
-        if parq:
-            lse_ref[0, 0, 0, pl.ds(hh, 1), :] = lse_row
-        else:
-            lse_ref[0, 0, hh, pl.ds(qi, 1), :] = lse_row
+            st = {0: jax.lax.fori_loop(_i32(0), _i32(s // block_k), body,
+                                       init)}
+        _fwd_finish(st, o_ref, sl, lse_ref,
+                    (0, 0, 0, pl.ds(hh, 1), slice(None)) if parq else
+                    (0, 0, hh, pl.ds(qi, 1), slice(None)))
 
 
 def _fwd_kernel_streamed(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
-                         acc_sc, *, causal, scale, hg, d, nk,
-                         bf16chain=False, iotafree=False):
+                         acc_sc, *, causal, scale, hg, d, nk, tile,
+                         bf16chain=False):
     # q/o: (1, BQ, HG*D); k/v: (1, BK, HG*D) — ki-th block, streamed by the
     # grid; lse: (1, 1, HG, NQ, BQ); scratch m/l: (HG, BQ) f32,
     # acc: (BQ, HG*D) f32, persistent across the sequential ki iterations.
@@ -465,45 +576,18 @@ def _fwd_kernel_streamed(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def _attend(masked):
-        vis = None
-        if masked:
-            vis = _cell_vis(jax.lax.mul(qi, _i32(block_q)),
-                            jax.lax.mul(ki, _i32(block_k)),
-                            block_q, block_k, iotafree)
+    def _attend(rows, cols, vis):
         for hh in range(hg):
             sl = slice(hh * d, (hh + 1) * d)
-            q = q_ref[0, :, sl]                               # (BQ, D)
-            k = k_ref[0, :, sl]                               # (BK, D)
-            v = v_ref[0, :, sl]
             new_m, new_l, new_acc = _online_step(
-                q, k, v, m_sc[hh], l_sc[hh], acc_sc[:, sl], vis, scale,
-                bf16chain)
-            l_sc[hh] = new_l
-            acc_sc[:, sl] = new_acc
-            m_sc[hh] = new_m
+                q_ref[0, rows, sl], k_ref[0, cols, sl], v_ref[0, cols, sl],
+                m_sc[hh, rows], l_sc[hh, rows], acc_sc[rows, sl], vis,
+                scale, bf16chain)
+            l_sc[hh, rows] = new_l
+            acc_sc[rows, sl] = new_acc
+            m_sc[hh, rows] = new_m
 
-    if causal:
-        # split visible blocks into fully-visible (no mask arithmetic —
-        # the iota/where VPU work is significant at these shapes) and the
-        # diagonal band (masked); the two pl.when branches are disjoint
-        first_row = jax.lax.mul(qi, _i32(block_q))
-        last_row = first_row + _i32(block_q - 1)
-        last_col = jax.lax.mul(ki, _i32(block_k)) + _i32(block_k - 1)
-        fully_visible = last_col <= first_row
-        diagonal = jnp.logical_and(last_col > first_row,
-                                   jax.lax.mul(ki, _i32(block_k)) <=
-                                   last_row)
-
-        @pl.when(fully_visible)
-        def _compute_full():
-            _attend(False)
-
-        @pl.when(diagonal)
-        def _compute_diag():
-            _attend(True)
-    else:
-        _attend(False)
+    _causal_cells(_attend, causal, qi, ki, block_q, block_k, tile)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -518,30 +602,31 @@ def _fwd_kernel_streamed(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
 
 
 def _fwd_kernel_pipelined(q_ref, k_any, v_any, o_ref, lse_ref, k_sc, v_sc,
-                          sem, *, causal, scale, hg, d, block_k, nk,
-                          bf16chain=False, iotafree=False):
+                          sem, *, causal, scale, hg, d, block_k, nk, tile,
+                          bf16chain=False):
     """Forward with EXPLICIT K/V streaming: K/V stay in HBM (ANY memory
     space) and block_k-sized chunks are double-buffered into VMEM scratch
     with async copies, so the fetch of chunk i+1 overlaps the softmax chain
     of chunk i.  Grid (B, n_hg, nq) like the resident kernel; O(block_k)
     K/V VMEM instead of O(S).  Under causal the scan stops after the
-    diagonal band; band blocks are classified per-iteration (scalar
-    compare), so unlike the resident kernel there is no separate mask-free
-    loop — the variant trades that split for the copy overlap."""
+    diagonal block: the chunks before it run whole and unmasked in the
+    fori loop, the block_q // block_k chunks of the band are unrolled
+    behind it, each walked by its live sub-tiles."""
     block_q = q_ref.shape[1]
     hgd = hg * d
     bi = _pid(0)
     g = _pid(1)
     qi = _pid(2)
-    row0 = jax.lax.mul(qi, _i32(block_q))
     col_base = jax.lax.mul(g, _i32(hgd))
 
     if causal:
-        # only blocks up to the band end attend; rest are strictly future
+        # only chunks up to the band end attend; rest are strictly future
         assert block_q % block_k == 0
-        kend = jax.lax.mul(qi + 1, _i32(block_q // block_k))
+        ratio = block_q // block_k
+        num_full = jax.lax.mul(qi, _i32(ratio))
+        kend = num_full + _i32(ratio)
     else:
-        kend = _i32(nk)
+        num_full = kend = _i32(nk)
 
     def kv_dma(slot, kb):
         start = jax.lax.mul(kb, _i32(block_k))
@@ -557,8 +642,8 @@ def _fwd_kernel_pipelined(q_ref, k_any, v_any, o_ref, lse_ref, k_sc, v_sc,
     ck0.start()
     cv0.start()
 
-    def body(kb, carry):
-        ms, ls, accs = carry     # per-head tuples: (BQ,), (BQ,), (BQ, D)
+    def chunk(kb):
+        """Start the fetch of chunk kb+1, wait for chunk kb: its slot."""
         slot = jax.lax.rem(kb, _i32(2))
         nxt = jax.lax.rem(kb + 1, _i32(2))
 
@@ -571,51 +656,74 @@ def _fwd_kernel_pipelined(q_ref, k_any, v_any, o_ref, lse_ref, k_sc, v_sc,
         ck, cv = kv_dma(slot, kb)
         ck.wait()
         cv.wait()
-        start = jax.lax.mul(kb, _i32(block_k))
-        vis = None
-        if causal:
-            # band blocks need the mask; fully-visible ones get vis=True
-            # everywhere (the scalar classification is folded into the
-            # mask itself — cheaper than a pl.when split inside fori)
-            vis = _cell_vis(row0, start, block_q, block_k, iotafree)
-        new_ms, new_ls, new_accs = [], [], []
-        for hh in range(hg):
-            sl = slice(hh * d, (hh + 1) * d)
-            nm, nl, na = _online_step(
-                q_ref[0, :, sl], k_sc[slot, :, sl], v_sc[slot, :, sl],
-                ms[hh], ls[hh], accs[hh], vis, scale, bf16chain)
-            new_ms.append(nm)
-            new_ls.append(nl)
-            new_accs.append(na)
-        return tuple(new_ms), tuple(new_ls), tuple(new_accs)
+        return slot
+
+    def body(kb, carry):
+        ms, ls, accs = carry     # per-head tuples: (BQ,), (BQ,), (BQ, D)
+        slot = chunk(kb)
+        new = [_online_step(q_ref[0, :, hh * d:(hh + 1) * d],
+                            k_sc[slot, :, hh * d:(hh + 1) * d],
+                            v_sc[slot, :, hh * d:(hh + 1) * d],
+                            ms[hh], ls[hh], accs[hh], None, scale,
+                            bf16chain) for hh in range(hg)]
+        return tuple(zip(*new))
 
     init = (tuple(jnp.full((block_q,), jnp.float32(_NEG_INF), jnp.float32)
                   for _ in range(hg)),
             tuple(jnp.zeros((block_q,), jnp.float32) for _ in range(hg)),
             tuple(jnp.zeros((block_q, d), jnp.float32)
                   for _ in range(hg)))
-    ms, ls, accs = jax.lax.fori_loop(_i32(0), kend, body, init)
+    ms, ls, accs = jax.lax.fori_loop(_i32(0), num_full, body, init)
+    sts = [_split_rows(ms[hh], ls[hh], accs[hh], tile if causal
+                       else block_q) for hh in range(hg)]
+    if causal:
+        tri = _tri_vis(tile)
+        for jj in range(ratio):
+            slot = chunk(num_full + _i32(jj))
+            runs = _band_runs(block_q, block_k, tile, -jj * block_k)
+            for hh in range(hg):
+                sl = slice(hh * d, (hh + 1) * d)
+                _band_steps(
+                    sts[hh], lambda rows: q_ref[0, rows, sl],
+                    lambda cols: (k_sc[slot, cols, sl],
+                                  v_sc[slot, cols, sl]),
+                    runs, tri, scale, bf16chain)
     for hh in range(hg):
-        sl = slice(hh * d, (hh + 1) * d)
-        l_safe = jnp.maximum(ls[hh], jnp.float32(1e-30))
-        o_ref[0, :, sl] = (accs[hh] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, hh, pl.ds(qi, 1), :] = \
-            (ms[hh] + jnp.log(l_safe) * jnp.float32(_LOG2E))[None, :]
+        _fwd_finish(sts[hh], o_ref, slice(hh * d, (hh + 1) * d), lse_ref,
+                    (0, 0, hh, pl.ds(qi, 1), slice(None)))
 
 
 def _flash_fwd(q3, k3, v3, causal, scale, d, interpret, spec):
+    """The forward's entry: settles what the program depends on beside
+    its operands (which family, the band's tile), counts the call's score
+    elements, and hands over to the jitted builder, so that the layers of
+    a model share ONE traced kernel instead of tracing one each."""
+    variant, block_q, block_k, hg = spec
+    feats = variant_features(variant, _FWD_FEATURES)
+    family = ("pipelined" if "pipelined" in feats else
+              "resident" if _kv_fits_resident(k3.shape[1], hg * d)
+              else "streamed")
+    tile = _band_tile(block_k)
+    if causal:
+        # the registry lives outside the kernel modules (their bodies are
+        # traced): the dispatch module writes the counter
+        from .flash_attention import note_score_elements
+        b, s, hd = q3.shape
+        note_score_elements(*(b * (hd // d) * n
+                              for n in score_elements(s, block_q, tile)))
     # trace with x64 off: the global x64 mode (needed for paddle's int64
     # semantics) surfaces i64/f64 intermediates that mosaic cannot lower
     with x64_scope(False):
         return _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret,
-                                spec)
+                                spec, family, tile)
 
 
-def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec, family,
+                     tile):
     variant, block_q, block_k, hg = spec
     feats = variant_features(variant, _FWD_FEATURES)
     bf16chain = "bf16chain" in feats
-    iotafree = "iotafree" in feats
     b, s, hd = q3.shape
     sk = k3.shape[1]
     n_hg = hd // (hg * d)
@@ -625,13 +733,13 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec):
     q_spec3 = pl.BlockSpec((1, block_q, hgd), lambda bi, g, i: (bi, i, g))
     lse_shape = _sds((b, n_hg, hg, nq, block_q), jnp.float32, q3)
     out_shape = _sds((b, s, hd), q3.dtype, q3)
-    if "pipelined" in feats:
+    if family == "pipelined":
         # explicit double-buffered K/V DMA — O(block_k) K/V VMEM at ANY
         # sequence length (an alternative to both the resident and the
         # grid-streamed paths; the autotuner decides when it wins)
         kernel = functools.partial(
             _fwd_kernel_pipelined, causal=causal, scale=scale, hg=hg, d=d,
-            block_k=block_k, nk=nk, bf16chain=bf16chain, iotafree=iotafree)
+            block_k=block_k, nk=nk, tile=tile, bf16chain=bf16chain)
         out, lse = pl.pallas_call(
             kernel,
             grid=(b, n_hg, nq),
@@ -655,14 +763,13 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec):
             interpret=interpret,
         )(q3, k3, v3)
         return out, lse
-    if _kv_fits_resident(sk, hgd):
+    if family == "resident":
         # fast path: whole K/V resident per cell, fori scan (measured
         # fastest at bench shapes)
         parq = "parq" in feats
         kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                                   hg=hg, d=d, block_k=block_k,
-                                   bf16chain=bf16chain, iotafree=iotafree,
-                                   parq=parq)
+                                   hg=hg, d=d, block_k=block_k, tile=tile,
+                                   bf16chain=bf16chain, parq=parq)
         kv_spec = pl.BlockSpec((1, sk, hgd), lambda bi, g, i: (bi, 0, g))
         if parq:
             # per-q-block lse blocks: nothing is revisited, so every grid
@@ -693,8 +800,8 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec):
     # long-sequence path: K/V blocks streamed by the grid — O(block) VMEM,
     # keeps the O(S) capability for sequences whose K/V don't fit resident
     kernel = functools.partial(_fwd_kernel_streamed, causal=causal,
-                               scale=scale, hg=hg, d=d, nk=nk,
-                               bf16chain=bf16chain, iotafree=iotafree)
+                               scale=scale, hg=hg, d=d, nk=nk, tile=tile,
+                               bf16chain=bf16chain)
     q_spec = pl.BlockSpec((1, block_q, hgd), lambda bi, g, i, j: (bi, i, g))
     kv_spec = pl.BlockSpec((1, block_k, hgd), lambda bi, g, i, j: (bi, j, g))
     out, lse = pl.pallas_call(
@@ -723,27 +830,17 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec):
 # backward (merged dQ/dK/dV + split dQ / dKV kernels)
 # ---------------------------------------------------------------------------
 
-def _apply_causal_split(compute, causal, qi, ki, block_q, block_k):
-    """Run ``compute(masked)`` under the causal block taxonomy: skipped
-    (strictly-future), fully-visible (no mask arithmetic), or diagonal
-    band (mask applied).  Non-causal runs unconditionally unmasked."""
-    if not causal:
-        compute(False)
-        return
-    first_row = jax.lax.mul(qi, _i32(block_q))
-    last_row = first_row + _i32(block_q - 1)
-    first_col = jax.lax.mul(ki, _i32(block_k))
-    last_col = first_col + _i32(block_k - 1)
-    fully_visible = last_col <= first_row
-    diagonal = jnp.logical_and(last_col > first_row, first_col <= last_row)
-    pl.when(fully_visible)(lambda: compute(False))
-    pl.when(diagonal)(lambda: compute(True))
+def _row_stat(ref, hh, qi, rows):
+    """Rows ``rows`` of head hh's lse/delta row of q block qi, (n,) f32:
+    the whole (BQ,) row is loaded and the value sliced — Mosaic has no
+    (1, t) load at a lane offset from a dynamically indexed row."""
+    row = ref[0, 0, hh, pl.ds(qi, 1), :][0]
+    return row if rows == slice(0, row.shape[0]) else row[rows]
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc, *,
-                causal, scale, hg, d, nq, nk, bf16chain=False,
-                iotafree=False):
+                causal, scale, hg, d, nq, nk, tile, bf16chain=False):
     block_k = k_ref.shape[1]
     block_q = q_ref.shape[1]
     ki = _pid(2)
@@ -758,31 +855,29 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    def _compute(masked):
-        vis = None
-        if masked:
-            vis = _cell_vis(jax.lax.mul(qi, _i32(block_q)),
-                            jax.lax.mul(ki, _i32(block_k)),
-                            block_q, block_k, iotafree)
+    def _compute(rows, cols, vis):
         row0 = jax.lax.mul(qi, _i32(block_q))
+        if rows.start:
+            row0 = row0 + _i32(rows.start)
+        n_rows = rows.stop - rows.start
         for hh in range(hg):
             sl = slice(hh * d, (hh + 1) * d)
             g = _bwd_head_math(
-                q_ref[0, :, sl], k_ref[0, :, sl], v_ref[0, :, sl],
-                do_ref[0, :, sl],
-                lse_ref[0, 0, hh, pl.ds(qi, 1), :][0],       # (BQ,) base-2
-                delta_ref[0, 0, hh, pl.ds(qi, 1), :][0],     # (BQ,) f32
+                q_ref[0, rows, sl], k_ref[0, cols, sl], v_ref[0, cols, sl],
+                do_ref[0, rows, sl],
+                _row_stat(lse_ref, hh, qi, rows),            # base-2
+                _row_stat(delta_ref, hh, qi, rows),
                 vis, scale, bf16chain)
-            dv_sc[:, sl] = dv_sc[:, sl] + g["dv"]
-            dk_sc[:, sl] = dk_sc[:, sl] + g["dk"]
-            # dQ rows qi accumulate in the full-sequence scratch
-            dq_sc[pl.ds(row0, block_q), sl] = \
-                dq_sc[pl.ds(row0, block_q), sl] + g["dq"]
+            dv_sc[cols, sl] = dv_sc[cols, sl] + g["dv"]
+            dk_sc[cols, sl] = dk_sc[cols, sl] + g["dk"]
+            # dQ rows accumulate in the full-sequence scratch
+            dq_sc[pl.ds(row0, n_rows), sl] = \
+                dq_sc[pl.ds(row0, n_rows), sl] + g["dq"]
 
-    # fully-visible blocks skip the iota/where mask arithmetic entirely —
-    # only the diagonal band pays it (the same split the streamed forward
-    # uses; the two pl.when conditions are disjoint)
-    _apply_causal_split(_compute, causal, qi, ki, block_q, block_k)
+    # fully-visible cells run whole with no mask arithmetic, the band
+    # cells by their live sub-tiles, strictly-future cells not at all (the
+    # static grid still streams their prefetch: the price of pipelining)
+    _causal_cells(_compute, causal, qi, ki, block_q, block_k, tile)
 
     @pl.when(qi == nq - 1)
     def _finalize_kv():
@@ -795,11 +890,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_sc, *, causal, scale, hg, d, nk,
-                   bf16chain=False, iotafree=False):
+                   dq_ref, dq_sc, *, causal, scale, hg, d, nk, tile,
+                   bf16chain=False):
     """dQ-only backward for LONG sequences: grid (b, n_hg, nq, nk) with ki
     innermost, so dq accumulates in a BLOCK-sized scratch (no full-sequence
-    scratch — the merged kernel's 16k+ VMEM blocker, PERF.md)."""
+    scratch — the merged kernel's VMEM bound on the sequence length)."""
     block_k = k_ref.shape[1]
     block_q = q_ref.shape[1]
     qi = _pid(2)
@@ -809,23 +904,18 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    def _compute(masked):
-        vis = None
-        if masked:
-            vis = _cell_vis(jax.lax.mul(qi, _i32(block_q)),
-                            jax.lax.mul(ki, _i32(block_k)),
-                            block_q, block_k, iotafree)
+    def _compute(rows, cols, vis):
         for hh in range(hg):
             sl = slice(hh * d, (hh + 1) * d)
             g = _bwd_head_math(
-                q_ref[0, :, sl], k_ref[0, :, sl], v_ref[0, :, sl],
-                do_ref[0, :, sl],
-                lse_ref[0, 0, hh, pl.ds(qi, 1), :][0],       # base-2
-                delta_ref[0, 0, hh, pl.ds(qi, 1), :][0],
+                q_ref[0, rows, sl], k_ref[0, cols, sl], v_ref[0, cols, sl],
+                do_ref[0, rows, sl],
+                _row_stat(lse_ref, hh, qi, rows),            # base-2
+                _row_stat(delta_ref, hh, qi, rows),
                 vis, scale, bf16chain, want_dkv=False)
-            dq_sc[:, sl] = dq_sc[:, sl] + g["dq"]
+            dq_sc[rows, sl] = dq_sc[rows, sl] + g["dq"]
 
-    _apply_causal_split(_compute, causal, qi, ki, block_q, block_k)
+    _causal_cells(_compute, causal, qi, ki, block_q, block_k, tile)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -834,7 +924,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_sc, dv_sc, *, causal, scale, hg, d,
-                    nq, bf16chain=False, iotafree=False):
+                    nq, tile, bf16chain=False):
     """dK/dV backward (ki outer, qi inner) — the merged kernel minus the
     full-sequence dq scratch; pairs with _bwd_dq_kernel for long seqs."""
     block_k = k_ref.shape[1]
@@ -847,29 +937,30 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    def _compute(masked):
-        vis = None
-        if masked:
-            vis = _cell_vis(jax.lax.mul(qi, _i32(block_q)),
-                            jax.lax.mul(ki, _i32(block_k)),
-                            block_q, block_k, iotafree)
+    def _compute(rows, cols, vis):
         for hh in range(hg):
             sl = slice(hh * d, (hh + 1) * d)
             g = _bwd_head_math(
-                q_ref[0, :, sl], k_ref[0, :, sl], v_ref[0, :, sl],
-                do_ref[0, :, sl],
-                lse_ref[0, 0, hh, pl.ds(qi, 1), :][0],
-                delta_ref[0, 0, hh, pl.ds(qi, 1), :][0],
+                q_ref[0, rows, sl], k_ref[0, cols, sl], v_ref[0, cols, sl],
+                do_ref[0, rows, sl],
+                _row_stat(lse_ref, hh, qi, rows),            # base-2
+                _row_stat(delta_ref, hh, qi, rows),
                 vis, scale, bf16chain, want_dq=False)
-            dv_sc[:, sl] = dv_sc[:, sl] + g["dv"]
-            dk_sc[:, sl] = dk_sc[:, sl] + g["dk"]
+            dv_sc[cols, sl] = dv_sc[cols, sl] + g["dv"]
+            dk_sc[cols, sl] = dk_sc[cols, sl] + g["dk"]
 
-    _apply_causal_split(_compute, causal, qi, ki, block_q, block_k)
+    _causal_cells(_compute, causal, qi, ki, block_q, block_k, tile)
 
     @pl.when(qi == nq - 1)
     def _finalize():
         dk_ref[0] = (jnp.float32(scale) * dk_sc[...]).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+
+
+#: the backward builders are jitted like the forward's: one traced kernel
+#: for all the layers of a model (operands dynamic, the rest static)
+_BWD_JIT = functools.partial(jax.jit,
+                             static_argnums=(6, 7, 8, 9, 10, 11, 12))
 
 
 def _fold_lse(lse, b, h, hg, block_q):
@@ -887,10 +978,10 @@ def _fold_rows(x, b, h, hg, block_q):
                                           block_q)
 
 
-def _bwd_dq_call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
-                 interpret):
-    """The dq pallas_call of the split backward — also the autotuner's
-    flash_bwd_dq runner entry."""
+@_BWD_JIT
+def _bwd_dq(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
+            interpret, tile):
+    """The dq pallas_call of the split backward."""
     variant, block_q, block_k = spec
     feats = variant_features(variant, _BWD_FEATURES)
     b, s, hd = q3.shape
@@ -910,8 +1001,8 @@ def _bwd_dq_call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
                           hg=hg, d=d, nk=nk,
-                          bf16chain="bf16chain" in feats,
-                          iotafree="iotafree" in feats),
+                          tile=tile,
+                          bf16chain="bf16chain" in feats),
         grid=(b, h // hg, nq, nk),
         in_specs=[q_spec_qout, kv_spec_qout, kv_spec_qout, q_spec_qout,
                   row_spec, row_spec],
@@ -924,10 +1015,10 @@ def _bwd_dq_call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
     )(q3, k3, v3, do3, lse5, delta5)
 
 
-def _bwd_dkv_call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
-                  interpret):
-    """The dk/dv pallas_call of the split backward — also the autotuner's
-    flash_bwd_dkv runner entry."""
+@_BWD_JIT
+def _bwd_dkv(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
+             interpret, tile):
+    """The dk/dv pallas_call of the split backward."""
     variant, block_q, block_k = spec
     feats = variant_features(variant, _BWD_FEATURES)
     b, s, hd = q3.shape
@@ -947,8 +1038,8 @@ def _bwd_dkv_call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
                           hg=hg, d=d, nq=nq,
-                          bf16chain="bf16chain" in feats,
-                          iotafree="iotafree" in feats),
+                          tile=tile,
+                          bf16chain="bf16chain" in feats),
         grid=(b, h // hg, nk, nq),
         in_specs=[q_spec_kout, kv_spec_kout, kv_spec_kout, q_spec_kout,
                   row_spec, row_spec],
@@ -963,9 +1054,10 @@ def _bwd_dkv_call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
     )(q3, k3, v3, do3, lse5, delta5)
 
 
-def _bwd_merged_call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d,
-                     spec, interpret):
-    """The merged dQ/dK/dV pallas_call — the autotuner's flash_bwd entry."""
+@_BWD_JIT
+def _bwd_merged(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
+                interpret, tile):
+    """The merged dQ/dK/dV pallas_call."""
     variant, block_q, block_k = spec
     feats = variant_features(variant, _BWD_FEATURES)
     b, s, hd = q3.shape
@@ -983,8 +1075,8 @@ def _bwd_merged_call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d,
     return pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal, scale=scale,
                           hg=hg, d=d, nq=nq, nk=nk,
-                          bf16chain="bf16chain" in feats,
-                          iotafree="iotafree" in feats),
+                          tile=tile,
+                          bf16chain="bf16chain" in feats),
         grid=(b, h // hg, nk, nq),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[
@@ -1007,6 +1099,23 @@ def _bwd_merged_call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d,
         name="flash_bwd",
         interpret=interpret,
     )(q3, k3, v3, do3, lse5, delta5)
+
+
+def _bwd_entry(builder):
+    """``call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
+    interpret)`` over a jitted builder — the production entry and the
+    autotuner's runner entry; the band's tile is settled out here, where
+    the builder's trace cache can see it."""
+    def call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
+             interpret):
+        return builder(q3, k3, v3, do3, lse, delta, causal, scale, hg, d,
+                       spec, interpret, _band_tile(spec[2]))
+    return call
+
+
+_bwd_dq_call = _bwd_entry(_bwd_dq)
+_bwd_dkv_call = _bwd_entry(_bwd_dkv)
+_bwd_merged_call = _bwd_entry(_bwd_merged)
 
 
 def _flash_bwd(q3, k3, v3, o3, lse, do3, causal, scale, d, interpret, spec,

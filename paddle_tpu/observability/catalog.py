@@ -229,6 +229,15 @@ CATALOG = {
     "autotune.tune_seconds": _m(
         "histogram", "wall time of one timed candidate selection",
         unit="seconds"),
+    "flash.score_elements": _m(
+        "counter", "attention score elements of the causal flash calls "
+        "traced so far, over all heads: which='computed' is what the "
+        "forward kernel's block and sub-tile walk computes (the backward "
+        "walks the same tiles), which='causal' the s*(s+1)/2 a head the "
+        "mask needs; (computed - causal) / computed is the share of the "
+        "kernels' work that is multiplied by zero.  Trace-time like "
+        "mp.overlap_chunks: a compile-once program contributes once",
+        labels=("which",)),
 
     # -- compile watchdog ---------------------------------------------------
     "compile.count": _m(

@@ -202,3 +202,263 @@ def test_cross_attention_kv_longer(variantless=True):
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5, rtol=1e-5,
                                    err_msg=variant)
+
+
+# ---------------------------------------------------------------------------
+# the causal band: static sub-tiles (both candidate tile edges)
+# ---------------------------------------------------------------------------
+
+TILES = [128, 256]
+
+
+def _bshd(shape, seed, scale=0.5):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(*shape), jnp.float32) * scale
+                 for _ in range(4))
+
+
+def _ref_bshd(q, k, v):
+    return jnp.swapaxes(_reference_bhsd(
+        *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), True,
+        1.0 / q.shape[-1] ** 0.5), 1, 2)
+
+
+def _band_parity(monkeypatch, tile, d, block_q, block_k, variant=None,
+                 resident=True, merged=True, s=1024):
+    """Forward and gradients of one causal call whose diagonal blocks are
+    walked in ``tile``-edge sub-tiles, against the O(S^2) reference."""
+    monkeypatch.setattr(fap, "_BAND_TILE", tile)
+    if not resident:
+        monkeypatch.setattr(fap, "_RESIDENT_KV_BUDGET", 1)
+    if not merged:
+        monkeypatch.setattr(fap, "_DQ_SCRATCH_BUDGET", 1)
+    assert fap._band_tile(block_k) == tile
+    q, k, v, ct = _bshd((1, s, 2, d), seed=tile + d)
+
+    def f(q_, k_, v_):
+        return jnp.sum(ct * fap.flash_attention_bshd_native(
+            q_, k_, v_, causal=True, block_q=block_q, block_k=block_k,
+            interpret=True, variant=variant))
+
+    def r(q_, k_, v_):
+        return jnp.sum(ct * _ref_bshd(q_, k_, v_))
+
+    out = fap.flash_attention_bshd_native(
+        q, k, v, causal=True, block_q=block_q, block_k=block_k,
+        interpret=True, variant=variant)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_ref_bshd(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    got = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(r, argnums=(0, 1, 2))(q, k, v)
+    for name, a, b_ in zip("dq dk dv".split(), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=2e-4, rtol=1e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("tile", TILES)
+def test_band_resident_forward_merged_backward(monkeypatch, tile, d):
+    """The benchmark cell's kernels: (s, block) = (1,024, 512)."""
+    _band_parity(monkeypatch, tile, d, 512, 512)
+
+
+@pytest.mark.parametrize("tile", TILES)
+def test_band_streamed_forward_split_backward(monkeypatch, tile):
+    """The long-sequence family: grid-streamed forward, dq and dkv kernels."""
+    _band_parity(monkeypatch, tile, 64, 512, 512, resident=False,
+                 merged=False)
+
+
+@pytest.mark.parametrize("family", ["resident", "streamed", "pipelined"])
+@pytest.mark.parametrize("tile,block_k", [(128, 256), (128, 128),
+                                          (256, 256)])
+def test_band_of_several_k_blocks(monkeypatch, tile, block_k, family):
+    """block_k < block_q: the band is block_q // block_k cells, each at
+    its own static offset from the diagonal (merged and split backward)."""
+    _band_parity(monkeypatch, tile, 64, 512, block_k, s=512,
+                 variant="pipelined" if family == "pipelined" else None,
+                 resident=family != "streamed",
+                 merged=family != "streamed")
+
+
+@pytest.mark.parametrize("tile", TILES)
+def test_band_with_lse_cotangent(monkeypatch, tile):
+    """flash_attention_bshd_with_lse over a sub-tiled band, the loss
+    consuming the lse too (the ring-attention inner's shape)."""
+    from paddle_tpu.kernels.flash_attention_pallas import \
+        flash_attention_bshd_with_lse
+    monkeypatch.setattr(fap, "_BAND_TILE", tile)
+    s, d = 512, 64
+    q, k, v, _ = _bshd((1, s, 2, d), seed=tile)
+    scale = 1.0 / np.sqrt(d)
+
+    def loss_flash(q_, k_, v_):
+        out, lse = flash_attention_bshd_with_lse(
+            q_, k_, v_, causal=True, interpret=True)
+        return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
+
+    def loss_ref(q_, k_, v_):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q_, k_) * scale
+        logits = jnp.where(jnp.tril(jnp.ones((s, s), bool)), logits, -1e30)
+        out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, -1), v_)
+        lse = jnp.moveaxis(jax.scipy.special.logsumexp(logits, -1), 1, -1)
+        return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
+
+    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("block_q,block_k,t,off", [
+    (8, 8, 2, 0), (8, 8, 4, 0), (8, 8, 8, 0), (8, 4, 2, 0), (8, 4, 2, -4),
+    (8, 2, 2, -2), (8, 2, 2, -6), (12, 4, 4, -8), (16, 8, 4, -8),
+    (4, 8, 4, 4)])
+def test_live_tiles_are_the_tiles_with_a_visible_element(block_q, block_k,
+                                                         t, off):
+    """Brute force over small grids: a tile is live iff the mask leaves
+    any of its elements visible, masked iff it hides any; the merged runs
+    cover exactly the live tiles."""
+    i = np.arange(block_q)[:, None]
+    j = np.arange(block_k)[None, :]
+    vis = j <= i + off
+    want = []
+    for r in range(block_q // t):
+        for c in range(block_k // t):
+            tile = vis[r * t:(r + 1) * t, c * t:(c + 1) * t]
+            if tile.any():
+                want.append((r, c, not tile.all()))
+                if not tile.all():      # the one constant triangle
+                    np.testing.assert_array_equal(
+                        tile, np.tril(np.ones((t, t), bool)))
+    assert fap._live_tiles(block_q, block_k, t, off) == want
+    covered = np.zeros_like(vis)
+    for rows, cols, masked in fap._band_runs(block_q, block_k, t, off):
+        assert not covered[rows, cols].any()
+        covered[rows, cols] = True
+        assert masked == (not vis[rows, cols].all())
+    tiles = np.zeros_like(vis)
+    for r, c, _ in want:
+        tiles[r * t:(r + 1) * t, c * t:(c + 1) * t] = True
+    np.testing.assert_array_equal(covered, tiles)
+
+
+def test_one_tile_band_is_one_masked_step():
+    """block_q == block_k == t: the walk is the whole block under the
+    mask, as before the band was sub-tiled."""
+    assert fap._band_runs(128, 128, 128, 0) == [
+        (slice(0, 128), slice(0, 128), True)]
+    assert fap._band_tile(128) == 128
+    # a block no candidate divides stays whole
+    assert fap._band_tile(192) == 192
+
+
+#: sha256 over the kernel bodies (the jaxprs inside the pallas_calls) of
+#: the non-causal programs at the parent of PR 26 (commit 6666a21), unused
+#: scalar equations dropped: the sub-tiled band must leave non-causal
+#: calls exactly as they were.  To regenerate after a deliberate change:
+#: print _noncausal_kernels_hash(...) on both trees.
+_NONCAUSAL_KERNELS = {
+    "resident+merged":
+        "5b2d29c08628afb2c84b1c4c61afc5e5e8b47760d2271d26f5ab23c4b68acef8",
+    "streamed+split":
+        "2b873f44dab64228bc77267bd05a4d92d3c9e6c17f54cc894c3786ca02192590",
+}
+
+
+def _kernel_bodies(jaxpr):
+    """The kernel jaxprs of every pallas_call under ``jaxpr``, in order."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(str(eqn.params["jaxpr"]))
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                out += _kernel_bodies(sub)
+    return out
+
+
+def _noncausal_kernels_hash(monkeypatch, family):
+    import hashlib
+    import re
+    if family == "streamed+split":
+        monkeypatch.setattr(fap, "_RESIDENT_KV_BUDGET", 1)
+        monkeypatch.setattr(fap, "_DQ_SCRATCH_BUDGET", 1)
+    q = jnp.zeros((1, 256, 2, 64), jnp.float32)
+    bodies = _kernel_bodies(jax.make_jaxpr(jax.grad(
+        lambda a, b, c: jnp.sum(fap.flash_attention_bshd_native(
+            a, b, c, causal=False, block_q=128, block_k=128,
+            interpret=True)), argnums=(0, 1, 2)))(q, q, q).jaxpr)
+    assert len(bodies) == (2 if family == "resident+merged" else 3)
+    text = re.sub(r"^\s*_:\S+ = .*\n", "", "\n".join(bodies), flags=re.M)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("family", sorted(_NONCAUSAL_KERNELS))
+def test_noncausal_kernel_bodies_are_the_parents(monkeypatch, family):
+    assert _noncausal_kernels_hash(monkeypatch, family) == \
+        _NONCAUSAL_KERNELS[family]
+
+
+# ---------------------------------------------------------------------------
+# flash.score_elements: how far the band walk engages
+# ---------------------------------------------------------------------------
+
+def _score_counter():
+    from paddle_tpu.observability import registry as reg
+    ctr = reg.counter("flash.score_elements", ("which",))
+    return {w: ctr.labels(which=w).value for w in ("computed", "causal")}
+
+
+@pytest.mark.parametrize("tile,computed,dead_pct", [
+    (512, 786432, 33.3), (128, 589824, 11.0), (256, 655360, 19.9)])
+def test_score_elements_at_the_benchmark_shape(tile, computed, dead_pct):
+    """s = 1,024 in 512-blocks: the whole-block walk, t = 128 and t = 256
+    (arithmetic from shapes)."""
+    got, causal = fap.score_elements(1024, 512, tile)
+    assert (got, causal) == (computed, 1024 * 1025 // 2)
+    assert round(100.0 * (got - causal) / got, 1) == dead_pct
+
+
+@pytest.mark.parametrize("transform", ["forward", "grad"])
+def test_score_elements_counted_once_a_traced_causal_call(monkeypatch,
+                                                          transform):
+    from paddle_tpu.observability import CATALOG
+    assert CATALOG["flash.score_elements"]["type"] == "counter"
+    assert CATALOG["flash.score_elements"]["labels"] == ("which",)
+    monkeypatch.setattr(fap, "_BAND_TILE", 128)
+    b, s, h, d = 2, 256, 2, 64
+    q = jnp.ones((b, s, h, d), jnp.float32)
+
+    def f(q_, causal):
+        return jnp.sum(fap.flash_attention_bshd_native(
+            q_, q_, q_, causal=causal, interpret=True))
+
+    fn = {"forward": f, "grad": jax.grad(f)}[transform]
+    before = _score_counter()
+    jax.jit(fn, static_argnums=1)(q, False)       # non-causal: not counted
+    assert _score_counter() == before
+    jitted = jax.jit(fn, static_argnums=1)
+    jitted(q, True)
+    jitted(q, True)                               # traced once, run twice
+    after = _score_counter()
+    # one 256-block a head: 3 of its 4 sub-tiles are live
+    assert after["computed"] - before["computed"] == b * h * 3 * 128 * 128
+    assert after["causal"] - before["causal"] == b * h * s * (s + 1) // 2
+
+
+def test_dead_score_reader_reads_the_counter_or_nothing():
+    """The benchmark's reader: the share from a registry snapshot, None on
+    a program without the counter (the parent) or outside a training run."""
+    from benchmarks.lib import harness
+    read = harness.layer_reader("flash_dead_score_pct.train")
+    run = {"kind": "train"}
+    assert read({}, None, run) is None
+    assert read(None, None, run) is None
+    snap = {"flash.score_elements": {"series": [
+        {"labels": {"which": "computed"}, "value": 24 * 256 * 589824.0},
+        {"labels": {"which": "causal"}, "value": 24 * 256 * 524800.0}]}}
+    assert round(read(snap, None, run), 1) == 11.0
+    assert read(snap, None, {"kind": "serve_open"}) is None
