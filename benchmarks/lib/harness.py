@@ -193,14 +193,24 @@ class Run:
 
 
 class PartClock:
-    """Seconds of each named part since the last one, for a phase line."""
+    """Seconds of each named part since the last one, for a phase line.
+    With ``memory`` also, for each part, the bytes of live device arrays
+    before it and the device's ``peak_bytes_in_use`` after it (a lifetime
+    peak: the part that raised it is the first that shows the new value)."""
 
-    def __init__(self):
+    def __init__(self, memory=False):
         self.parts, self._t = {}, time.perf_counter()
+        self.memory = {} if memory else None
+        self._live = live_bytes() if memory else 0
 
     def part(self, name):
         now = time.perf_counter()
         self.parts[name] = round(now - self._t, 3)
+        if self.memory is not None:
+            self.memory[name] = {"live_before": self._live,
+                                 "peak_after": peak_bytes()}
+            self._live = live_bytes()
+            now = time.perf_counter()
         self._t = now
 
 
@@ -270,9 +280,38 @@ class Profiler:
             shutil.rmtree(self.dir, ignore_errors=True)
 
 
+def live_bytes():
+    """Bytes of the device arrays this process holds right now (an array
+    sharded over a mesh counts whole)."""
+    import jax
+    return int(sum(a.nbytes for a in jax.live_arrays()))
+
+
+def peak_bytes(devices=None):
+    """``peak_bytes_in_use`` of the fullest of ``devices`` (default: every
+    local one); 0 where the backend keeps no such count, as the CPU's."""
+    import jax
+    return int(max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                   for d in devices or jax.local_devices()))
+
+
+def program_bytes(compiled):
+    """A compiled program's own memory analysis, in bytes: what it takes
+    as arguments, hands back, needs for temporaries and for its code, and
+    how much of its output lives in its (donated) arguments.  ``total`` is
+    what the device holds while it runs: arguments + outputs - aliased +
+    temporaries + code.  (The arithmetic of
+    ``paddle_tpu/observability/costs.py::memory_analysis_dict``, kept here
+    with the yardstick.)"""
+    m = compiled.memory_analysis()
+    out = {k: int(getattr(m, k + "_size_in_bytes"))
+           for k in ("argument", "output", "temp", "alias", "generated_code")}
+    out["total"] = (out["argument"] + out["output"] - out["alias"]
+                    + out["temp"] + out["generated_code"])
+    return out
+
+
 def device_record(devices, chips):
     used = devices[:chips]
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-             for d in used]
     return {"platform": used[0].platform, "kind": used[0].device_kind,
-            "count": len(devices), "memory_peak_bytes": int(max(peaks))}
+            "count": len(devices), "memory_peak_bytes": peak_bytes(used)}

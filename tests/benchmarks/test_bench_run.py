@@ -72,16 +72,14 @@ def test_rehearsal_end_to_end(rehearsal_spec, workload, trace, seed):
     assert checked["within"] is True and checked["seconds"] >= 0
 
 
-def test_mesh_cell_is_data(tmp_path, rehearsal_spec):
-    """train_c13b_dp2mp2 can be added as two data files and one entry: a
-    traffic file with ``mesh`` runs the hybrid path, rehearsed here on four
-    virtual CPU devices."""
+def mesh_spec_file(tmp_path, spec):
+    """``spec`` with one cell, ``train_mesh``: the train cell's traffic
+    under a dp 2 x mp 2 mesh, on cerebras-gpt-1.3b, four chips."""
     traffic = harness.load_json(harness.BENCH_DIR, "traffic",
                                 "train_gpt2m_s1024.json")
     traffic["mesh"] = {"dp": 2, "mp": 2}
     traffic_file = tmp_path / "train_mesh.json"
     traffic_file.write_text(json.dumps(traffic))
-    spec = rehearsal_spec
     trial = dict(spec, workloads=[{
         "name": "train_mesh", "config": "cerebras-gpt-1.3b",
         "traffic": "train_mesh", "chips": 4, "why": "rehearsal",
@@ -91,9 +89,18 @@ def test_mesh_cell_is_data(tmp_path, rehearsal_spec):
                            for m in spec["end_to_end"]]
     spec_file = tmp_path / "spec.json"
     spec_file.write_text(json.dumps(trial))
+    return str(spec_file)
+
+
+def test_mesh_cell_is_data(tmp_path, rehearsal_spec):
+    """train_c13b_dp2mp2 can be added as two data files and one entry: a
+    traffic file with ``mesh`` runs the hybrid path, rehearsed here on four
+    virtual CPU devices.  (Traced, gradients compared:
+    ``test_bench_check_memory.py``.)"""
     lines = lines_of(run_py("--workload", "train_mesh", "--seed", "3",
                             "--seconds", "2", "--trace", "0", "--rehearse",
-                            "--spec", str(spec_file)))
+                            "--spec",
+                            mesh_spec_file(tmp_path, rehearsal_spec)))
     assert lines[-1]["correct"] is True
     assert lines[-1]["device"]["count"] == 4
     assert "train_tokens_per_s" in lines[-1]["rehearsed_metric_names"]
