@@ -58,6 +58,17 @@ def note_score_elements(computed: int, causal: int) -> None:
         pass
 
 
+def note_bwd_call(path: str) -> None:
+    """Drive ``flash.bwd_calls{path}`` at trace time — one inc per
+    backward call traced, ``path`` the residency its shape chose
+    (``resident``, ``merged`` or ``split``)."""
+    try:
+        from ..observability import registry as _reg
+        _reg.counter("flash.bwd_calls", ("path",)).labels(path=path).inc()
+    except Exception:
+        pass
+
+
 def _active_mesh():
     """The global mesh the GSPMD program is being traced for, or None for
     a one-device program and inside a shard_map (manual axes: the caller

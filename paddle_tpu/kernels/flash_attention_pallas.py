@@ -32,20 +32,48 @@ is one masked step, the band as it was.  Non-causal calls and fully
 visible blocks lower to the same kernel body as before.  What the chip
 said (v5e, 16 x 1,024 x 16 heads of 64, PERF.md section 6): steps cost
 beside elements — t = 256 beats t = 128 though it computes more — the
-forward is a third shorter, the merged backward unchanged: its diagonal
-cells take the same time for three quarters of the elements.
+forward is a third shorter, the merged backward unchanged: what it paid
+was around its cells (PR 30: the resident backward).
 
-Backward is ONE merged kernel producing dQ, dK and dV: the textbook
-two-kernel FlashAttention-2 split recomputes the logits and dP matmuls
-twice; merging halves that recompute and saves a launch per layer.
-Grid = (B, n_hg, nk, nq) with both inner dims sequential: dK/dV accumulate
-per key block in scratch (reset at qi==0), dQ accumulates across the whole
-(nk, nq) sweep in a full-sequence f32 scratch written at the final step.
-``_causal_cells`` classifies a grid cell: strictly future (no MXU/VPU
-work; the static grid still streams the prefetch, which is the price of
-pipelining), fully visible (whole, unmasked) or one of the block_q //
-block_k band cells (its live runs of sub-tiles).  Sequences whose dq
-scratch does not fit take the split dq / dkv kernels, same walk.
+Backward: three rungs, the residency chosen from the shape alone
+(``_bwd_plan``; no switch).  The first two produce dQ, dK and dV from ONE
+recompute of the logits and dP (the textbook two-kernel FlashAttention-2
+split, the third rung, recomputes both twice):
+
+- ``resident`` (PR 30) where a (batch, head group)'s whole backward fits
+  VMEM and its walk is short (``_resident_bwd_fits``: s up to 2,048 at the
+  default blocks).  Grid (B, n_hg), both parallel: q, k, v, dO and O and
+  the lse rows come in as whole-sequence blocks, each crossing HBM once
+  while the cell before computes.  It takes O, not delta: ``delta =
+  rowsum(dO * O)`` is formed in the cell, in f32, from rows it already
+  holds, so no caller builds the f32 product (XLA had made it a second
+  output of the output projection's input-gradient GEMM, copied it
+  transposed and reduced it: 64 MB written and read again a layer at the
+  benchmark's shape).  The walk over block pairs is Python-static
+  (``_cell_runs``, the static twin of ``_causal_cells``): pairs strictly
+  in the future are absent, fully visible pairs run whole, band pairs by
+  their live runs under the constant triangle; dq / dk / dv row ranges are
+  sums of a handful of block contributions held as values and stored
+  once, scaled and cast in the store — no full-sequence scratch, nothing
+  zeroed, nothing read back.  The lse cotangent of
+  ``flash_attention_bshd_with_lse`` rides beside the lse rows and is
+  subtracted from delta in the cell.  What the chip said (v5e, PERF.md
+  section 6, PR 30): in GPT-2 345M's step at 16 x 1,024 the kernel takes
+  31.6 ms where the merged one took 40.5 (1.32 ms a layer against about
+  1.1 of half-filled MXU passes at head size 64), delta's passes in XLA
+  (6.6 + 2.4 ms) are gone, 54,968 -> 58,618 tokens/s; at (2 x 2,048, 16
+  heads of 128) a call takes 0.65 ms against 0.86; the smallest
+  lane-aligned head group is as fast as twice it.
+- ``merged`` beyond that, while a full-sequence f32 dq scratch fits
+  (``_DQ_SCRATCH_BUDGET``): grid (B, n_hg, nk, nq), both inner dims
+  sequential; dK/dV accumulate per key block in scratch (reset at qi==0),
+  dQ across the whole (nk, nq) sweep in the full-sequence scratch written
+  at the final step; ``_causal_cells`` classifies a grid cell at run time
+  (strictly-future cells do no work and still stream the prefetch).  It is
+  handed delta, built in XLA.
+- ``split`` beyond that: the dq and dk/dv kernels, O(block) VMEM, same
+  walk, also handed delta.
+
 (History, retired set-up: a fori-style backward, K/V outer and q scanned
 inside, was slower, 47.6k against 49.6k tokens/s on the 345M bench.)
 
@@ -190,6 +218,19 @@ _RESIDENT_KV_BUDGET = 4 * 1024 * 1024
 # 4MB empirically: 8MB of dq scratch plus streamed blocks + dk/dv scratch
 # + lse/delta overflowed the 16MB VMEM by 4.5MB at s=8192.
 _DQ_SCRATCH_BUDGET = 4 * 1024 * 1024
+# VMEM the RESIDENT backward may plan for (_resident_bwd_bytes: its eight
+# whole-sequence blocks, double-buffered by the pipeline, plus one
+# head-cell's temporaries) and the longest static walk it unrolls (nq * nk
+# block pairs a head: 16 compile in 3-14 s, 64 took a minute).  Shapes
+# beyond either take the merged or the split backward.  Found by compiling
+# for a described v5e (tests/test_flash_tpu_compile.py): Mosaic asks
+# within 4% of the plan — 6.3 MB at (s 1,024, hg*d 128), 11.9 MB at
+# (2,048, 128) — and 21.6 MB for float32 operands at the largest shape
+# admitted, hence the call's vmem_limit_bytes (the default scoped limit is
+# 16 MiB of the v5e's 128).
+_RESIDENT_BWD_BUDGET = 12 * 1024 * 1024
+_RESIDENT_BWD_MAX_CELLS = 16
+_RESIDENT_BWD_VMEM_LIMIT = 32 * 1024 * 1024
 
 
 def _aligned_groups(h: int, d: int):
@@ -228,6 +269,53 @@ def _kv_fits_resident(s: int, hgd: int) -> bool:
     """K+V bf16, double-buffered — must match _flash_fwd_inner's dispatch
     between the resident and streamed forward."""
     return s * hgd * 2 * 2 <= _RESIDENT_KV_BUDGET
+
+
+def _resident_bwd_bytes(s: int, sk: int, hgd: int, block_q: int,
+                        block_k: int) -> int:
+    """What a (batch, head group) cell of the resident backward plans to
+    hold: q, dO, O, dq (s rows) and k, v, dk, dv (sk rows) as bf16
+    whole-sequence blocks, double-buffered, and one head-cell's score-sized
+    temporaries (logits, p and dP in f32, p and dS in the operand dtype)."""
+    return 2 * 4 * (s + sk) * hgd * 2 + 16 * block_q * block_k
+
+
+def _resident_bwd_fits(s, sk, hgd, causal, block_q, block_k) -> bool:
+    """Whether the resident backward takes this shape at these blocks: the
+    static walk covers it (a causal call is square — with sk > s whole key
+    blocks would have no visible score and no store), it is short, and the
+    working set is within the budget."""
+    return _valid_blocks(block_q, block_k, s, sk, causal) and \
+        (s == sk or not causal) and \
+        (s // block_q) * (sk // block_k) <= _RESIDENT_BWD_MAX_CELLS and \
+        _resident_bwd_bytes(s, sk, hgd, block_q, block_k) <= \
+        _RESIDENT_BWD_BUDGET
+
+
+def _resident_bwd_group(s, sk, h, d, causal, block_q, block_k):
+    """Heads per cell of the resident backward at this shape, or None: the
+    shape is the merged or the split backward's.  The SMALLEST lane-aligned
+    group (hg*d = 128 where d divides it): the v5e ran it as fast as twice
+    the group at both head sizes (1.461 against 1.475 ms a call at (16 x
+    1,024, 16 x 64), 0.651 against 0.654 at (2 x 2,048, 16 x 128); PERF.md,
+    PR 30) on half the working set and a third of the compile."""
+    hg = _valid_forced_group(h, d) or _aligned_groups(h, d)[-1]
+    if hg * d <= 256 and _resident_bwd_fits(s, sk, hg * d, causal, block_q,
+                                            block_k):
+        return hg
+    return None
+
+
+def _bwd_plan(s, sk, h, d, causal, block_q, block_k, hg_b):
+    """``(path, hg)`` of one differentiated call, from its shape alone:
+    ``resident`` where a (batch, head group)'s whole backward fits VMEM,
+    ``merged`` while the full-sequence dq scratch does, ``split`` beyond."""
+    hg = _resident_bwd_group(s, sk, h, d, causal, block_q, block_k)
+    if hg is not None:
+        return "resident", hg
+    if max(s, sk) * hg_b * d * 4 <= _DQ_SCRATCH_BUDGET:
+        return "merged", hg_b
+    return "split", hg_b
 
 
 def _valid_forced_group(h: int, d: int):
@@ -957,6 +1045,91 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
+def _cell_runs(causal, qi, ki, block_q, block_k, t):
+    """:func:`_causal_cells` for STATIC block indices: the ``[(rows, cols,
+    masked)]`` runs block pair (qi, ki) must attend — the whole pair, its
+    live band runs, or nothing for a pair strictly in the future."""
+    if causal:
+        j = ki - qi * (block_q // block_k)
+        if j >= 0:
+            return _band_runs(block_q, block_k, t, -j * block_k) \
+                if j < block_q // block_k else []
+    return [(slice(0, block_q), slice(0, block_k), False)]
+
+
+def _add_rows(parts, first, x, g):
+    """Add the f32 block ``x``, whose first row is row ``first`` of the
+    head, to the ``g``-row accumulators ``parts`` (by first row; an absent
+    entry is zero: nothing is zeroed, nothing read back)."""
+    for i in range(0, x.shape[0], g):
+        piece = x if x.shape[0] == g else x[i:i + g]
+        parts[first + i] = parts[first + i] + piece \
+            if first + i in parts else piece
+
+
+def _store_rows(ref, sl, parts, g, first, n, factor=None):
+    """Write rows [first, first + n) of a head's output ONCE from their
+    finished accumulators, scaled and cast in the same store."""
+    assert sorted(parts) == list(range(first, first + n, g)), sorted(parts)
+    for r0, x in parts.items():
+        if factor is not None:
+            x = jnp.float32(factor) * x
+        ref[0, r0:r0 + g, sl] = x.astype(ref.dtype)
+
+
+def _bwd_resident_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest,
+                         causal, scale, hg, d, block_q, block_k, tile,
+                         bf16chain=False):
+    """The whole backward of one (batch, head group) in ONE grid cell.
+    q/dO/O/dq: (1, S, HG*D), k/v/dk/dv: (1, SK, HG*D) whole-sequence
+    blocks, each crossing HBM once; lse (and, from
+    flash_attention_bshd_with_lse, the lse cotangent in its place beside
+    it): (1, 1, HG, S) rows.  ``delta = rowsum(dO * O)`` is formed here, in
+    f32 from the rows already held, so no caller builds it.  The walk over
+    block pairs is Python-static (:func:`_cell_runs`): a pair strictly in
+    the future is absent, every dq / dk / dv row range is the sum of a
+    handful of block contributions held as values and stored once."""
+    *dlse_ref, dq_ref, dk_ref, dv_ref = rest
+    s, sk = q_ref.shape[1], k_ref.shape[1]
+    # what the accumulators are cut by: the band's tile under causal
+    # (block_q % block_k == 0 and t divides both), whole blocks otherwise
+    gq, gk = (tile, tile) if causal else (block_q, block_k)
+    tri = _tri_vis(tile) if causal else None
+    for hh in range(hg):
+        sl = slice(hh * d, (hh + 1) * d)
+        stats = {}
+        for q0 in range(0, s, block_q):
+            rows = slice(q0, q0 + block_q)
+            delta = jnp.sum(do_ref[0, rows, sl].astype(jnp.float32) *
+                            o_ref[0, rows, sl].astype(jnp.float32), axis=-1)
+            if dlse_ref:
+                # dS = P * (dP - delta + dlse)
+                delta = delta - dlse_ref[0][0, 0, hh, rows]
+            stats[q0] = (lse_ref[0, 0, hh, rows], delta)        # base-2
+        dq = {}
+        for k0 in range(0, sk, block_k):
+            dk, dv = {}, {}
+            for q0 in range(0, s, block_q):
+                lse, delta = stats[q0]
+                for rows, cols, masked in _cell_runs(
+                        causal, q0 // block_q, k0 // block_k, block_q,
+                        block_k, tile):
+                    whole = rows == slice(0, block_q)
+                    ar = slice(q0 + rows.start, q0 + rows.stop)
+                    ac = slice(k0 + cols.start, k0 + cols.stop)
+                    g = _bwd_head_math(
+                        q_ref[0, ar, sl], k_ref[0, ac, sl], v_ref[0, ac, sl],
+                        do_ref[0, ar, sl], lse if whole else lse[rows],
+                        delta if whole else delta[rows],
+                        tri if masked else None, scale, bf16chain)
+                    _add_rows(dv, ac.start, g["dv"], gk)
+                    _add_rows(dk, ac.start, g["dk"], gk)
+                    _add_rows(dq, ar.start, g["dq"], gq)
+            _store_rows(dk_ref, sl, dk, gk, k0, block_k, scale)
+            _store_rows(dv_ref, sl, dv, gk, k0, block_k)
+        _store_rows(dq_ref, sl, dq, gq, 0, s, scale)
+
+
 #: the backward builders are jitted like the forward's: one traced kernel
 #: for all the layers of a model (operands dynamic, the rest static)
 _BWD_JIT = functools.partial(jax.jit,
@@ -1101,11 +1274,48 @@ def _bwd_merged(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
     )(q3, k3, v3, do3, lse5, delta5)
 
 
+@_BWD_JIT
+def _bwd_resident(q3, k3, v3, do3, o3, rows, causal, scale, hg, d, spec,
+                  interpret, tile):
+    """The resident backward's pallas_call: grid (b, h // hg), both
+    parallel.  ``rows``: (lse,) or (lse, dlse) — f32 row statistics in any
+    fold of (b, h, s)."""
+    variant, block_q, block_k = spec
+    feats = variant_features(variant, _BWD_FEATURES)
+    b, s, hd = q3.shape
+    sk = k3.shape[1]
+    n_hg = hd // (hg * d)
+    hgd = hg * d
+    q_spec = pl.BlockSpec((1, s, hgd), lambda bi, g: (bi, 0, g))
+    kv_spec = pl.BlockSpec((1, sk, hgd), lambda bi, g: (bi, 0, g))
+    row_spec = pl.BlockSpec((1, 1, hg, s), lambda bi, g: (bi, g, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_resident_kernel, causal=causal, scale=scale,
+                          hg=hg, d=d, block_q=block_q, block_k=block_k,
+                          tile=tile, bf16chain="bf16chain" in feats),
+        grid=(b, n_hg),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec] +
+        [row_spec] * len(rows),
+        out_specs=[q_spec, kv_spec, kv_spec],
+        out_shape=[
+            _sds((b, s, hd), q3.dtype, q3),
+            _sds((b, sk, hd), k3.dtype, k3),
+            _sds((b, sk, hd), v3.dtype, v3),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_RESIDENT_BWD_VMEM_LIMIT),
+        name="flash_bwd",
+        interpret=interpret,
+    )(q3, k3, v3, do3, o3, *(r.reshape(b, n_hg, hg, s) for r in rows))
+
+
 def _bwd_entry(builder):
     """``call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
-    interpret)`` over a jitted builder — the production entry and the
-    autotuner's runner entry; the band's tile is settled out here, where
-    the builder's trace cache can see it."""
+    interpret)`` over a jitted builder (the resident one takes ``o3`` and
+    its row statistics where the others take ``lse`` and ``delta``) — the
+    production entry and the autotuner's runner entry; the band's tile is
+    settled out here, where the builder's trace cache can see it."""
     def call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
              interpret):
         return builder(q3, k3, v3, do3, lse, delta, causal, scale, hg, d,
@@ -1116,27 +1326,38 @@ def _bwd_entry(builder):
 _bwd_dq_call = _bwd_entry(_bwd_dq)
 _bwd_dkv_call = _bwd_entry(_bwd_dkv)
 _bwd_merged_call = _bwd_entry(_bwd_merged)
+_bwd_resident_call = _bwd_entry(_bwd_resident)
 
 
 def _flash_bwd(q3, k3, v3, o3, lse, do3, causal, scale, d, interpret, spec,
                dlse=None):
-    # dlse: optional (b, s, h) f32 cotangent of a base-e lse OUTPUT
-    # (flash_attention_bshd_with_lse): it folds into the kernels as
-    # delta - dlse — dS_ij = P_ij (dP_ij - delta_i + dlse_i), so the
-    # existing kernels run unchanged.
-    # spec: ("merged", variant, block_q, block_k, hg) or
+    # dlse: optional (b, h, s) f32 rows, the cotangent of a base-e lse
+    # OUTPUT (flash_attention_bshd_with_lse): dS_ij = P_ij (dP_ij - delta_i
+    # + dlse_i), so it enters as delta - dlse.
+    # spec: ("resident" | "merged", variant, block_q, block_k, hg) or
     #       ("split", (variant, bq, bk), (variant, bq, bk), hg) — decided
-    # by the wrapper (default: merged while the full-seq dq scratch fits).
+    # by _resolve_specs from the shape (_bwd_plan).
+    from .flash_attention import note_bwd_call
+    note_bwd_call(spec[0])
     with x64_scope(False):
         b, s, hd = q3.shape
         h = hd // d
-        # delta = rowsum(dO * O) per head — cheap, fused by XLA; folded to
-        # the kernels' (b, n_hg, hg, nq, bq) row layout per call
+        if spec[0] == "resident":
+            # the kernel takes O and forms delta itself; the lse
+            # cotangent's rows ride beside the lse rows
+            _, variant, block_q, block_k, hg = spec
+            rows = (lse,) if dlse is None else \
+                (lse, dlse.astype(jnp.float32))
+            return _bwd_resident_call(q3, k3, v3, do3, o3, rows, causal,
+                                      scale, hg, d,
+                                      (variant, block_q, block_k), interpret)
+        # delta = rowsum(dO * O) per head in XLA, folded to the kernels'
+        # (b, n_hg, hg, nq, bq) row layout per call
         delta = jnp.sum(
             do3.reshape(b, s, h, d).astype(jnp.float32) *
             o3.reshape(b, s, h, d).astype(jnp.float32), axis=-1)  # (b,s,h)
         if dlse is not None:
-            delta = delta - dlse.astype(jnp.float32)
+            delta = delta - jnp.moveaxis(dlse.astype(jnp.float32), 1, -1)
         if spec[0] == "split":
             _, dq_spec, dkv_spec, hg = spec
             dq = _bwd_dq_call(q3, k3, v3, do3, lse, delta, causal, scale,
@@ -1233,10 +1454,9 @@ def _flash_lse_vjp_bwd(causal, scale, d, interpret, fwd_spec, bwd_spec,
     dout, dlse2 = g
     b, s, hd = q3.shape
     h = hd // d
-    # unfold the (b, n_hg, hg, nq, bq) base-2 lse cotangent to (b, s, h)
+    # the (b, n_hg, hg, nq, bq) base-2 lse cotangent as (b, h, s) rows,
     # base-e: lse2 = lse_e * log2e, so dlse_e = dlse2 * log2e
-    dlse = jnp.moveaxis(
-        dlse2.reshape(b, h, s), 1, -1) * jnp.float32(_LOG2E)
+    dlse = dlse2.reshape(b, h, s) * jnp.float32(_LOG2E)
     return _flash_bwd(q3, k3, v3, out, lse2, dout, causal, scale, d,
                       interpret, bwd_spec, dlse=dlse)
 
@@ -1297,14 +1517,24 @@ def _sane_bwd_blocks(cand, s, sk, causal, default):
     return (cand["variant"], bq, bk)
 
 
-def _sane_bwd_merged(cand, s, sk, h, d, causal, default):
-    cfg = cand.get("config", {})
-    hg = cfg.get("hg")
+def _bwd_group_fits(path, s, sk, hg, d, causal, bq, bk) -> bool:
+    """Whether the ``flash_bwd`` family's kernel on ``path`` (resident, or
+    the merged one) can hold this head group at these blocks."""
+    if path == "resident":
+        return _resident_bwd_fits(s, sk, hg * d, causal, bq, bk)
+    return max(s, sk) * hg * d * 4 <= _DQ_SCRATCH_BUDGET
+
+
+def _sane_bwd_grouped(path, cand, s, sk, h, d, causal, default):
+    """A resolved/pinned ``flash_bwd`` candidate as the ``path`` (resident
+    or merged) the shape chose: off-spec blocks, variant or head group, or
+    a working set the path cannot hold, fall back to the default."""
+    hg = cand.get("config", {}).get("hg")
     variant, bq, bk = _sane_bwd_blocks(cand, s, sk, causal, default[:2])
-    if not _valid_hg(hg, h, d) or \
-            max(s, sk) * hg * d * 4 > _DQ_SCRATCH_BUDGET:
-        return ("merged", "base") + default
-    return ("merged", variant, bq, bk, hg)
+    if not (_valid_hg(hg, h, d) and
+            _bwd_group_fits(path, s, sk, hg, d, causal, bq, bk)):
+        return (path, "base") + default
+    return (path, variant, bq, bk, hg)
 
 
 def _resolve_specs(b, s, sk, h, d, dtype, causal, block_q, block_k, hg_f,
@@ -1314,22 +1544,23 @@ def _resolve_specs(b, s, sk, h, d, dtype, causal, block_q, block_k, hg_f,
     caller-pinned block sizes (``use_autotune=False``) bypass the autotuner
     entirely (the A/B and parity-test entry); otherwise the specs resolve
     through autotune.resolve() with the hand-tuned values as the registered
-    defaults — identical programs until tuning runs."""
-    split = max(s, sk) * hg_b * d * 4 > _DQ_SCRATCH_BUDGET
+    defaults — identical programs until tuning runs.  The backward's path
+    (:func:`_bwd_plan`) is the shape's in both cases."""
+    path, hg_b = _bwd_plan(s, sk, h, d, causal, block_q, block_k, hg_b)
     if variant is not None or not use_autotune:
         variant = variant or "base"
         fv = canon_variant(variant_features(variant, _FWD_FEATURES))
         bv = bwd_variant_of(variant)
         fwd_spec = (fv, block_q, block_k, hg_f)
         bwd_spec = (("split", (bv, block_q, block_k),
-                     (bv, block_q, block_k), hg_b) if split
-                    else ("merged", bv, block_q, block_k, hg_b))
+                     (bv, block_q, block_k), hg_b) if path == "split"
+                    else (path, bv, block_q, block_k, hg_b))
         return fwd_spec, bwd_spec
     from . import autotune as at
     key = autotune_key(b, s, sk, h, d, dtype, causal)
     fwd_spec = _sane_fwd_spec(at.resolve("flash_fwd", key), s, sk, h, d,
                               causal, (block_q, block_k, hg_f))
-    if split:
+    if path == "split":
         bwd_spec = ("split",
                     _sane_bwd_blocks(at.resolve("flash_bwd_dq", key),
                                      s, sk, causal, (block_q, block_k)),
@@ -1337,18 +1568,19 @@ def _resolve_specs(b, s, sk, h, d, dtype, causal, block_q, block_k, hg_f,
                                      s, sk, causal, (block_q, block_k)),
                     hg_b)
     else:
-        bwd_spec = _sane_bwd_merged(at.resolve("flash_bwd", key),
-                                    s, sk, h, d, causal,
-                                    (block_q, block_k, hg_b))
-    if tie_groups:
+        bwd_spec = _sane_bwd_grouped(path, at.resolve("flash_bwd", key),
+                                     s, sk, h, d, causal,
+                                     (block_q, block_k, hg_b))
+    if tie_groups and path != "resident":
         # one group for both directions: the lse OUTPUT layout must match
         # what the caller-visible (b, s, h) unfold assumes alongside the
         # backward's consumption (flash_attention_bshd_with_lse).  A tuned
         # fwd winner with a DIFFERENT head group is discarded for the
         # hand-tuned default rather than silently re-grouped — the
         # (variant, blocks, hg) combination after a re-group was never
-        # timed, and alternate-hg candidates differ ONLY by hg.
-        hg = bwd_spec[4] if bwd_spec[0] == "merged" else bwd_spec[3]
+        # timed, and alternate-hg candidates differ ONLY by hg.  (The
+        # resident backward reads the rows in any fold of (b, h, s).)
+        hg = bwd_spec[3] if path == "split" else bwd_spec[4]
         if fwd_spec[3] != hg:
             fwd_spec = ("base", block_q, block_k, hg)
     return fwd_spec, bwd_spec
@@ -1411,12 +1643,27 @@ def _fwd_candidates(key):
     return cands
 
 
-def _bwd_candidates_merged(key):
+def _key_bwd_plan(key):
+    """``(path, bq0, bk0, hg)``: what production runs for ``key`` by
+    default — the ``flash_bwd`` family's first candidate."""
     s, sk, h, d, causal = (key[k] for k in ("s", "sk", "h", "d", "causal"))
-    bq0, bk0, hg_f, hg_b = _default_cfg(key)
+    bq0, bk0, _, hg_b = _default_cfg(key)
+    path, hg = _bwd_plan(s, sk, h, d, causal, bq0, bk0, hg_b)
+    return path, bq0, bk0, hg
+
+
+def _bwd_candidates_merged(key):
+    """The ``flash_bwd`` family's candidates: for the resident backward
+    where the shape takes it, the merged one elsewhere, each within what
+    that path can hold."""
+    s, sk, h, d, causal = (key[k] for k in ("s", "sk", "h", "d", "causal"))
+    path, bq0, bk0, hg_b = _key_bwd_plan(key)
     cands = [{"variant": "base",
               "config": {"block_q": bq0, "block_k": bk0, "hg": hg_b}}]
     for bq, bk in _candidate_blocks(s, sk, causal, bq0, bk0):
+        if (bq, bk) != (bq0, bk0) and not _bwd_group_fits(
+                path, s, sk, hg_b, d, causal, bq, bk):
+            continue
         for v in (["base"] if (bq, bk) != (bq0, bk0) else []) + \
                 list(_CAND_BWD_VARIANTS):
             cand = {"variant": v,
@@ -1425,7 +1672,7 @@ def _bwd_candidates_merged(key):
                 cands.append(cand)
     for hg in _aligned_groups(h, d):
         if hg != hg_b and hg * d <= 256 and \
-                max(s, sk) * hg * d * 4 <= _DQ_SCRATCH_BUDGET:
+                _bwd_group_fits(path, s, sk, hg, d, causal, bq0, bk0):
             cands.append({"variant": "base",
                           "config": {"block_q": bq0, "block_k": bk0,
                                      "hg": hg}})
@@ -1503,26 +1750,37 @@ def _bwd_runner(which):
         causal, d = key["causal"], key["d"]
         hg = cfg.get("hg", data["hg_b"])
         spec = (cand["variant"], cfg["block_q"], cfg["block_k"])
-        call = {"merged": _bwd_merged_call, "dq": _bwd_dq_call,
-                "dkv": _bwd_dkv_call}[which]
+        call, rows = _bwd_family_call(which, key)
 
-        def timed(q, k, v, do, lse, delta):
+        def timed(q, k, v, do, *rest):
             # same x64-off trace scope as the production entry
             # (_flash_bwd) — under the global x64 mode the candidate
             # would otherwise lower a different (or unlowerable) program
             # than the one production runs
             with x64_scope(False):
-                return call(q, k, v, do, lse, delta, causal,
-                            data["scale"], hg, d, spec,
-                            data["interpret"])
+                return call(q, k, v, do, *rest, causal, data["scale"], hg,
+                            d, spec, data["interpret"])
         fn = jax.jit(timed)
 
         def run():
             jax.block_until_ready(fn(
                 data["q3"], data["k3"], data["v3"], data["do3"],
-                data["lse"], data["delta"]))
+                *rows(data["out"], data["lse"], data["delta"])))
         return run
     return make
+
+
+def _bwd_family_call(which, key):
+    """``(call, rows)`` of a backward family at ``key``: the entry the
+    family's candidates run through, and ``rows(out, lse, delta)`` — the
+    two operands it takes after dO (the resident backward, which the
+    ``flash_bwd`` family is wherever the shape takes it, wants O and the
+    lse rows; the others lse and delta)."""
+    if which == "merged" and _key_bwd_plan(key)[0] == "resident":
+        return _bwd_resident_call, lambda out, lse, delta: (out, (lse,))
+    return ({"merged": _bwd_merged_call, "dq": _bwd_dq_call,
+             "dkv": _bwd_dkv_call}[which],
+            lambda out, lse, delta: (lse, delta))
 
 
 def _runner_cleanup(key):
@@ -1561,20 +1819,21 @@ def _bwd_traceable(which):
         hg = cfg.get("hg", hg_b)
         spec = (cand["variant"], cfg["block_q"], cfg["block_k"])
         scale = 1.0 / d ** 0.5
-        call = {"merged": _bwd_merged_call, "dq": _bwd_dq_call,
-                "dkv": _bwd_dkv_call}[which]
+        call, rows = _bwd_family_call(which, key)
 
-        def fn(q, k, v, do, lse, delta):
+        def fn(q, k, v, do, *rest):
             with x64_scope(False):
-                return call(q, k, v, do, lse, delta, causal, scale, hg, d,
-                            spec, interpret)
+                return call(q, k, v, do, *rest, causal, scale, hg, d, spec,
+                            interpret)
         sds = jax.ShapeDtypeStruct
         # lse/delta in the layout the default forward produces (what the
         # production bwd — and the timed runner — actually receives)
         return fn, (sds((b, s, h * d), dtype), sds((b, sk, h * d), dtype),
                     sds((b, sk, h * d), dtype), sds((b, s, h * d), dtype),
-                    sds((b, h // hg_b, hg_b, s // bq0, bq0), jnp.float32),
-                    sds((b, s, h), jnp.float32))
+                    *rows(sds((b, s, h * d), dtype),
+                          sds((b, h // hg_b, hg_b, s // bq0, bq0),
+                              jnp.float32),
+                          sds((b, s, h), jnp.float32)))
     return make
 
 

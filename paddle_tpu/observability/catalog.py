@@ -238,6 +238,14 @@ CATALOG = {
         "kernels' work that is multiplied by zero.  Trace-time like "
         "mp.overlap_chunks: a compile-once program contributes once",
         labels=("which",)),
+    "flash.bwd_calls": _m(
+        "counter", "differentiated flash calls traced so far by the "
+        "residency their shape chose: path='resident' (one grid cell a "
+        "(batch, head group), takes O and forms delta itself), 'merged' "
+        "(the (nk, nq) grid walk over a full-sequence dq scratch) or "
+        "'split' (dq and dk/dv kernels).  Trace-time, one inc a backward "
+        "call: a compile-once program contributes once",
+        labels=("path",)),
 
     # -- compile watchdog ---------------------------------------------------
     "compile.count": _m(

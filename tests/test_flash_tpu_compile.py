@@ -38,25 +38,40 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-# (b, s, h, d, resident forward + merged backward?)
+# (b, s, h, d, the backward's path, resident forward?)
 CELLS = [
-    pytest.param(16, 1024, 16, 64, True, id="gpt2-medium-s1024"),
-    pytest.param(4, 2048, 16, 128, True, id="cerebras-1.3b-s2048"),
-    pytest.param(1, 16384, 16, 64, False, id="gpt2-medium-s16384-streamed"),
+    pytest.param(16, 1024, 16, 64, "resident", True, id="gpt2-medium-s1024"),
+    pytest.param(4, 2048, 16, 128, "resident", True,
+                 id="cerebras-1.3b-s2048"),
+    pytest.param(1, 16384, 16, 64, "split", False,
+                 id="gpt2-medium-s16384-streamed"),
+    pytest.param(1, 4096, 16, 64, "merged", True, id="gpt2-medium-s4096"),
+    pytest.param(4, 2048, 8, 128, "resident", True,
+                 id="cerebras-1.3b-s2048-mp2-shard"),
 ]
 
 
-@pytest.mark.parametrize("b,s,h,d,short", CELLS)
-def test_causal_flash_compiles_for_v5e(one_chip, no_persistent_cache, b, s,
-                                       h, d, short):
+def _specs(b, s, h, d, causal, dtype=jnp.bfloat16):
     hg_b = fap._pick_head_group(h, d, s)
     hg_f = fap._pick_fwd_head_group(h, d, s, hg_b)
-    bq, bk = fap._prep_blocks(s, s, True, fap.DEFAULT_BLOCK_Q,
+    bq, bk = fap._prep_blocks(s, s, causal, fap.DEFAULT_BLOCK_Q,
                               fap.DEFAULT_BLOCK_K, "test")
-    fwd_spec, bwd_spec = fap._resolve_specs(
-        b, s, s, h, d, jnp.bfloat16, True, bq, bk, hg_f, hg_b,
-        use_autotune=False)
-    assert (bwd_spec[0] == "merged") == short
+    return hg_f, fap._resolve_specs(b, s, s, h, d, dtype, causal, bq, bk,
+                                    hg_f, hg_b, use_autotune=False)
+
+
+def _compiled_text(fn, *args):
+    # the suite asks for f32 matmul passes (conftest); the chip runs the
+    # default precision, and Mosaic has no f32 pass over bf16 operands
+    with jax.default_matmul_precision("default"):
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("b,s,h,d,path,short", CELLS)
+def test_causal_flash_compiles_for_v5e(one_chip, no_persistent_cache, b, s,
+                                       h, d, path, short):
+    hg_f, (fwd_spec, bwd_spec) = _specs(b, s, h, d, True)
+    assert bwd_spec[0] == path
     assert fap._kv_fits_resident(s, hg_f * d) == short
     scale = 1.0 / d ** 0.5
 
@@ -66,9 +81,42 @@ def test_causal_flash_compiles_for_v5e(one_chip, no_persistent_cache, b, s,
                               bwd_spec)
 
     x = jax.ShapeDtypeStruct((b, s, h * d), jnp.bfloat16, sharding=one_chip)
-    # the suite asks for f32 matmul passes (conftest); the chip runs the
-    # default precision, and Mosaic has no f32 pass over bf16 operands
-    with jax.default_matmul_precision("default"):
-        text = jax.jit(step).lower(x, x, x, x).compile().as_text()
+    text = _compiled_text(step, x, x, x, x)
     assert text.count('custom_call_target="tpu_custom_call"') == \
-        (2 if short else 3)
+        (3 if path == "split" else 2)
+    # delta: the merged and split kernels are handed it (an f32 product of
+    # dO and O in XLA), the resident one forms it from O
+    assert ("f32[%d,%d,%d]" % (b, s, h * d) in text) == (path != "resident")
+
+
+# what else the resident backward is asked for: (b, s, h, d, causal,
+# dtype, lse cotangent?) — full attention (every block pair live), the
+# ring-attention inner's lse cotangent rows, float32 operands at the
+# largest shape the rule admits (twice the bytes it plans with)
+RESIDENT = [
+    pytest.param(16, 1024, 16, 64, False, jnp.bfloat16, False, id="full"),
+    pytest.param(4, 2048, 16, 128, False, jnp.bfloat16, False,
+                 id="full-s2048-d128"),
+    pytest.param(16, 1024, 16, 64, True, jnp.bfloat16, True, id="dlse"),
+    pytest.param(2, 2048, 16, 128, True, jnp.float32, True,
+                 id="float32-dlse-s2048-d128"),
+]
+
+
+@pytest.mark.parametrize("b,s,h,d,causal,dtype,with_dlse", RESIDENT)
+def test_resident_backward_compiles_for_v5e(one_chip, no_persistent_cache,
+                                            b, s, h, d, causal, dtype,
+                                            with_dlse):
+    _, (_, bwd_spec) = _specs(b, s, h, d, causal, dtype)
+    assert bwd_spec[0] == "resident"
+    scale = 1.0 / d ** 0.5
+
+    def bwd(q, k, v, do, out, lse, *dlse):
+        return fap._flash_bwd(q, k, v, out, lse, do, causal, scale, d,
+                              False, bwd_spec, dlse=dlse[0] if dlse else None)
+
+    x = jax.ShapeDtypeStruct((b, s, h * d), dtype, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((b, h, s), jnp.float32, sharding=one_chip)
+    text = _compiled_text(bwd, x, x, x, x, x, rows,
+                          *([rows] if with_dlse else []))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1

@@ -125,14 +125,15 @@ def test_variant_split_backward_parity(variant):
     ct = jnp.asarray(rng.randn(b, s, h, d), jnp.float32) * 0.1
 
     def loss(q, k, v, budget):
-        old = fap._DQ_SCRATCH_BUDGET
-        fap._DQ_SCRATCH_BUDGET = budget
+        # the resident rung off: this is merged against split
+        old = fap._DQ_SCRATCH_BUDGET, fap._RESIDENT_BWD_BUDGET
+        fap._DQ_SCRATCH_BUDGET, fap._RESIDENT_BWD_BUDGET = budget, 0
         try:
             out = fap.flash_attention_bshd_native(
                 q, k, v, causal=True, block_q=256, block_k=256,
                 interpret=True, variant=variant)
         finally:
-            fap._DQ_SCRATCH_BUDGET = old
+            fap._DQ_SCRATCH_BUDGET, fap._RESIDENT_BWD_BUDGET = old
         return jnp.sum(out * ct)
 
     g_merged = jax.grad(loss, argnums=(0, 1, 2))(q, k, v, 4 * 1024 * 1024)
@@ -226,8 +227,11 @@ def _ref_bshd(q, k, v):
 def _band_parity(monkeypatch, tile, d, block_q, block_k, variant=None,
                  resident=True, merged=True, s=1024):
     """Forward and gradients of one causal call whose diagonal blocks are
-    walked in ``tile``-edge sub-tiles, against the O(S^2) reference."""
+    walked in ``tile``-edge sub-tiles, against the O(S^2) reference.  The
+    backward is the merged kernel, or the split pair: the resident one
+    (which these shapes would take) has tests/test_flash_resident_bwd.py."""
     monkeypatch.setattr(fap, "_BAND_TILE", tile)
+    monkeypatch.setattr(fap, "_RESIDENT_BWD_BUDGET", 0)
     if not resident:
         monkeypatch.setattr(fap, "_RESIDENT_KV_BUDGET", 1)
     if not merged:
@@ -383,6 +387,9 @@ def _kernel_bodies(jaxpr):
 def _noncausal_kernels_hash(monkeypatch, family):
     import hashlib
     import re
+    # the merged and split kernels: the resident backward, which this
+    # shape would take, did not exist at that commit
+    monkeypatch.setattr(fap, "_RESIDENT_BWD_BUDGET", 0)
     if family == "streamed+split":
         monkeypatch.setattr(fap, "_RESIDENT_KV_BUDGET", 1)
         monkeypatch.setattr(fap, "_DQ_SCRATCH_BUDGET", 1)

@@ -158,14 +158,15 @@ def test_flash_bwd_split_long_seq_parity():
     ct = jnp.asarray(rng.randn(b, s, h, d), jnp.float32) * 0.1
 
     def loss(q, k, v, budget):
-        old = fap._DQ_SCRATCH_BUDGET
-        fap._DQ_SCRATCH_BUDGET = budget
+        # the resident rung off: this is merged against split
+        old = fap._DQ_SCRATCH_BUDGET, fap._RESIDENT_BWD_BUDGET
+        fap._DQ_SCRATCH_BUDGET, fap._RESIDENT_BWD_BUDGET = budget, 0
         try:
             out = fap.flash_attention_bshd_native(
                 q, k, v, causal=True, block_q=256, block_k=256,
                 interpret=True)
         finally:
-            fap._DQ_SCRATCH_BUDGET = old
+            fap._DQ_SCRATCH_BUDGET, fap._RESIDENT_BWD_BUDGET = old
         return jnp.sum(out * ct)
 
     # merged path (budget comfortably fits s*hgd*4 = 512KB)

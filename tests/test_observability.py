@@ -394,11 +394,13 @@ def test_catalog_coverage_is_two_way(monkeypatch, tmp_path):
     at.tune("ln", nop.autotune_key(8, 64, jnp.float32), persist=False)
     at.resolve("ln", nop.autotune_key(8, 64, jnp.float32))
 
-    # -- flash kernels: one traced causal call counts its score elements ---
+    # -- flash kernels: one traced causal call counts its score elements,
+    # its backward the residency the shape chose (flash.bwd_calls{path}) ---
     from paddle_tpu.kernels.flash_attention_pallas import \
         flash_attention_bshd_native
     qkv = jnp.zeros((1, 128, 2, 64), jnp.float32)
-    flash_attention_bshd_native(qkv, qkv, qkv, causal=True, interpret=True)
+    jax.grad(lambda x: jnp.sum(flash_attention_bshd_native(
+        x, x, x, causal=True, interpret=True)))(qkv)
 
     # -- HBM ledger: one armed sample prices live arrays + KV pools --------
     hbm.enable()
