@@ -41,11 +41,6 @@ def main():
         batch, seq, steps, warmup = 8, 1024, 48, 5
         batch = int(os.getenv("PADDLE_TPU_BENCH_BATCH", batch))
         seq = int(os.getenv("PADDLE_TPU_BENCH_SEQ", seq))
-        # scan-over-layers (natively stacked params): A/B'd round 5
-        cfg.scan_layers = os.getenv("PADDLE_TPU_BENCH_SCAN", "0") == "1"
-        cfg.scan_unroll = int(os.getenv("PADDLE_TPU_BENCH_SCAN_UNROLL",
-                                        cfg.num_hidden_layers))
-        cfg.scan_mode = os.getenv("PADDLE_TPU_BENCH_SCAN_MODE", "scan")
     else:  # CPU smoke config so bench.py always runs
         cfg = GPTConfig.tiny()
         batch, seq, steps, warmup = 2, 64, 4, 1

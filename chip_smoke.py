@@ -480,13 +480,11 @@ def kernel_pairs(rehearse: bool):
         return autotune.standard_keys()
     # the families at shapes the interpreter finishes in seconds
     from paddle_tpu.distributed import mp_overlap
-    from paddle_tpu.kernels import ce_pallas, decode_attention, norm_pallas
+    from paddle_tpu.kernels import decode_attention
     flash = fap.autotune_key(b=1, s=256, sk=256, h=4, d=64, dtype="float32",
                              causal=True)
     return [("flash_fwd", flash), ("flash_bwd", flash),
             ("flash_bwd_dq", flash), ("flash_bwd_dkv", flash),
-            ("ce_lse", ce_pallas.autotune_key(n=64, v=2048, dtype="float32")),
-            ("ln", norm_pallas.autotune_key(n=64, f=256, dtype="float32")),
             ("decode_attn_paged", decode_attention.paged_autotune_key(
                 slots=2, pages=8, page_size=16, max_pages=4, h=2, d=64,
                 qlen=1, dtype="float32")),

@@ -96,12 +96,7 @@ def decorate(models, optimizers=None, level="O2", dtype="bfloat16",
             for layer in m.sublayers(include_self=True):
                 if isinstance(layer, (_BatchNormBase, LayerNorm)):
                     continue  # keep norms fp32 (reference keep_batch_norm_fp32)
-                # layers holding norm params inline (e.g. GPTScanBlocks'
-                # stacked LN arrays) declare them by name
-                keep = getattr(layer, "_amp_keep_fp32_params", ())
-                for name, p in layer._parameters.items():
-                    if name in keep:
-                        continue
+                for p in layer._parameters.values():
                     if p is not None and p.dtype == np.dtype("float32"):
                         p._array = p._array.astype(dtype)
     if optimizers is None:

@@ -247,106 +247,6 @@ def load(path, **config):
     return TranslatedLayer(predictor)
 
 
-import re as _re
-
-_LAYER_IDX_RE = _re.compile(r"\.(\d+)\.")
-
-#: step-state key holding the single flat f32 master buffer (flat_master mode)
-_FLAT_KEY = "__flat_master__"
-
-#: params at or above this element count stay out of the flat buffer: the
-#: huge arrays (GPT-2's 51.5M-element wte) already run their optimizer
-#: fusion at ~700 GB/s (PERF.md trace) — flattening them would only add
-#: concat traffic for no bandwidth win.  The 4-16 MB per-layer params are
-#: the ones XLA updates at ~250 GB/s, and those are what the buffer packs.
-_FLAT_MAX_ELEMS = 1 << 25
-
-
-def _make_flat_unflatten(groups):
-    """flat 1-D f32 master buffer -> tuple of per-parameter COMPUTE-dtype
-    views.  ``groups`` = [(dtype_or_None, g0, g1, [(rel_off, size, shape),
-    ...]), ...] with same-compute-dtype members contiguous in the buffer.
-
-    Two measured failure modes shape this design (PERF.md):
-
-    * jax's default slice vjp is pad-into-zeros-and-add — the scatter that
-      sank the stacked-params experiment.  custom_vjp makes the backward
-      ONE concatenate per dtype group (the exact cotangent for disjoint
-      static slices) + one group upcast.
-    * casting f32->bf16 per *member* view re-creates ~150 small XLA
-      fusions (measured 26.5 ms/step of ``convert_bitcast_fusion`` — the
-      same per-fusion overhead the flat buffer exists to kill, moved from
-      the update to the cast).  So each dtype group is cast ONCE as a big
-      contiguous segment; the member views are then contiguous
-      slice+reshape = free bitcasts XLA folds into the consumers.
-    """
-    @jax.custom_vjp
-    def unflatten(flat):
-        views = []
-        for dt, g0, g1, members in groups:
-            seg = jax.lax.slice(flat, (g0,), (g1,))
-            if dt is not None:
-                seg = seg.astype(dt)
-            for off, size, shp in members:
-                views.append(
-                    jax.lax.slice(seg, (off,), (off + size,)).reshape(shp))
-        return tuple(views)
-
-    def fwd(flat):
-        return unflatten(flat), None
-
-    def bwd(_, cots):
-        segs, i = [], 0
-        for dt, g0, g1, members in groups:
-            seg = jnp.concatenate(
-                [jnp.asarray(c).reshape(-1)
-                 for c in cots[i:i + len(members)]])
-            segs.append(seg.astype(jnp.float32))
-            i += len(members)
-        return (segs[0] if len(segs) == 1 else jnp.concatenate(segs),)
-
-    unflatten.defvjp(fwd, bwd)
-    return unflatten
-
-
-def _stack_layout(params):
-    """Group parameter names that differ only in ONE numeric segment (the
-    repeated-layer index, e.g. ``gpt.h.{0..23}.attn.qkv_proj.weight``) and
-    whose shapes match.  Returns {template: [names in index order]} for
-    groups of size > 1.
-
-    Rationale: holding each of a deep model's ~300 per-layer params as its
-    own array makes the optimizer update ~300 small XLA fusions running at
-    ~250 GB/s where stacked (L, ...) arrays run at ~700 GB/s.  MEASURED
-    OUTCOME (PERF.md): the per-layer slice views' grad transpose costs more
-    than the update saves on the GPT-2 345M bench (49.8k vs 52.2k
-    tokens/s), so TrainStep(stack_layers=...) defaults OFF; the machinery
-    stays as an opt-in for shapes where the trade goes the other way.  The
-    stack is INTERNAL to TrainStep: state_dict()/sync_to_model still speak
-    per-layer names.
-    """
-    groups = {}
-    for name, arr in params.items():
-        hits = _LAYER_IDX_RE.findall(name)
-        if len(hits) != 1:
-            continue
-        template = _LAYER_IDX_RE.sub(".#.", name)
-        groups.setdefault(template, []).append((int(hits[0]), name))
-    layout = {}
-    for template, members in groups.items():
-        if len(members) < 2:
-            continue
-        members.sort()
-        idxs = [i for i, _n in members]
-        names = [n for _i, n in members]
-        shapes = {params[n].shape for n in names}
-        dtypes = {params[n].dtype for n in names}
-        if idxs == list(range(len(idxs))) and len(shapes) == 1 \
-                and len(dtypes) == 1:
-            layout[template] = names
-    return layout
-
-
 class TrainStep:
     """One fused, compiled training step: forward + backward + optimizer.
 
@@ -369,9 +269,7 @@ class TrainStep:
 
     def __init__(self, model: Layer, loss_fn: Callable, optimizer,
                  num_inputs: int = 1, in_shardings=None, donate=True,
-                 zero_stage: Optional[int] = None, zero_axis: str = "sdp",
-                 stack_layers: bool = False,
-                 flat_master: Optional[bool] = None):
+                 zero_stage: Optional[int] = None, zero_axis: str = "sdp"):
         self.model = model
         self.loss_fn = loss_fn
         self.optimizer = optimizer
@@ -394,13 +292,6 @@ class TrainStep:
         # twice per step — neither buffer can donation-alias the other —
         # measured ~15 ms/step of pure copies on the GPT-2 345M bench
         # (PERF.md "copy lane").
-        # stack layout computed on ORIGINAL dtypes: groups whose members
-        # mix dtypes (e.g. a partially AMP-decorated layer list) fail the
-        # uniformity check here and stay unstacked — after the f32 master
-        # promotion below everything is f32 and the mix would be invisible
-        self._stack = _stack_layout(self.params) if stack_layers else {}
-        self._stacked_names = {n for names in self._stack.values()
-                               for n in names}
         self._compute_dtypes = {}
         if getattr(optimizer, "_multi_precision", None) is not False:
             for k, v in list(self.params.items()):
@@ -408,72 +299,6 @@ class TrainStep:
                                                        jnp.float16):
                     self._compute_dtypes[k] = v.dtype
                     self.params[k] = v.astype(jnp.float32)
-        for template, names in self._stack.items():
-            self.params[template] = jnp.stack(
-                [self.params.pop(n) for n in names])
-            if names[0] in self._compute_dtypes:
-                # sound: the layout's dtype-uniformity check (pre-promotion)
-                # guarantees every member shared names[0]'s compute dtype
-                self._compute_dtypes[template] = self._compute_dtypes[
-                    names[0]]
-                for n in names:
-                    self._compute_dtypes.pop(n, None)
-
-        # ---- flat master buffer ------------------------------------------
-        # One 1-D f32 array holding every small/mid trainable master; the
-        # optimizer update over it is ONE fusion running at big-array HBM
-        # bandwidth (~700 GB/s) instead of ~150 per-param fusions at
-        # ~250 GB/s (PERF.md: the 32.6 ms AdamW bucket vs its 11.8 ms
-        # bandwidth floor).  Forward slices per-param views back out via
-        # _make_flat_unflatten (concat backward, no scatter).
-        self._flat_names: list = []
-        self._flat_offsets: list = []
-        self._flat_sizes: list = []
-        self._flat_shapes: list = []
-        self._flat_unflatten = None
-        if flat_master is None:
-            # default OFF: A/B'd end-to-end on the GPT-2 345M TPU bench in
-            # two variants and both LOST (PERF.md round-4 log) — the flat
-            # update fusion itself runs at big-array bandwidth (32.6 ->
-            # 14.5 ms measured), but params on TPU carry tiled layouts, so
-            # the per-name <-> flat 1-D bridge forces retiling copies that
-            # cost more than the update saves.  Kept as a tested opt-in
-            # for layouts/backends where the trade differs.
-            flat_master = False
-        elif flat_master and not self._flat_eligible(optimizer, zero_stage):
-            raise ValueError(
-                "flat_master=True is incompatible with this configuration "
-                "(ZeRO/stack_layers/per-param optimizer semantics — see "
-                "TrainStep._flat_eligible)")
-        if flat_master:
-            members = [
-                (k, v) for k, v in self.params.items()
-                if hasattr(v, "dtype") and v.dtype == jnp.float32
-                and v.size < _FLAT_MAX_ELEMS]
-            # same-compute-dtype members contiguous, so the per-group cast
-            # in _make_flat_unflatten is one big convert (dtype name keys
-            # the sort; None/f32 members group together)
-            members.sort(key=lambda kv: (
-                str(self._compute_dtypes.get(kv[0], "")), kv[0]))
-            if len(members) >= 2:
-                groups, off = [], 0
-                for k, v in members:
-                    dt = self._compute_dtypes.get(k)
-                    self._flat_names.append(k)
-                    self._flat_offsets.append(off)
-                    self._flat_sizes.append(int(v.size))
-                    self._flat_shapes.append(tuple(v.shape))
-                    if not groups or groups[-1][0] != dt:
-                        groups.append([dt, off, off, []])
-                    groups[-1][3].append(
-                        (off - groups[-1][1], int(v.size), tuple(v.shape)))
-                    off += int(v.size)
-                    groups[-1][2] = off
-                self.params[_FLAT_KEY] = jnp.concatenate(
-                    [self.params.pop(k).reshape(-1) for k, _ in members])
-                self._flat_unflatten = _make_flat_unflatten(
-                    tuple((dt, g0, g1, tuple(m))
-                          for dt, g0, g1, m in groups))
         self.opt_state = optimizer.init_state(self.params)
         self._dirty = True
         self._step_index = -1  # host-side step counter (faultpoint ctx)
@@ -534,28 +359,12 @@ class TrainStep:
             self._mesh = None
 
         def loss_core(params, buffers, rng, batch):
-            if self._flat_unflatten is not None:
-                # flat 1-D master -> per-param f32 views (concat backward);
-                # the per-name compute-dtype cast below then applies to the
-                # views exactly as it would to standalone masters
-                params = dict(params)
-                views = self._flat_unflatten(params.pop(_FLAT_KEY))
-                params.update(zip(self._flat_names, views))
             if self._compute_dtypes:
                 # fp32 master -> compute dtype; the cast's vjp upcasts the
                 # bf16 grads back to f32 for the optimizer update
                 params = {k: (p.astype(self._compute_dtypes[k])
                               if k in self._compute_dtypes else p)
                           for k, p in params.items()}
-            if self._stack:
-                # stacked (L, ...) -> per-layer views for functional_call;
-                # the slices are free and their vjp writes each layer's
-                # grad into one stacked buffer
-                params = dict(params)
-                for template, names in self._stack.items():
-                    stacked = params.pop(template)
-                    for i, n in enumerate(names):
-                        params[n] = stacked[i]
             state = {**params, **buffers}
             self.model.train()
             inputs = batch[:self.num_inputs]
@@ -744,79 +553,11 @@ class TrainStep:
                 pass
         return Tensor(loss)
 
-    def _flat_eligible(self, optimizer, zero_stage) -> bool:
-        """flat_master auto-gate.  The flat buffer is only semantics-
-        preserving when the optimizer update and grad clip are uniform
-        elementwise over parameters:
-
-        * ZeRO re-lays slots/params per-name over the mesh — incompatible.
-        * stack_layers is the competing (opt-in, measured-slower) layout.
-        * Lamb computes per-parameter trust norms (``_flat_safe = False``).
-        * AdamW's ``apply_decay_param_fun`` makes weight decay per-name.
-        * ClipGradByNorm clips per-parameter norms (global-norm clip is
-          fine: the norm over the flat buffer equals the tree norm).
-        """
-        if zero_stage or self._stack:
-            return False
-        if getattr(optimizer, "_flat_safe", True) is False:
-            return False
-        if getattr(optimizer, "_apply_decay_param_fun", None) is not None:
-            return False
-        clip = getattr(optimizer, "_grad_clip", None)
-        if clip is not None:
-            from ..nn import ClipGradByNorm
-            if isinstance(clip, ClipGradByNorm):
-                return False
-        return True
-
-    def _flat_views(self, flat):
-        """Eager per-name views of a flat buffer (for state export)."""
-        return [flat[o:o + s].reshape(shp)
-                for o, s, shp in zip(self._flat_offsets, self._flat_sizes,
-                                     self._flat_shapes)]
-
-    def _unstacked_params(self):
-        """self.params with stacked groups / the flat buffer expanded back
-        to per-layer names (the external contract)."""
-        params = dict(self.params)
-        for template, names in self._stack.items():
-            stacked = params.pop(template)
-            for i, n in enumerate(names):
-                params[n] = stacked[i]
-        if self._flat_names and _FLAT_KEY in params:
-            flat = params.pop(_FLAT_KEY)
-            params.update(zip(self._flat_names, self._flat_views(flat)))
-        return params
-
-    def _restacked(self, params):
-        """Inverse of _unstacked_params for incoming per-layer dicts."""
-        params = dict(params)
-        for template, names in self._stack.items():
-            if template in params:
-                continue      # already stacked (same-format checkpoint)
-            if all(n in params for n in names):
-                params[template] = jnp.stack(
-                    [jnp.asarray(params.pop(n)) for n in names])
-        if self._flat_names and _FLAT_KEY not in params \
-                and all(n in params for n in self._flat_names):
-            # incoming per-name entries may carry the model-side compute
-            # dtype (e.g. a bf16 jit.save re-load); masters are f32
-            params[_FLAT_KEY] = jnp.concatenate(
-                [jnp.asarray(params.pop(n)).astype(jnp.float32).reshape(-1)
-                 for n in self._flat_names])
-        return params
-
     def sync_to_model(self):
         """Write the trained arrays back into the eager model."""
         params = {k: (v.astype(self._compute_dtypes.get(k, v.dtype))
                       if hasattr(v, "dtype") else v)
-                  for k, v in self._unstacked_params().items()}
-        # per-name compute dtypes were collapsed onto the template; map back
-        for template, names in self._stack.items():
-            if template in self._compute_dtypes:
-                for n in names:
-                    params[n] = params[n].astype(
-                        self._compute_dtypes[template])
+                  for k, v in self.params.items()}
         self.model.load_functional_state({**params, **self.buffers})
         self._dirty = False
 
@@ -828,33 +569,8 @@ class TrainStep:
         lr = self.optimizer._learning_rate
         if hasattr(lr, "state_dict"):
             opt_extra["lr_scheduler"] = lr.state_dict()
-        # params AND optimizer slots exported UNSTACKED (per-layer names)
-        # so the checkpoint format is independent of the internal stacking
-        # optimization
-        opt_state = self.opt_state
-        if self._stack and isinstance(opt_state, dict) \
-                and "slots" in opt_state:
-            slots = dict(opt_state["slots"])
-            for template, names in self._stack.items():
-                if template not in slots:
-                    continue
-                grp = slots.pop(template)
-                for i, n in enumerate(names):
-                    slots[n] = {k: v[i] for k, v in grp.items()}
-            opt_state = {**opt_state, "slots": slots}
-        if self._flat_names and isinstance(opt_state, dict) \
-                and "slots" in opt_state and _FLAT_KEY in opt_state["slots"]:
-            slots = dict(opt_state["slots"])
-            grp = slots.pop(_FLAT_KEY)
-            for n, o, s, shp in zip(self._flat_names, self._flat_offsets,
-                                    self._flat_sizes, self._flat_shapes):
-                slots[n] = {k: (v[o:o + s].reshape(shp)
-                                if hasattr(v, "shape") and v.ndim == 1
-                                else v)
-                            for k, v in grp.items()}
-            opt_state = {**opt_state, "slots": slots}
-        return {"params": self._unstacked_params(), "buffers": self.buffers,
-                "opt_state": opt_state, "opt_extra": opt_extra}
+        return {"params": dict(self.params), "buffers": self.buffers,
+                "opt_state": self.opt_state, "opt_extra": opt_extra}
 
     def set_state_dict(self, state):
         """Restore from :meth:`state_dict` output.  Arrays are re-placed on
@@ -873,45 +589,11 @@ class TrainStep:
                 return jax.device_put(arr, old.sharding)
             return new
         self.params = {k: place_like(v, self.params.get(k))
-                       for k, v in self._restacked(
-                           state["params"]).items()}
+                       for k, v in state["params"].items()}
         self.buffers = {k: place_like(v, self.buffers.get(k))
                         for k, v in state["buffers"].items()}
-        opt_state = state["opt_state"]
-        if self._stack and isinstance(opt_state, dict) \
-                and "slots" in opt_state:
-            slots = dict(opt_state["slots"])
-            for template, names in self._stack.items():
-                if template in slots or not all(n in slots for n in names):
-                    continue
-                per = [slots.pop(n) for n in names]
-                slots[template] = {
-                    k: jnp.stack([jnp.asarray(p[k]) for p in per])
-                    for k in per[0]}
-            opt_state = {**opt_state, "slots": slots}
-        if self._flat_names and isinstance(opt_state, dict) \
-                and "slots" in opt_state \
-                and _FLAT_KEY not in opt_state["slots"] \
-                and all(n in opt_state["slots"] for n in self._flat_names):
-            slots = dict(opt_state["slots"])
-            per = [slots.pop(n) for n in self._flat_names]
-            # mirror the EXPORT guard (state_dict passes non-param-shaped
-            # slot leaves through shared): only concatenate per-name
-            # leaves whose size matches the member's flat size — a future
-            # optimizer with scalar slots would otherwise produce a
-            # tree/shape mismatch against init_state (ADVICE r4)
-            slots[_FLAT_KEY] = {
-                k: (jnp.concatenate(
-                        [jnp.asarray(p[k]).reshape(-1) for p in per])
-                    if all(hasattr(p[k], "shape")
-                           and int(jnp.asarray(p[k]).size) == sz
-                           for p, sz in zip(per, self._flat_sizes))
-                    else per[0][k])
-                for k in per[0]
-                if hasattr(per[0][k], "shape")}
-            opt_state = {**opt_state, "slots": slots}
         self.opt_state = jax.tree_util.tree_map(
-            place_like, opt_state, self.opt_state)
+            place_like, state["opt_state"], self.opt_state)
         lr = self.optimizer._learning_rate
         sched = state.get("opt_extra", {}).get("lr_scheduler")
         if sched is not None and hasattr(lr, "set_state_dict"):

@@ -1,17 +1,17 @@
 """Kernel autotuner — timed variant/config selection for the Pallas kernels.
 
-The hand-tuned kernel configs (flash 512/512 blocks, hg*d=256 head groups,
-the CE lse (row, chunk) layout, LN row blocks) were each found by one-off
-on-chip A/Bs (PERF.md rounds 2-5).  That search is exhausted at the *config*
-level; what remains is the variant*config product space (bf16 softmax
-chains, iota-free band masks, DMA-pipelined K/V — see
+The hand-tuned kernel configs (flash 512/512 blocks, the head groups) were
+each found by one-off on-chip A/Bs (PERF.md section 6).  That search is
+exhausted at the *config* level; what remains is the variant*config product
+space (bf16 softmax chains, DMA-pipelined K/V — see
 flash_attention_pallas.py), which is too large to A/B by hand.  This module
 makes the search systematic:
 
 - a **registry** of kernel families (flash_fwd, flash_bwd, flash_bwd_dq,
-  flash_bwd_dkv, ce_lse, ln), each exposing the per-key candidate list
-  (variant name + config dict; candidate [0] is ALWAYS the hand-tuned
-  default) and a runner that executes one candidate on synthetic data;
+  flash_bwd_dkv, the decode-attention families, mp_overlap), each exposing
+  the per-key candidate list (variant name + config dict; candidate [0] is
+  ALWAYS the hand-tuned default) and a runner that executes one candidate
+  on synthetic data;
 - **timed selection** at first call per (shape, dtype, platform, causal)
   key: median-of-k on-device wall times per candidate, best wins
   (off by default — enable with FLAGS_autotune=1 / PADDLE_TPU_AUTOTUNE=1,
@@ -486,8 +486,7 @@ def report() -> Dict[str, Dict[str, dict]]:
 
 def _import_kernel_families():
     """Family registration happens at kernel-module import."""
-    from . import (ce_pallas, decode_attention,  # noqa: F401
-                   flash_attention_pallas, norm_pallas)
+    from . import decode_attention, flash_attention_pallas  # noqa: F401
 
 
 def standard_keys() -> List[tuple]:
@@ -502,10 +501,6 @@ def standard_keys() -> List[tuple]:
                      "flash_bwd_dkv"):
         out.append((fam_name, fap.autotune_key(
             b=8, s=1024, sk=1024, h=16, d=64, dtype=dtype, causal=True)))
-    from . import ce_pallas as cep
-    out.append(("ce_lse", cep.autotune_key(n=8192, v=50304, dtype=dtype)))
-    from . import norm_pallas as nop
-    out.append(("ln", nop.autotune_key(n=8192, f=1024, dtype=dtype)))
     from . import decode_attention as dat
     # the serving decode step's attention at the bench-standard serving
     # shape (8 slots, 1024-token cache, GPT-2 345M heads)

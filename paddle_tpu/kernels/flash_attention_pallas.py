@@ -108,7 +108,6 @@ accepts).
 from __future__ import annotations
 
 import functools
-import os
 
 import numpy as np
 
@@ -120,24 +119,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ..core.dtype import x64_scope
 
 
-def _block_env(name, default):
-    """Power-of-two >=128 only: the divisibility-fallback loop in
-    flash_attention_bshd halves the block until it divides the sequence, so
-    a non-power-of-two would turn supported() shapes into dispatch errors."""
-    raw = os.getenv(name)
-    if not raw:
-        return default
-    try:
-        v = int(raw)
-    except ValueError:
-        return default
-    if v < 128 or v & (v - 1):
-        return default
-    return v
-
-
-DEFAULT_BLOCK_Q = _block_env("PADDLE_TPU_FLASH_BLOCK_Q", 512)
-DEFAULT_BLOCK_K = _block_env("PADDLE_TPU_FLASH_BLOCK_K", 512)
+#: 512/512 won on the v5e over 1,024/512 and 512/256 (PERF.md section 6,
+#: PR 30); ``block_q``/``block_k`` stay arguments and autotune configs
+DEFAULT_BLOCK_Q = DEFAULT_BLOCK_K = 512
 _NEG_INF = -1e30
 #: largest edge of the causal band's sub-tiles (_band_tile is the rule):
 #: 256 against 128 measured on the v5e at (s, d) = (1,024, 64) and (2,048,
@@ -250,9 +234,6 @@ def _pick_head_group(h: int, d: int, s: int):
     def bwd_fits(hg):
         return s * hg * d * 4 <= _DQ_SCRATCH_BUDGET
 
-    forced = _valid_forced_group(h, d)
-    if forced is not None:
-        return forced
     groups = _aligned_groups(h, d)
     for hg in groups:            # largest first
         if hg * d <= 256 and bwd_fits(hg):
@@ -299,7 +280,7 @@ def _resident_bwd_group(s, sk, h, d, causal, block_q, block_k):
     the group at both head sizes (1.461 against 1.475 ms a call at (16 x
     1,024, 16 x 64), 0.651 against 0.654 at (2 x 2,048, 16 x 128); PERF.md,
     PR 30) on half the working set and a third of the compile."""
-    hg = _valid_forced_group(h, d) or _aligned_groups(h, d)[-1]
+    hg = _aligned_groups(h, d)[-1]
     if hg * d <= 256 and _resident_bwd_fits(s, sk, hg * d, causal, block_q,
                                             block_k):
         return hg
@@ -318,27 +299,10 @@ def _bwd_plan(s, sk, h, d, causal, block_q, block_k, hg_b):
     return "split", hg_b
 
 
-def _valid_forced_group(h: int, d: int):
-    raw = os.getenv("PADDLE_TPU_FLASH_HEAD_GROUP")
-    if not raw:
-        return None
-    try:
-        hg = int(raw)
-    except ValueError:
-        return None
-    if h % hg == 0 and ((hg * d) % 128 == 0 or hg == h):
-        return hg
-    return None
-
-
 def _pick_fwd_head_group(h: int, d: int, s: int, hg_b: int) -> int:
     """The forward has no full-sequence scratch, so it can afford a larger
     group (up to hg*d = 512) when the resident K/V still fits — fewer grid
-    cells amortize per-cell overhead.  Falls back to the backward's group.
-    A VALID env override (PADDLE_TPU_FLASH_HEAD_GROUP) pins both
-    directions; invalid values are ignored in both pickers."""
-    if _valid_forced_group(h, d) is not None:
-        return hg_b
+    cells amortize per-cell overhead.  Falls back to the backward's group."""
     for hg in _aligned_groups(h, d):      # largest first
         if hg * d <= 512 and _kv_fits_resident(s, hg * d):
             # the first admissible candidate is always >= hg_b (hg_b

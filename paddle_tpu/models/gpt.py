@@ -19,8 +19,6 @@ from __future__ import annotations
 import dataclasses
 import math
 
-import jax
-import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 from .. import ops
@@ -53,28 +51,6 @@ class GPTConfig:
     # FLOPs for O(sqrt)-ish activation memory — required for long-sequence
     # training (s=8192 without it sits at the 16GB HBM edge on one v5e)
     use_recompute: bool = False
-    # scan_layers: hold the L identical blocks as NATIVELY stacked (L, ...)
-    # parameter arrays and run lax.scan over the layer axis.  Grads arrive
-    # stacked BY CONSTRUCTION (scan's transpose accumulates them — no
-    # per-name<->stacked bridge, the thing that sank both prior layout
-    # experiments, PERF.md rounds 3-4), so the optimizer update is ~17 big
-    # fusions at large-array HBM bandwidth instead of ~300 small ones.
-    scan_layers: bool = False
-    # unroll factor for the layer scan.  unroll=num_hidden_layers gives
-    # straight-line HLO (XLA fuses/remats across layer boundaries exactly
-    # like the per-layer model — a rolled scan stacks every backward
-    # residual as (L, ...) loop buffers, measured 17.4G HBM = OOM on one
-    # v5e at the 345M bench shapes) while keeping the stacked param layout.
-    scan_unroll: int = 1
-    # how the stacked params meet the per-layer compute:
-    #   "scan"      — lax.scan (with scan_unroll); grads accumulate via
-    #                 per-layer dynamic-update-slice (measured 18.3 ms/step
-    #                 of bitcast+DUS fusions at the 345M bench)
-    #   "stack_vjp" — python loop over custom_vjp slice views whose
-    #                 backward builds each stacked grad with ONE jnp.stack
-    #                 (the exact cotangent for disjoint static slices —
-    #                 same trick as TrainStep._make_flat_unflatten)
-    scan_mode: str = "scan"
 
     @classmethod
     def gpt2_small(cls):
@@ -247,310 +223,6 @@ class GPTBlock(Layer):
         return x
 
 
-#: stacked-param field -> per-layer submodule path (state_dict key mapping)
-_SCAN_FIELD_MAP = {
-    "ln1_w": "ln1.weight", "ln1_b": "ln1.bias",
-    "qkv_w": "attn.qkv_proj.weight", "qkv_b": "attn.qkv_proj.bias",
-    "out_w": "attn.out_proj.weight", "out_b": "attn.out_proj.bias",
-    "ln2_w": "ln2.weight", "ln2_b": "ln2.bias",
-    "fc1_w": "mlp.fc1.weight", "fc1_b": "mlp.fc1.bias",
-    "fc2_w": "mlp.fc2.weight", "fc2_b": "mlp.fc2.bias",
-}
-
-
-def _scan_block_apply(x, p, cfg, *, training, keys=None, cache=None):
-    """One transformer block over raw arrays with per-layer params ``p``
-    (each a slice of the stacked (L, ...) arrays).  Matches GPTBlock's
-    math exactly (pre-LN, f32 LN stats, bf16 residual stream)."""
-    from ..nn.functional.attention import (scaled_dot_product_attention,
-                                           sdpa_reference_raw)
-    from ..nn.functional.norm import layer_norm_raw
-
-    h_sz = cfg.hidden_size
-    nh = cfg.num_attention_heads
-    hd = h_sz // nh
-    b, s = x.shape[0], x.shape[1]
-
-    def dropout(a, p_drop, key):
-        if p_drop <= 0.0 or not training or key is None:
-            return a
-        keep = jax.random.bernoulli(key, 1.0 - p_drop, a.shape)
-        return jnp.where(keep, a / jnp.asarray(1.0 - p_drop, a.dtype),
-                         jnp.zeros((), a.dtype))
-
-    with _scopes.scope(_scopes.NORM):
-        h = layer_norm_raw(x, p["ln1_w"], p["ln1_b"], (h_sz,),
-                           cfg.layer_norm_epsilon)
-    with _scopes.scope(_scopes.ATTN):
-        static_cache = (cache is not None
-                        and not isinstance(cache, (tuple, list)))
-        if static_cache and _mpo.qkv_viable(nh, hd):
-            # overlapped fused-qkv island (see GPTAttention.forward)
-            q, k, v = _mpo.qkv_heads(h, p["qkv_w"], p["qkv_b"], nh, hd)
-        else:
-            qkv = h @ p["qkv_w"] + p["qkv_b"]
-            # last-dim slices (free) — see GPTAttention.forward for the
-            # measured why
-            q = qkv[..., :h_sz].reshape(b, s, nh, hd)
-            k = qkv[..., h_sz:2 * h_sz].reshape(b, s, nh, hd)
-            v = qkv[..., 2 * h_sz:].reshape(b, s, nh, hd)
-        if static_cache:
-            # static slotted cache view (serving.cache): in-place append +
-            # length-masked attention — no shape growth, no retrace.  Head-
-            # sharded under a tensor-parallel serving mesh (see
-            # GPTAttention.forward; no-op without an 'mp' mesh)
-            q, k, v = shard_heads(q), shard_heads(k), shard_heads(v)
-            out = cache.attend_raw(q, k, v)
-        elif cache is not None:
-            # LEGACY CONCAT SHIM (see GPTForCausalLM.gen_legacy_concat_cache)
-            pk, pv = cache
-            k = jnp.concatenate([pk, k], axis=1)
-            v = jnp.concatenate([pv, v], axis=1)
-            cache = (k, v)
-            out = scaled_dot_product_attention(q, k, v, is_causal=True,
-                                               training=training)
-            if isinstance(out, Tensor):
-                out = out._array
-        else:
-            attn_p = cfg.attention_dropout_prob
-            if attn_p > 0.0 and training and keys is not None:
-                # explicit per-layer key: sdpa's own next_key() would be a
-                # closure constant inside the scan body (same mask every layer)
-                out = sdpa_reference_raw(q, k, v, None, attn_p, True, None,
-                                         keys[0])
-            else:
-                out = scaled_dot_product_attention(q, k, v, is_causal=True,
-                                                   training=training)
-                if isinstance(out, Tensor):
-                    out = out._array
-        out = out.reshape(b, s, h_sz)
-        if _mpo.row_viable(h_sz):
-            out = _mpo.row_parallel_matmul(out, p["out_w"], p["out_b"])
-        else:
-            out = out @ p["out_w"] + p["out_b"]
-        out = dropout(out, cfg.hidden_dropout_prob,
-                      None if keys is None else keys[1])
-    x = x + out
-    with _scopes.scope(_scopes.NORM):
-        h2 = layer_norm_raw(x, p["ln2_w"], p["ln2_b"], (h_sz,),
-                            cfg.layer_norm_epsilon)
-    with _scopes.scope(_scopes.MLP):
-        m = jax.nn.gelu(h2 @ p["fc1_w"] + p["fc1_b"], approximate=True)
-        if _mpo.row_viable(cfg.intermediate_size):
-            m = _mpo.row_parallel_matmul(m, p["fc2_w"], p["fc2_b"])
-        else:
-            m = m @ p["fc2_w"] + p["fc2_b"]
-        m = dropout(m, cfg.hidden_dropout_prob,
-                    None if keys is None else keys[2])
-    x = x + m
-    x = with_sharding_constraint(x, PartitionSpec("dp", "sep", None))
-    return x, cache
-
-
-class GPTScanBlocks(Layer):
-    """The L transformer blocks as twelve natively stacked (L, ...)
-    parameters; forward is ``lax.scan`` over the layer axis.
-
-    This is the canonical TPU-native deep-transformer layout (the pattern
-    flax's ``nn.scan`` production models use): the stacked arrays slice
-    along the LEADING axis inside the loop (contiguous, no retiling — the
-    (8,128) tiling lives in the trailing dims), scan's transpose
-    accumulates each layer's grad into the stacked buffer in-place, and
-    the optimizer sees ~12 large arrays.  Compile time also drops: the
-    block body is traced/compiled once, not L times.
-
-    Reference analogue: none (the reference materialises every layer);
-    capability parity is with its fleet GPT models
-    (auto_parallel_gpt_model.py) via GPTModel(scan_layers=True).
-    """
-
-    #: amp.decorate(level='O2') keeps these f32 (reference
-    #: keep_batch_norm_fp32 semantics — LN params stay master precision)
-    _amp_keep_fp32_params = ("ln1_w", "ln1_b", "ln2_w", "ln2_b")
-
-    def __init__(self, config: GPTConfig):
-        super().__init__()
-        from ..core.tensor import Parameter
-        c = config
-        self.config = c
-        L, H, Iz = c.num_hidden_layers, c.hidden_size, c.intermediate_size
-        std = c.initializer_range
-        out_std = std / math.sqrt(2 * L)
-
-        def param(shape, init, pspec=None):
-            p = Parameter(Tensor(init(tuple(shape)))._array)
-            if pspec is not None:
-                p.pspec = pspec
-            return p
-
-        P = PartitionSpec
-        normal, out_normal = I.Normal(0.0, std), I.Normal(0.0, out_std)
-        ones, zeros = I.Constant(1.0), I.Constant(0.0)
-        self.ln1_w = param((L, H), ones)
-        self.ln1_b = param((L, H), zeros)
-        self.qkv_w = param((L, H, 3 * H), normal, P(None, None, "mp"))
-        self.qkv_b = param((L, 3 * H), zeros, P(None, "mp"))
-        self.out_w = param((L, H, H), out_normal, P(None, "mp", None))
-        self.out_b = param((L, H), zeros)
-        self.ln2_w = param((L, H), ones)
-        self.ln2_b = param((L, H), zeros)
-        self.fc1_w = param((L, H, Iz), normal, P(None, None, "mp"))
-        self.fc1_b = param((L, Iz), zeros, P(None, "mp"))
-        self.fc2_w = param((L, Iz, H), out_normal, P(None, "mp", None))
-        self.fc2_b = param((L, H), zeros)
-
-    def forward(self, x, cache=None):
-        from ..core.dispatch import call
-        c = self.config
-        params = {n: self._parameters[n] for n in _SCAN_FIELD_MAP}
-        keys = None
-        any_drop = (c.hidden_dropout_prob > 0.0
-                    or c.attention_dropout_prob > 0.0)
-        if self.training and any_drop and cache is None:
-            from ..core import random as _rnd
-            flat = jax.random.split(_rnd.next_key(), c.num_hidden_layers * 3)
-            keys = flat.reshape(c.num_hidden_layers, 3, *flat.shape[1:])
-        if cache is not None and not isinstance(cache, (tuple, list)):
-            # slotted/paged decode path: the per-layer walk re-enters
-            # inside ONE traced fn, over a clone of the view whose arrays
-            # are that trace's own arguments (and outputs — no tracer
-            # leaks onto the caller's view object).  The view declares
-            # which arrays it threads (carry_arrays: k/v, the int8 scale
-            # pools when quantized, the page table for the paged layout,
-            # lengths, and the opt-in quant-error scalar) and which come
-            # back mutated (k, v, scales, quant_err).
-            seq = int(x.shape[1]) if hasattr(x, "shape") else 1
-            carries = cache.carry_arrays()
-
-            def raw_decode_cached(x, params, *arrs):
-                inner = cache.clone_raw(*arrs)
-                for i in range(c.num_hidden_layers):
-                    pi = {k: v[i] for k, v in params.items()}
-                    x, _ = _scan_block_apply(x, pi, c, training=False,
-                                             cache=inner)
-                return (x,) + tuple(inner.mutated_arrays())
-
-            out = call(raw_decode_cached, x, params, *carries,
-                       name="gpt_scan_blocks")
-            cache.adopt(*out[1:], steps=seq)
-            return out[0], cache
-        if cache is not None:
-            # LEGACY CONCAT SHIM decode path: python loop over leading-axis
-            # slices (no grads); shapes grow per token — retraces every step
-            def raw_decode(x, params, *flat_cache):
-                cache_l = [(flat_cache[2 * i], flat_cache[2 * i + 1])
-                           for i in range(c.num_hidden_layers)]
-                new_caches = []
-                for i in range(c.num_hidden_layers):
-                    pi = {k: v[i] for k, v in params.items()}
-                    x, ci = _scan_block_apply(x, pi, c, training=False,
-                                              cache=cache_l[i])
-                    new_caches.append(ci)
-                return (x,) + tuple(a for kv in new_caches for a in kv)
-            flat_cache = [a for kv in cache for a in kv]
-            out = call(raw_decode, x, params, *flat_cache,
-                       name="gpt_scan_blocks")
-            x_out = out[0]
-            new_caches = [(out[1 + 2 * i], out[2 + 2 * i])
-                          for i in range(c.num_hidden_layers)]
-            return x_out, new_caches
-
-        training = self.training
-
-        def raw_scan(x, params, keys):
-            def body(carry, xs):
-                pi, ki = xs
-                y, _ = _scan_block_apply(carry, pi, c, training=training,
-                                         keys=ki)
-                return y, None
-            if c.use_recompute and training:
-                body = jax.checkpoint(body)
-            xs = (params, keys)
-            unroll = max(1, min(int(c.scan_unroll), c.num_hidden_layers))
-            y, _ = jax.lax.scan(body, x, xs, unroll=unroll)
-            return y
-
-        def raw_stack_vjp(x, params, keys):
-            L = c.num_hidden_layers
-            views = _unstack_for_grad(params, L)
-
-            def block(x, pi, ki):
-                return _scan_block_apply(x, pi, c, training=training,
-                                         keys=ki)[0]
-            if c.use_recompute and training:
-                block = jax.checkpoint(block)
-            for i in range(L):
-                x = block(x, views[i],
-                          None if keys is None else keys[i])
-            return x
-
-        raw = raw_stack_vjp if c.scan_mode == "stack_vjp" else raw_scan
-        return call(raw, x, params, keys, name="gpt_scan_blocks")
-
-
-def _unstack_for_grad(params, L):
-    """Slice {name: (L, ...)} stacked params into L per-layer dicts through
-    a custom_vjp whose backward is ONE jnp.stack per stacked array — the
-    exact cotangent for disjoint static slices, avoiding both jax's
-    pad-and-add slice transpose (round-3 stacked experiment) and scan's
-    per-layer dynamic-update-slice accumulation (18.3 ms/step measured,
-    PERF.md round 5)."""
-    @jax.custom_vjp
-    def unstack(stacked):
-        return tuple({k: v[i] for k, v in stacked.items()}
-                     for i in range(L))
-
-    def fwd(stacked):
-        return unstack(stacked), None
-
-    def bwd(_, cots):
-        return ({k: jnp.stack([c[k] for c in cots]) for k in cots[0]},)
-
-    unstack.defvjp(fwd, bwd)
-    return unstack(params)
-
-
-def scan_state_to_per_layer(state):
-    """Host-side checkpoint mapping: a scan-layers model's stacked state
-    ('gpt.h_stack.qkv_w': (L, H, 3H)) -> per-layer names
-    ('gpt.h.{i}.attn.qkv_proj.weight').  Checkpoints stay per-name
-    portable regardless of the in-memory layout."""
-    out = {}
-    for k, v in state.items():
-        if ".h_stack." in k:
-            prefix, field = k.rsplit(".h_stack.", 1)
-            sub = _SCAN_FIELD_MAP[field]
-            for i in range(int(v.shape[0])):
-                out["%s.h.%d.%s" % (prefix, i, sub)] = v[i]
-        else:
-            out[k] = v
-    return out
-
-
-def per_layer_state_to_scan(state):
-    """Inverse of :func:`scan_state_to_per_layer`: stack per-layer entries
-    into the scan model's (L, ...) arrays.  Non-block entries pass through."""
-    import re
-    pat = re.compile(r"^(.*)\.h\.(\d+)\.(.+)$")
-    rev = {v: k for k, v in _SCAN_FIELD_MAP.items()}
-    out, groups = {}, {}
-    for k, v in state.items():
-        m = pat.match(k)
-        if m and m.group(3) in rev:
-            key = (m.group(1), rev[m.group(3)])
-            groups.setdefault(key, {})[int(m.group(2))] = v
-        else:
-            out[k] = v
-    for (prefix, field), per in groups.items():
-        idxs = sorted(per)
-        if idxs != list(range(len(idxs))):
-            raise ValueError("per-layer state has gaps for %s.h.*.%s: %r"
-                             % (prefix, _SCAN_FIELD_MAP[field], idxs))
-        out["%s.h_stack.%s" % (prefix, field)] = jnp.stack(
-            [jnp.asarray(per[i]) for i in idxs])
-    return out
-
-
 class GPTModel(Layer):
     def __init__(self, config: GPTConfig):
         super().__init__()
@@ -564,11 +236,8 @@ class GPTModel(Layer):
         self.wpe.weight.set_value(
             Tensor(init((c.max_position_embeddings, c.hidden_size))))
         self.drop = Dropout(c.hidden_dropout_prob)
-        if c.scan_layers:
-            self.h_stack = GPTScanBlocks(c)
-        else:
-            self.h = LayerList(
-                [GPTBlock(c) for _ in range(c.num_hidden_layers)])
+        self.h = LayerList(
+            [GPTBlock(c) for _ in range(c.num_hidden_layers)])
         self.ln_f = LayerNorm(c.hidden_size, c.layer_norm_epsilon)
 
     def forward(self, input_ids, position_ids=None, cache=None):
@@ -613,13 +282,6 @@ class GPTModel(Layer):
             x = self.wte(input_ids) + self.wpe(position_ids)
         x = self.drop(x)
         x = with_sharding_constraint(x, PartitionSpec("dp", "sep", None))
-        if self.config.scan_layers:
-            if cache is not None:
-                x, new_caches = self.h_stack(x, cache)
-                if finalize:
-                    new_caches = view.finalize()
-                return self.ln_f(x), new_caches
-            return self.ln_f(self.h_stack(x))
         new_caches = []
         if self.config.use_recompute and self.training and cache is None:
             from ..distributed.recompute import recompute as _recompute

@@ -14,81 +14,6 @@ from ...core.dispatch import call, wrap_op
 from ...core.dtype import x64_scope
 
 
-def _one_device_operand(x) -> bool:
-    """Whether ``x`` lives on one device: a concrete array says so itself;
-    a tracer belongs to a program that spans the global mesh when one with
-    more than one device is installed (distributed.mesh) and to a
-    one-device program otherwise — how many chips the HOST has does not
-    enter into it."""
-    if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
-        return len(x.sharding.device_set) == 1
-    from ...distributed.mesh import multi_device_mesh
-    return multi_device_mesh() is None
-
-
-def _pallas_ce_gate(flag_name, logits):
-    """Shared eligibility gate for the Pallas CE/LSE routes: flag on, TPU
-    backend, one-device operand (a Mosaic custom call has no GSPMD
-    partitioning rule — in a multi-device program XLA would all-gather
-    the (N, V) logits per device; the sharded-model CE is
-    ParallelCrossEntropy and the 'sep' routing, not this).  Returns
-    (n, v, lead) or None."""
-    from ...utils.flags import fast_get
-    if not fast_get(flag_name):
-        return None
-    if jax.default_backend() != "tpu" or not _one_device_operand(logits):
-        return None
-    v = logits.shape[-1]
-    lead = logits.shape[:-1]
-    n = 1
-    for dim in lead:
-        n *= dim
-    return n, v, lead
-
-
-def _fused_ce_or_none(logits, lbl, ignore_index):
-    """Opt-in route (FLAGS_use_pallas_ce=1) to the Pallas fused softmax-CE
-    kernel.  Default stays XLA: the streaming-reduction path measured
-    FASTER on the 345M bench (49.7k vs 49.1k tokens/s) — the VMEM budget
-    caps the kernel at 8-row tiles whose grid overhead outweighs the fused
-    gather.  The kernel remains the escape hatch for shapes where XLA's
-    reduction fusion misbehaves.  Returns None to take the XLA path."""
-    gate = _pallas_ce_gate("use_pallas_ce", logits)
-    if gate is None:
-        return None
-    n, v, lead = gate
-    from ...kernels import ce_pallas
-    if not ce_pallas.supported(n, v):
-        return None
-    # explicit i32 index math; softmax_ce_pallas scopes its own kernel
-    # lowering (x64 off) internally
-    idx = jnp.clip(lbl.astype(jnp.int32), 0, v - 1).reshape(n, 1)
-    nll = ce_pallas.softmax_ce_pallas(logits.reshape(n, v), idx)
-    nll = nll.reshape(lead)
-    mask = (lbl != ignore_index)
-    return jnp.where(mask, nll, 0.0)
-
-
-def _streamed_lse_or_none(logits, axis):
-    """One-pass streamed Pallas logsumexp over the class axis
-    (FLAGS_use_pallas_lse): ONE read of the bf16 logits with online
-    (max, sum-exp2) statistics vs XLA's two streaming reductions.
-    Returns None to take the XLA path (non-TPU, multi-device, unsupported
-    shape/dtype, or the class axis is not last)."""
-    if axis not in (-1, logits.ndim - 1):
-        return None
-    if logits.dtype not in (jnp.bfloat16, jnp.float16, jnp.float32):
-        return None
-    gate = _pallas_ce_gate("use_pallas_lse", logits)
-    if gate is None:
-        return None
-    n, v, lead = gate
-    from ...kernels import ce_pallas
-    if not ce_pallas.lse_supported(n, v, logits.dtype.itemsize):
-        return None
-    return ce_pallas.logsumexp_pallas(logits.reshape(n, v)).reshape(lead)
-
-
 def _reduce(out, reduction, weight_sum=None):
     if reduction == "mean":
         if weight_sum is not None:
@@ -113,26 +38,17 @@ def softmax_with_cross_entropy_raw(logits, label, soft_label=False,
     lbl = label
     if lbl.ndim == logits.ndim and lbl.shape[axis] == 1:
         lbl = jnp.squeeze(lbl, axis)
-    if axis in (-1, logits.ndim - 1):
-        out = _fused_ce_or_none(logits, lbl, ignore_index)
-        if out is not None:
-            return out
-    lse = _streamed_lse_or_none(logits, axis)
-    if lse is None:
-        # keep every elementwise use of `logits` in its own consumer fusion:
-        # binding `lf = logits.astype(f32)` once made XLA CSE the convert and
-        # MATERIALISE the full f32 logits (1.65 GB at GPT-2 bench shapes,
-        # ~10 ms/step of HBM traffic); with per-consumer converts the bf16
-        # matmul output is the only materialised array and each streaming
-        # reduction fuses its own upcast
-        # (a max-free clamped variant was benched and measured no faster —
-        # XLA's two streaming reductions are not the bottleneck they look
-        # like)
-        m = jax.lax.stop_gradient(jnp.max(logits, axis=axis))
-        mf = m.astype(jnp.float32)
-        lse = mf + jnp.log(jnp.sum(
-            jnp.exp(logits.astype(jnp.float32) - jnp.expand_dims(mf, axis)),
-            axis=axis))
+    # keep every elementwise use of `logits` in its own consumer fusion:
+    # binding `lf = logits.astype(f32)` once made XLA CSE the convert and
+    # MATERIALISE the full f32 logits (1.65 GB at GPT-2 bench shapes,
+    # ~10 ms/step of HBM traffic); with per-consumer converts the bf16
+    # matmul output is the only materialised array and each streaming
+    # reduction fuses its own upcast
+    m = jax.lax.stop_gradient(jnp.max(logits, axis=axis))
+    mf = m.astype(jnp.float32)
+    lse = mf + jnp.log(jnp.sum(
+        jnp.exp(logits.astype(jnp.float32) - jnp.expand_dims(mf, axis)),
+        axis=axis))
     # cast BEFORE the clip so every index op is i32: s64 labels would
     # otherwise put emulated 64-bit clamp/compare ops into the TPU program
     # (caught by tests/test_x64_audit.py)

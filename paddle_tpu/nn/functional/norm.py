@@ -1,8 +1,7 @@
 """Normalization functionals (reference: python/paddle/nn/functional/norm.py).
 
-layer_norm runs on the XLA-fused path by default (measured at peak on TPU —
-PERF.md); FLAGS_use_pallas_norm=1 opts into the hand kernel in
-kernels/norm_pallas.py.  batch_norm keeps running stats on the layer like
+layer_norm is plain jnp: XLA fuses the f32 statistics into the neighbouring
+ops (PERF.md section 6).  batch_norm keeps running stats on the layer like
 the reference (paddle/phi/kernels/gpu/batch_norm_kernel.cu semantics).
 """
 from __future__ import annotations
@@ -13,32 +12,10 @@ import jax.numpy as jnp
 from ...core.dispatch import call, wrap_op
 from ...core.tensor import Tensor
 
-def _use_pallas_norm() -> bool:
-    from ...utils.flags import fast_get
-    return bool(fast_get("use_pallas_norm"))
-
 
 def layer_norm_raw(x, weight, bias, normalized_shape, epsilon=1e-5):
     n_axes = len(normalized_shape) if isinstance(normalized_shape, (list, tuple)) else 1
     axes = tuple(range(x.ndim - n_axes, x.ndim))
-    if _use_pallas_norm() and n_axes == 1 and weight is not None \
-            and bias is not None and x.shape[-1] % 128 == 0:
-        # hand-kernel path (FLAGS_use_pallas_norm=1): XLA's fused LN is
-        # already at peak (PERF.md), so this is opt-in
-        from ...kernels.norm_pallas import (DEFAULT_BLOCK_ROWS,
-                                            layer_norm_pallas)
-        rows = 1
-        for s in x.shape[:-1]:
-            rows *= s
-        if rows % 8 == 0:
-            if jax.default_backend() != "tpu":
-                raise RuntimeError(
-                    "FLAGS_use_pallas_norm selects a Mosaic kernel and this "
-                    "process runs on %r; call kernels.norm_pallas."
-                    "layer_norm_pallas(..., interpret=True) to run it in "
-                    "the Pallas interpreter" % jax.default_backend())
-            return layer_norm_pallas(x, weight, bias, epsilon,
-                                     DEFAULT_BLOCK_ROWS, False)
     # statistics in f32 regardless of activation dtype, output cast back to
     # the input dtype: keeps bf16 activations bf16 through the residual
     # stream (an f32-promoting LN silently turns every downstream matmul

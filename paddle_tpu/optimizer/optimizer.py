@@ -184,10 +184,6 @@ class Optimizer:
     # decoupled weight decay? (AdamW overrides)
     _decoupled_wd = False
 
-    # update is uniform elementwise over parameters, so TrainStep may pack
-    # them into one flat buffer (Lamb overrides: per-param trust norms)
-    _flat_safe = True
-
     # -- compiled-path API ---------------------------------------------------
     def init_state(self, params_tree):
         step = jnp.zeros((), jnp.int32)

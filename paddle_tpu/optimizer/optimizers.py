@@ -243,10 +243,6 @@ class Adamax(Optimizer):
 
 
 class Lamb(Optimizer):
-    # per-parameter trust-ratio norms: packing params into one flat buffer
-    # (TrainStep flat_master) would change the math — keep it per-name
-    _flat_safe = False
-
     def __init__(self, learning_rate=0.001, lamb_weight_decay=0.01, beta1=0.9,
                  beta2=0.999, epsilon=1e-6, parameters=None, grad_clip=None,
                  exclude_from_weight_decay_fn=None, name=None):

@@ -75,14 +75,13 @@ trace-time carriers, not pytrees — the arrays they hold thread through
   attends to the full mapped past + itself (the chunked-prefill
   program the engine interleaves with decode).
 
-A view's *carry fields* — the traced arrays it threads through a
-re-entrant walk — are dynamic: ``k, v`` always, ``k_scale, v_scale``
+A view's *carry fields* — the traced arrays it threads through the
+model's per-layer walk — are dynamic: ``k, v`` always, ``k_scale, v_scale``
 when the cache is quantized, the page table for paged views,
 ``lengths``, and (opt-in) a ``quant_err`` f32 scalar accumulating the
 max abs dequantization error of the step's appends (the
 ``serving.kv_quant_error`` gauge).  :meth:`_CacheView.carry_fields`
-is the single source of that ordering; ``clone_raw``/``adopt`` and the
-scan-layers re-entry in ``models/gpt.py`` follow it.
+is the single source of that ordering.
 
 Dependency note: this module is imported by ``models/gpt.py`` and must
 stay model-free (jax + the decode-attention kernel family only).
@@ -373,15 +372,13 @@ def _paged_scatter(kc, vc, layer, table, pos, valid, k_new, v_new, ksc, vsc,
 class _CacheView:
     """Trace-time carrier threading the cache arrays through the model's
     per-layer walk.  Layers call :meth:`attend` (Tensor-level, tape-aware)
-    or :meth:`attend_raw` (raw arrays, for the scan-layers block body) in
-    order; the view allocates layer indices from an internal cursor.
+    in order; the view allocates layer indices from an internal cursor.
 
     :meth:`carry_fields` names the traced arrays the view threads through
-    a re-entrant walk (the scan-layers path passes them across its own
-    ``call`` boundary via :meth:`carry_arrays`/:meth:`clone_raw`);
-    :meth:`mutated_fields` is the subset a layer MUTATES — ``k, v``, plus
-    the scale pools when the cache is quantized, plus the ``quant_err``
-    accumulator when tracking is on."""
+    the walk (each :meth:`attend` passes them across its ``call``
+    boundary); :meth:`mutated_fields` is the subset a layer MUTATES —
+    ``k, v``, plus the scale pools when the cache is quantized, plus the
+    ``quant_err`` accumulator when tracking is on."""
 
     #: layout-specific carry fields between the scale pools and lengths
     #: (the paged views add "page_table")
@@ -437,14 +434,9 @@ class _CacheView:
         return i
 
     def carry_arrays(self):
-        """The traced arrays a re-entrant walk must pass across its own
-        trace boundary, in :meth:`carry_fields` order."""
+        """The traced arrays :meth:`attend` passes across its ``call``
+        boundary, in :meth:`carry_fields` order."""
         return tuple(getattr(self, f) for f in self.carry_fields())
-
-    def mutated_arrays(self):
-        """The subset of :meth:`carry_arrays` the walk mutates — what the
-        re-entrant fn returns and :meth:`adopt` takes back."""
-        return tuple(getattr(self, f) for f in self.mutated_fields())
 
     def attend(self, q, k_new, v_new, scale=None):
         """Tensor-level append+attend (dispatches through core.dispatch.call
@@ -463,45 +455,6 @@ class _CacheView:
         for f, a in zip(self.mutated_fields(), res[1:]):
             setattr(self, f, _unwrap(a))
         return res[0]
-
-    def attend_raw(self, q, k_new, v_new, scale=None):
-        """Raw-array append+attend (the scan-layers block body path)."""
-        layer = self._alloc_layer()
-        res = self._append_attend_raw(
-            layer, self.carry_arrays(), q, k_new, v_new, scale)
-        for f, a in zip(self.mutated_fields(), res[1:]):
-            setattr(self, f, a)
-        return res[0]
-
-    def clone_raw(self, *arrays):
-        """A fresh same-typed view over explicit raw arrays (in
-        :meth:`carry_fields` order) — for code that re-enters the
-        per-layer walk inside its own traced function (the scan-layers
-        decode path): the clone's arrays are that trace's arguments, so
-        no tracer ever leaks onto this view."""
-        import copy
-        fields = self.carry_fields()
-        if len(arrays) != len(fields):
-            raise ValueError("clone_raw expects %d arrays %r, got %d"
-                             % (len(fields), fields, len(arrays)))
-        c = copy.copy(self)
-        for f, a in zip(fields, arrays):
-            setattr(c, f, _unwrap(a))
-        c._layer = 0
-        return c
-
-    def adopt(self, *arrays, steps=None):
-        """Take the (concrete) arrays a traced clone produced as outputs,
-        in :meth:`mutated_fields` order."""
-        fields = self.mutated_fields()
-        if len(arrays) != len(fields):
-            raise ValueError("adopt expects %d arrays %r, got %d"
-                             % (len(fields), fields, len(arrays)))
-        for f, a in zip(fields, arrays):
-            setattr(self, f, _unwrap(a))
-        self._layer = int(self.k.shape[1])
-        if steps is not None and hasattr(self, "_steps"):
-            self._steps = int(steps)
 
     # -- shared quantized-append helper ------------------------------------
 

@@ -59,22 +59,6 @@ def get_flags(names=None):
 define_flag("check_nan_inf", False,
             "check every op output for NaN/Inf (reference operator.cc:1252)")
 define_flag("use_flash_attention", True, "route attention through Pallas")
-define_flag("use_pallas_norm", False,
-            "route layer_norm through the Pallas kernel (XLA's fused LN is "
-            "already at peak; opt-in escape hatch)")
-define_flag("use_pallas_ce", False,
-            "route hard-label cross_entropy through the fused Pallas "
-            "softmax-CE kernel (XLA's streaming path measured faster on "
-            "the 345M bench; opt-in escape hatch)")
-define_flag("use_pallas_lse", False,
-            "compute hard-label CE's logsumexp with the one-pass streamed "
-            "Pallas kernel (big tiles, online max/sum-exp2) instead of "
-            "XLA's two streaming reductions — wall-clock WASH on the "
-            "GPT-2 345M bench (within the +-500 tok/s run-to-run noise, "
-            "~-1.5 ms/step in-device; PERF.md round-4).  Default OFF for "
-            "consistency with use_pallas_ce: a wash does not earn a "
-            "brand-new kernel the default single-device CE path "
-            "(ADVICE r4)")
 define_flag("autotune", False,
             "time kernel variant/config candidates on first call per "
             "(shape, dtype, platform) key and pick the fastest "

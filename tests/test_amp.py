@@ -8,7 +8,6 @@ stalls.  The fp32 master copy must accumulate them.
 """
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import nn
@@ -76,66 +75,26 @@ def test_trainstep_o2_master_weights():
     assert m.weight.dtype == paddle.bfloat16
 
 
-@pytest.mark.slow   # tier-1 wall budget: runs unfiltered in CI (see ci.yml)
-def test_trainstep_layer_stacking_parity():
-    """The internal stacked-params optimization (TrainStep stack_layers)
-    must be invisible: identical losses to the unstacked step, per-layer
-    state_dict keys, and a state_dict round-trip across modes."""
-    import numpy as np
+def test_amp_o2_keeps_gpt_layer_norms_fp32():
+    """decorate(level='O2') casts a GPT's matrices and biases to bf16 and
+    leaves every LayerNorm parameter f32 (reference keep_batch_norm_fp32)."""
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 
+    paddle.seed(0)
+    model = GPTForCausalLM(GPTConfig.tiny())
+    paddle.amp.decorate(model, level="O2", dtype="bfloat16")
+    sd = model.state_dict()
+    norms = [k for k in sd if ".ln1." in k or ".ln2." in k or ".ln_f." in k]
+    assert len(norms) == 2 * (2 * 2 + 1)
+    assert all(sd[k].dtype == paddle.float32 for k in norms)
+    assert all(sd[k].dtype == paddle.bfloat16 for k in sd if k not in norms)
+
+
+def test_trainstep_state_dict_round_trip_under_o2():
+    """The checkpoint contract of an AMP O2 step: ``state_dict`` speaks the
+    model's own names with f32 masters, and a fresh step that loads it
+    takes the same next step."""
     from paddle_tpu.jit import TrainStep
-    from paddle_tpu.models.gpt import (GPTConfig, GPTForCausalLM,
-                                       GPTPretrainingCriterion)
-
-    def build():
-        paddle.seed(3)
-        return GPTForCausalLM(GPTConfig.tiny())
-
-    crit = GPTPretrainingCriterion()
-    ids = np.random.RandomState(0).randint(0, 512, (2, 32)).astype(np.int32)
-    x = paddle.to_tensor(ids)
-
-    losses = {}
-    steps = {}
-    for mode in (True, False):
-        m = build()
-        opt = paddle.optimizer.AdamW(parameters=m.parameters(),
-                                     learning_rate=1e-3)
-        step = TrainStep(m, lambda lg, lb: crit(lg, lb), opt,
-                         stack_layers=mode)
-        losses[mode] = [float(step(x, x).numpy()) for _ in range(4)]
-        steps[mode] = step
-    np.testing.assert_allclose(losses[True], losses[False],
-                               rtol=2e-5, atol=1e-6)
-    # the stacked step really grouped the 2 blocks' params
-    assert steps[True]._stack and not steps[False]._stack
-    # external contract: state_dict speaks per-layer names in both modes
-    sdT = steps[True].state_dict()["params"]
-    sdF = steps[False].state_dict()["params"]
-    assert set(sdT) == set(sdF)
-    for k in sdT:
-        np.testing.assert_allclose(
-            np.asarray(sdT[k], np.float32), np.asarray(sdF[k], np.float32),
-            rtol=2e-4, atol=1e-5, err_msg=k)
-    # round-trip: an unstacked save restores into a stacked step
-    steps[True].set_state_dict(steps[False].state_dict())
-    np.testing.assert_allclose(
-        float(steps[True](x, x).numpy()),
-        float(steps[False](x, x).numpy()), rtol=2e-5, atol=1e-6)
-
-
-def test_trainstep_flat_master_parity():
-    """flat_master=True packs every small/mid f32 master into ONE 1-D
-    buffer (TrainStep._FLAT_KEY) whose optimizer update is a single XLA
-    fusion; the custom_vjp unflatten (jit/__init__.py
-    _make_flat_unflatten) must keep training numerically on the per-name
-    path and the checkpoint contract per-name in both directions.
-
-    Measured end-to-end on the TPU bench this layout LOSES to per-name
-    params (PERF.md round-4 log: tiled-layout bridge costs), so it is an
-    opt-in — this test keeps the machinery honest.
-    """
-    from paddle_tpu.jit import TrainStep, _FLAT_KEY
 
     def build():
         paddle.seed(7)
@@ -150,56 +109,24 @@ def test_trainstep_flat_master_parity():
     y = jnp.asarray(np.random.RandomState(1).randn(4, 16).astype(np.float32))
     loss_fn = lambda out, lab: ((out - lab) ** 2).mean()
 
-    steps, losses = {}, {}
-    for mode in (True, False):
-        m, opt = build()
-        step = TrainStep(m, loss_fn, opt, flat_master=mode)
-        losses[mode] = [float(step(x, y).numpy()) for _ in range(5)]
-        steps[mode] = step
-    assert _FLAT_KEY in steps[True].params
-    assert _FLAT_KEY not in steps[False].params
-    np.testing.assert_allclose(losses[True], losses[False],
-                               rtol=2e-3, atol=1e-6)
-    # external contract: per-name params + slots in both modes
-    sdT, sdF = steps[True].state_dict(), steps[False].state_dict()
-    assert set(sdT["params"]) == set(sdF["params"])
-    assert _FLAT_KEY not in sdT["opt_state"]["slots"]
-    assert set(sdT["opt_state"]["slots"]) == set(sdF["opt_state"]["slots"])
-    for k in sdT["params"]:
-        np.testing.assert_allclose(
-            np.asarray(sdT["params"][k], np.float32),
-            np.asarray(sdF["params"][k], np.float32),
-            rtol=5e-3, atol=1e-5, err_msg=k)
-    # cross restore: per-name checkpoint -> flat step and back
-    mT, oT = build()
-    reT = TrainStep(mT, loss_fn, oT, flat_master=True)
-    reT.set_state_dict(sdF)
-    mF, oF = build()
-    reF = TrainStep(mF, loss_fn, oF, flat_master=False)
-    reF.set_state_dict(sdT)
-    np.testing.assert_allclose(float(reT(x, y).numpy()),
-                               float(reF(x, y).numpy()),
-                               rtol=2e-3, atol=1e-6)
+    m, opt = build()
+    step = TrainStep(m, loss_fn, opt)
+    for _ in range(5):
+        step(x, y)
+    sd = step.state_dict()
+    names = {n for n, _ in m.named_parameters()}
+    assert set(sd["params"]) == names
+    assert set(sd["opt_state"]["slots"]) == names
+    assert all(v.dtype == jnp.float32 for v in sd["params"].values())
 
-
-def test_trainstep_flat_master_incompatible_configs_raise():
-    """Explicit flat_master=True under ZeRO / Lamb / per-name wd must
-    raise rather than silently change semantics."""
-    import pytest
-    from paddle_tpu.jit import TrainStep
-
-    paddle.seed(0)
-    m = nn.Sequential(nn.Linear(8, 8), nn.Linear(8, 8))
-    loss_fn = lambda out, lab: ((out - lab) ** 2).mean()
-    lamb = paddle.optimizer.Lamb(parameters=m.parameters(),
-                                 learning_rate=1e-3)
-    with pytest.raises(ValueError):
-        TrainStep(m, loss_fn, lamb, flat_master=True)
-    adamw = paddle.optimizer.AdamW(
-        parameters=m.parameters(), learning_rate=1e-3,
-        apply_decay_param_fun=lambda n: "weight" in n)
-    with pytest.raises(ValueError):
-        TrainStep(m, loss_fn, adamw, flat_master=True)
+    m2, opt2 = build()
+    fresh = TrainStep(m2, loss_fn, opt2)
+    fresh.set_state_dict(sd)
+    assert all(v.dtype == jnp.float32 for v in fresh.params.values())
+    for k, v in sd["params"].items():
+        np.testing.assert_array_equal(np.asarray(fresh.params[k]),
+                                      np.asarray(v), err_msg=k)
+    assert float(fresh(x, y).numpy()) == float(step(x, y).numpy())
 
 
 def test_adamw_bf16_moment_dtype():

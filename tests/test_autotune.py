@@ -11,9 +11,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.kernels import autotune as at
-from paddle_tpu.kernels import ce_pallas as cep
 from paddle_tpu.kernels import flash_attention_pallas as fap
-from paddle_tpu.kernels import norm_pallas as nop
 
 
 @pytest.fixture(autouse=True)
@@ -39,7 +37,35 @@ def _isolated_cache(tmp_path, monkeypatch):
     at._CACHE_LOADED_FROM = None
 
 
-LN_KEY = dict(n=64, f=256, dtype="float32", platform="cpu")
+# -- the tuner's vehicle: a family of the test's own --------------------------
+# Row sums of an (n, f) array taken ``block_rows`` rows at a time: a plain
+# jnp function with one config knob, registered for this module only.
+
+def _rows_candidates(key):
+    n = key["n"]
+    br0 = min(64, n)
+    cands = [{"variant": "base", "config": {"block_rows": br0}}]
+    for br in (1024, 512, 256, 128, 64, 32, 16, 8):
+        if br != br0 and br <= n and n % br == 0:
+            cands.append({"variant": "base", "config": {"block_rows": br}})
+    return cands
+
+
+def _rows_runner(cand, key):
+    n, f, br = key["n"], key["f"], cand["config"]["block_rows"]
+    x = jnp.ones((n, f), jnp.dtype(key["dtype"]))
+    fn = jax.jit(lambda a: a.reshape(n // br, br, f).sum(axis=1))
+    return lambda: jax.block_until_ready(fn(x))
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _rows_family():
+    at.register_family("rows", _rows_candidates, _rows_runner)
+    yield
+    at._FAMILIES.pop("rows", None)
+
+
+ROWS_KEY = dict(n=64, f=256, dtype="float32", platform="cpu")
 
 
 def _fake_timer(table):
@@ -50,8 +76,8 @@ def _fake_timer(table):
 
 
 def test_disabled_resolve_returns_registered_default():
-    cand = at.resolve("ln", LN_KEY)
-    assert cand == nop._ln_candidates(LN_KEY)[0]
+    cand = at.resolve("rows", ROWS_KEY)
+    assert cand == _rows_candidates(ROWS_KEY)[0]
     # flash too: the default candidate IS the hand-tuned config
     fkey = fap.autotune_key(1, 256, 256, 2, 64, jnp.float32, True)
     cand = at.resolve("flash_fwd", fkey)
@@ -60,7 +86,7 @@ def test_disabled_resolve_returns_registered_default():
 
 
 def test_tune_selects_fastest_and_caches(monkeypatch):
-    cands = nop._ln_candidates(LN_KEY)
+    cands = _rows_candidates(ROWS_KEY)
     want = cands[2]          # an arbitrary non-default candidate
 
     def fake_time(fn, samples):
@@ -71,7 +97,7 @@ def test_tune_selects_fastest_and_caches(monkeypatch):
     times[2] = 1.0
     it = iter(times)
     monkeypatch.setattr(at, "_time_callable", lambda fn, s: next(it))
-    chosen = at.tune("ln", LN_KEY)
+    chosen = at.tune("rows", ROWS_KEY)
     assert chosen["config"] == want["config"]
     # persisted: a fresh process (memo cleared, cache reloaded) resolves
     # to the tuned pick without re-timing
@@ -79,11 +105,11 @@ def test_tune_selects_fastest_and_caches(monkeypatch):
     at._CACHE = None
     monkeypatch.setattr(at, "_time_callable",
                         lambda fn, s: pytest.fail("re-timed a cached key"))
-    assert at.resolve("ln", LN_KEY)["config"] == want["config"]
+    assert at.resolve("rows", ROWS_KEY)["config"] == want["config"]
     # the cache file records the full timing table
     with open(at.cache_path()) as f:
         data = json.load(f)
-    entry = data["families"]["ln"][at.key_str(LN_KEY)]
+    entry = data["families"]["rows"][at.key_str(ROWS_KEY)]
     assert entry["config"] == want["config"]
     assert len(entry["timings"]) == len(cands)
 
@@ -92,12 +118,12 @@ def test_tune_is_deterministic_under_equal_timers(monkeypatch):
     """Equal fake times -> the FIRST candidate (hand-tuned default) wins:
     selection is strict-improvement only."""
     monkeypatch.setattr(at, "_time_callable", lambda fn, s: 1.0)
-    chosen = at.tune("ln", LN_KEY)
-    assert chosen == nop._ln_candidates(LN_KEY)[0]
+    chosen = at.tune("rows", ROWS_KEY)
+    assert chosen == _rows_candidates(ROWS_KEY)[0]
 
 
 def test_failed_candidates_are_skipped(monkeypatch):
-    cands = nop._ln_candidates(LN_KEY)
+    cands = _rows_candidates(ROWS_KEY)
     calls = {"n": 0}
 
     def runner(cand, key):
@@ -105,45 +131,45 @@ def test_failed_candidates_are_skipped(monkeypatch):
             raise RuntimeError("VMEM OOM (simulated)")
         return lambda: None
 
-    fam = at.families()["ln"]
+    fam = at.families()["rows"]
     monkeypatch.setattr(fam, "runner", runner)
-    monkeypatch.setattr(at._FAMILIES["ln"], "runner", runner)
+    monkeypatch.setattr(at._FAMILIES["rows"], "runner", runner)
     times = iter([3.0, 1.0] + [9.0] * len(cands))
     monkeypatch.setattr(at, "_time_callable", lambda fn, s: next(times))
-    chosen = at.tune("ln", LN_KEY)
+    chosen = at.tune("rows", ROWS_KEY)
     assert chosen["config"] == cands[2]["config"]
     with open(at.cache_path()) as f:
-        entry = json.load(f)["families"]["ln"][at.key_str(LN_KEY)]
+        entry = json.load(f)["families"]["rows"][at.key_str(ROWS_KEY)]
     assert "failed" in str(entry["timings"][at._cand_sig(cands[0])])
 
 
 def test_pin_overrides_cache_and_tuning(monkeypatch):
     # seed the cache with a tuned pick
     monkeypatch.setattr(at, "_time_callable", lambda fn, s: 1.0)
-    at.tune("ln", LN_KEY)
+    at.tune("rows", ROWS_KEY)
     # env pin wins over the cache
-    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_PIN", "ln=base:block_rows=8")
-    assert at.resolve("ln", LN_KEY)["config"]["block_rows"] == 8
+    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_PIN", "rows=base:block_rows=8")
+    assert at.resolve("rows", ROWS_KEY)["config"]["block_rows"] == 8
     # FLAGS pin wins over the env pin
     from paddle_tpu.utils import flags
     monkeypatch.setitem(flags._REGISTRY, "autotune_pin",
-                        "ln=base:block_rows=32")
-    assert at.resolve("ln", LN_KEY)["config"]["block_rows"] == 32
+                        "rows=base:block_rows=32")
+    assert at.resolve("rows", ROWS_KEY)["config"]["block_rows"] == 32
     # partial pins merge over the default config
-    monkeypatch.setitem(flags._REGISTRY, "autotune_pin", "ln=base")
-    assert at.resolve("ln", LN_KEY) == nop._ln_candidates(LN_KEY)[0]
+    monkeypatch.setitem(flags._REGISTRY, "autotune_pin", "rows=base")
+    assert at.resolve("rows", ROWS_KEY) == _rows_candidates(ROWS_KEY)[0]
 
 
 def test_pin_parsing_types_and_multiple_families():
     os.environ["PADDLE_TPU_AUTOTUNE_PIN"] = (
         "flash_fwd=bf16chain+iotafree:block_q=256,block_k=128;"
-        "ln=base:block_rows=16")
+        "rows=base:block_rows=16")
     try:
         pins = at._pins()
         assert pins["flash_fwd"]["variant"] == "bf16chain+iotafree"
         assert pins["flash_fwd"]["config"] == {"block_q": 256,
                                                "block_k": 128}
-        assert pins["ln"]["config"] == {"block_rows": 16}
+        assert pins["rows"]["config"] == {"block_rows": 16}
     finally:
         del os.environ["PADDLE_TPU_AUTOTUNE_PIN"]
 
@@ -153,7 +179,7 @@ def test_corrupt_cache_falls_back_to_default():
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
         f.write("{not json")
-    assert at.resolve("ln", LN_KEY) == nop._ln_candidates(LN_KEY)[0]
+    assert at.resolve("rows", ROWS_KEY) == _rows_candidates(ROWS_KEY)[0]
 
 
 def test_invalid_cached_config_sanitized_at_kernel_level(monkeypatch):
@@ -176,12 +202,12 @@ def test_invalid_cached_config_sanitized_at_kernel_level(monkeypatch):
 
 def test_warm_and_cli_smoke(capsys, monkeypatch):
     """warm() on a real (tiny) key + the CLI table/dump/clear paths."""
-    key = nop.autotune_key(16, 128, jnp.float32)
-    results = at.warm([("ln", key)], verbose=False)
+    key = dict(ROWS_KEY, n=16, f=128)
+    results = at.warm([("rows", key)], verbose=False)
     assert results and "config" in results[0]
     at._cli_main(["table"])
     out = capsys.readouterr().out
-    assert "ln [" in out and "chosen:" in out
+    assert "rows [" in out and "chosen:" in out
     at._cli_main(["dump"])
     assert "families" in capsys.readouterr().out
     at._cli_main(["clear"])
@@ -197,8 +223,8 @@ def _hlo(fn, *args):
 
 def test_bit_identical_programs_when_disabled():
     """With tuning disabled (no cache/pin), the autotune-resolved path
-    must produce the SAME program as the explicit hand-tuned default for
-    all three kernel families (acceptance criterion)."""
+    must produce the SAME program as the explicit hand-tuned default,
+    forward and backward (acceptance criterion)."""
     rng = np.random.RandomState(3)
     q = jnp.asarray(rng.randn(1, 256, 2, 64), jnp.float32)
 
@@ -213,32 +239,11 @@ def test_bit_identical_programs_when_disabled():
 
     assert _hlo(flash_auto, q) == _hlo(flash_hand, q)
 
-    x2 = jnp.asarray(rng.randn(64, 2048), jnp.float32)
+    # the backward's families resolve at trace time too
+    def grad_of(f):
+        return jax.grad(lambda x: jnp.sum(f(x)))
 
-    def lse_auto(x):
-        return cep._lse_call(x, True)
-
-    def lse_hand(x):
-        br, c = cep._lse_layout(64, 2048, 4)
-        return cep._lse_call_cfg(x, br, c, True)
-
-    assert _hlo(lse_auto, x2) == _hlo(lse_hand, x2)
-
-    g = jnp.ones((2048,), jnp.float32)
-    b = jnp.zeros((2048,), jnp.float32)
-
-    def ln_auto(x):
-        return nop.layer_norm_pallas(x, g, b, interpret=True)
-
-    def ln_hand(x):
-        return nop.layer_norm_pallas(
-            x, g, b, block_rows=nop._shrink_rows(nop.DEFAULT_BLOCK_ROWS,
-                                                 64),
-            interpret=True)
-
-    # explicit block_rows equal to the shrunk default bypasses the
-    # autotuner; the resolved path must lower to the identical program
-    assert _hlo(ln_auto, x2) == _hlo(ln_hand, x2)
+    assert _hlo(grad_of(flash_auto), q) == _hlo(grad_of(flash_hand), q)
 
 
 def test_resolve_trace_safe():
@@ -262,13 +267,13 @@ def test_resolve_trace_safe():
 def test_enabling_autotune_mid_process_still_tunes(monkeypatch):
     """A key first resolved with tuning OFF (default memo) must still be
     tuned when the flag is flipped later in the same process."""
-    default = at.resolve("ln", LN_KEY)
-    assert default == nop._ln_candidates(LN_KEY)[0]
+    default = at.resolve("rows", ROWS_KEY)
+    assert default == _rows_candidates(ROWS_KEY)[0]
     monkeypatch.setenv("PADDLE_TPU_AUTOTUNE", "1")
     times = iter([9.0, 9.0, 1.0, 9.0, 9.0])
     monkeypatch.setattr(at, "_time_callable", lambda fn, s: next(times))
-    tuned = at.resolve("ln", LN_KEY)
-    assert tuned == nop._ln_candidates(LN_KEY)[2]
+    tuned = at.resolve("rows", ROWS_KEY)
+    assert tuned == _rows_candidates(ROWS_KEY)[2]
 
 
 def test_multihost_gates_lazy_tuning(monkeypatch):
@@ -280,38 +285,25 @@ def test_multihost_gates_lazy_tuning(monkeypatch):
     monkeypatch.setattr(at, "_single_process", lambda: False)
     monkeypatch.setattr(at, "_time_callable",
                         lambda fn, s: pytest.fail("timed on multihost"))
-    assert at.resolve("ln", LN_KEY) == nop._ln_candidates(LN_KEY)[0]
+    assert at.resolve("rows", ROWS_KEY) == _rows_candidates(ROWS_KEY)[0]
     # explicit tune() (CLI warm) still works — pytest.fail above would
     # fire if it went through _time_callable, so un-patch first
     monkeypatch.setattr(at, "_time_callable", lambda fn, s: 1.0)
     at._MEMO.clear()
-    assert at.tune("ln", LN_KEY) == nop._ln_candidates(LN_KEY)[0]
+    assert at.tune("rows", ROWS_KEY) == _rows_candidates(ROWS_KEY)[0]
 
 
 def test_report_snapshot():
-    at.resolve("ln", LN_KEY)
+    at.resolve("rows", ROWS_KEY)
     rep = at.report()
-    assert rep["ln"][at.key_str(LN_KEY)]["config"]["block_rows"] == 64
+    assert rep["rows"][at.key_str(ROWS_KEY)]["config"]["block_rows"] == 64
 
 
 def test_report_includes_pinned_families(monkeypatch):
     """The PERF.md attribution protocol pins one family and reads
     bench.py's 'autotune' field — pinned resolutions must appear in
     report(), not just memoised ones."""
-    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_PIN", "ln=base:block_rows=8")
-    at.resolve("ln", LN_KEY)
+    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_PIN", "rows=base:block_rows=8")
+    at.resolve("rows", ROWS_KEY)
     rep = at.report()
-    assert rep["ln"][at.key_str(LN_KEY)]["config"]["block_rows"] == 8
-
-
-def test_lse_candidates_all_lane_aligned():
-    """Every emitted ce_lse candidate must pass the production validator
-    in _lse_call (chunk % 128) — at v=50304 the naive half-chunk of 384
-    is 192, which dispatch would silently discard."""
-    for key in (cep.autotune_key(8192, 50304, jnp.bfloat16),
-                cep.autotune_key(64, 2048, jnp.float32)):
-        for cand in cep._lse_candidates(key):
-            cfg = cand["config"]
-            assert cfg["chunk"] % 128 == 0, cand
-            assert key["v"] % cfg["chunk"] == 0, cand
-            assert key["n"] % cfg["block_rows"] == 0, cand
+    assert rep["rows"][at.key_str(ROWS_KEY)]["config"]["block_rows"] == 8

@@ -207,13 +207,13 @@ def test_compile_program_caches_on_meta():
 @pytest.mark.slow   # tier-1 wall budget: runs unfiltered in CI (see ci.yml)
 def test_programs_cli_pattern_subset(capsys):
     from paddle_tpu.observability.__main__ import main
-    rc = main(["programs", "pallas/ln/*"])
+    rc = main(["programs", "pallas/flash_fwd/base"])
     out = capsys.readouterr().out
     assert rc == 0
-    assert "pallas/ln/base" in out and "priced" in out
-    rc = main(["programs", "pallas/ln/*", "--format", "json"])
+    assert "pallas/flash_fwd/base" in out and "priced" in out
+    rc = main(["programs", "pallas/flash_fwd/base", "--format", "json"])
     doc = json.loads(capsys.readouterr().out)
-    assert rc == 0 and doc[0]["name"] == "pallas/ln/base"
+    assert rc == 0 and doc[0]["name"] == "pallas/flash_fwd/base"
     assert doc[0]["available"] and doc[0]["peak_bytes"] > 0
     # off-chip Pallas rows are labeled as interpret-mode pricing
     assert "interpret" in doc[0]["note"]
@@ -424,8 +424,10 @@ def test_hbm_restore_transient_gauge(tmp_path):
 def test_hbm_marks_land_in_chrome_export(tmp_path):
     from paddle_tpu.observability import tracing
     led = hbm.enable()
+    live = jnp.ones((8,), jnp.float32)    # a lane needs one live array
     try:
         led.sample("chrome")
+        del live
         tr = tracing.Tracer()
         tr.add_span("decode", 1000, 2000, trace_id=1)
         out = tmp_path / "chrome.json"

@@ -229,14 +229,6 @@ def test_a_refused_shape_still_differentiates():
     assert float(jnp.max(jnp.abs(got[1][:, s:]))) == 0.0
 
 
-def test_the_forced_head_group_pins_the_resident_backward_too(monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_FLASH_HEAD_GROUP", "4")
-    assert fap._resident_bwd_group(1024, 1024, 16, 64, True, 512, 512) == 4
-    monkeypatch.setenv("PADDLE_TPU_FLASH_HEAD_GROUP", "8")    # hg*d = 512
-    assert fap._resident_bwd_group(1024, 1024, 16, 64, True, 512, 512) \
-        is None
-
-
 @pytest.mark.parametrize("config,want", [
     # a pinned group the path can hold is kept
     ({"block_q": 512, "block_k": 512, "hg": 4},

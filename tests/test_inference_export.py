@@ -89,6 +89,25 @@ def test_jit_save_load_roundtrip(tmp_path):
     np.testing.assert_allclose(got.numpy(), want, rtol=1e-5, atol=1e-5)
 
 
+def test_gpt_jit_save_load_parity(tmp_path):
+    """The GPT model exports through the StableHLO artifact path and the
+    loaded program (no class) returns the eager model's logits."""
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+
+    paddle.seed(0)
+    m = GPTForCausalLM(GPTConfig.tiny())
+    m.eval()
+    prefix = os.path.join(str(tmp_path), "gpt")
+    paddle.jit.save(m, prefix, input_spec=[InputSpec([1, 8], "int32", "ids")])
+    loaded = paddle.jit.load(prefix)
+    ids = paddle.to_tensor(
+        np.random.default_rng(6).integers(0, 512, (1, 8)).astype("int32"))
+    got = loaded(ids)
+    got = got[0] if isinstance(got, (list, tuple)) else got
+    np.testing.assert_allclose(got.numpy(), m(ids).numpy(),
+                               rtol=1e-4, atol=1e-5)
+
+
 def test_jit_save_needs_spec(tmp_path):
     with pytest.raises(ValueError):
         paddle.jit.save(SmallNet(), os.path.join(str(tmp_path), "m"))

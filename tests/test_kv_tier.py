@@ -48,11 +48,9 @@ VOCAB = 128
 BUDGET = 16 << 20
 
 
-def _tiny_model(scan_layers=False, seed=0):
+def _tiny_model(seed=0):
     paddle.seed(seed)
-    cfg = GPTConfig.tiny()
-    cfg.scan_layers = scan_layers
-    m = GPTForCausalLM(cfg)
+    m = GPTForCausalLM(GPTConfig.tiny())
     m.eval()
     return m
 
@@ -131,20 +129,14 @@ def test_host_tier_lru_budget_honesty():
 # spill -> evict -> host-fetch -> resume bit-parity (the acceptance sweep)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("scan_layers", [
-    False,
-    # the scan twin rides in the CI serving job (unfiltered) so tier-1
-    # keeps one full parity sweep, not two
-    pytest.param(True, marks=pytest.mark.slow),
-], ids=["layered", "scan"])
-def test_spill_fetch_greedy_parity_both_layouts(scan_layers, monkeypatch):
+def test_spill_fetch_greedy_parity(monkeypatch):
     """Wave 1 populates the device prefix cache; spill_cached_pages
     pushes every cached page to host RAM and evicts it device-side;
     wave 2 re-admits the same prompts THROUGH the host tier — greedy
     output bit-identical across both waves and vs a tier-off engine,
     under the strict watchdog."""
     monkeypatch.setenv("PADDLE_TPU_STRICT_COMPILE", "1")
-    m = _tiny_model(scan_layers=scan_layers)
+    m = _tiny_model()
     prompts = _prompts(4, seed=1)
     baseline, _ = _drive(_engine(m, tier=False), prompts)
 

@@ -173,7 +173,6 @@ def test_catalog_coverage_is_two_way(monkeypatch, tmp_path):
                                                    SocketReset, chaos,
                                                    declare)
     from paddle_tpu.kernels import autotune as at
-    from paddle_tpu.kernels import norm_pallas as nop
     from paddle_tpu.observability import hbm
 
     reg = obs.default_registry()
@@ -226,10 +225,12 @@ def test_catalog_coverage_is_two_way(monkeypatch, tmp_path):
 
     # -- serving D: tensor-parallel sharded decode (ISSUE 12) — drives the
     # tp_degree gauge past 1 and, via the opt-in, the per-step
-    # collective-bytes counter priced from the compiled sharded program
+    # collective-bytes counter priced from the compiled sharded program;
+    # its collectives run as the rings (mp.overlap_chunks, one increment
+    # an island traced)
     monkeypatch.setenv("PADDLE_TPU_METRICS_COLLECTIVES", "1")
     tp_eng = DecodeEngine(model, num_slots=2, max_len=32, seed=0,
-                          page_size=8, tp=2)
+                          page_size=8, tp=2, overlap_comm=True)
     monkeypatch.delenv("PADDLE_TPU_METRICS_COLLECTIVES")
     tok, _ = tp_eng.prefill(0, rng.integers(0, cfg.vocab_size, (6,)),
                             temperature=0.0)
@@ -389,10 +390,20 @@ def test_catalog_coverage_is_two_way(monkeypatch, tmp_path):
 
     # -- autotune: resolve miss, one real timed tune, then the memoised
     # winner resolves as a HIT (both cache counters must fire)
-    at.resolve("ln", nop.autotune_key(8, 64, jnp.float32))
-    monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_SAMPLES", "1")
-    at.tune("ln", nop.autotune_key(8, 64, jnp.float32), persist=False)
-    at.resolve("ln", nop.autotune_key(8, 64, jnp.float32))
+    # (a family of the test's own: two candidates of one jnp function)
+    x = jnp.ones((8, 64), jnp.float32)
+    at.register_family(
+        "_test_obs", lambda key: [{"variant": "base", "config": {"axis": a}}
+                                  for a in (0, 1)],
+        lambda cand, key: lambda: jax.block_until_ready(
+            jnp.sum(x, axis=cand["config"]["axis"])))
+    try:
+        at.resolve("_test_obs", {"n": 8})
+        monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_SAMPLES", "1")
+        at.tune("_test_obs", {"n": 8}, persist=False)
+        at.resolve("_test_obs", {"n": 8})
+    finally:
+        at._FAMILIES.pop("_test_obs", None)
 
     # -- flash kernels: one traced causal call counts its score elements,
     # its backward the residency the shape chose (flash.bwd_calls{path}) ---

@@ -3,8 +3,7 @@ chunked prefill.
 
 Covers the acceptance criteria:
 * paged-vs-slotted greedy decode is BIT-identical, and paged decode
-  logits match a full-forward recompute at every position, for both
-  layer layouts (python per-layer walk and scan_layers);
+  logits match a full-forward recompute at every position;
 * prefix-sharing correctness under copy-on-write: an admission that
   maps another request's pages never recomputes them, and mutating one
   sharer (its decode appends) never perturbs the other's logits;
@@ -27,11 +26,9 @@ from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.serving.pages import PageAllocator, PagePoolExhausted
 
 
-def _tiny_model(scan_layers=False, seed=0):
+def _tiny_model(seed=0):
     paddle.seed(seed)
-    cfg = GPTConfig.tiny()
-    cfg.scan_layers = scan_layers
-    m = GPTForCausalLM(cfg)
+    m = GPTForCausalLM(GPTConfig.tiny())
     m.eval()
     return m
 
@@ -148,11 +145,10 @@ def test_allocator_cow_remap():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow   # tier-1 wall budget: runs unfiltered in CI (see ci.yml)
-@pytest.mark.parametrize("scan_layers", [False, True])
-def test_model_level_paged_decode_parity(scan_layers):
+def test_model_level_paged_decode_parity():
     """model(x, cache=PagedKVCache) matches the full forward at every
-    position, both layer layouts (dense identity table — no allocator)."""
-    m = _tiny_model(scan_layers)
+    position (dense identity table — no allocator)."""
+    m = _tiny_model()
     ids = np.random.default_rng(3).integers(0, 512, (1, 8)).astype("int32")
     full = m(paddle.to_tensor(ids)).numpy()
     cache = m.gen_paged_cache(1, max_len=64, page_size=16)
@@ -166,12 +162,11 @@ def test_model_level_paged_decode_parity(scan_layers):
     assert int(np.asarray(cache.lengths)[0]) == 8
 
 
-@pytest.mark.parametrize("scan_layers", [False, True])
-def test_paged_vs_slotted_greedy_decode_bit_identical(scan_layers):
+def test_paged_vs_slotted_greedy_decode_bit_identical():
     """The acceptance criterion: greedy decode over the paged engine
     emits the EXACT token sequence of the slotted engine."""
     from paddle_tpu.serving.engine import DecodeEngine
-    m = _tiny_model(scan_layers)
+    m = _tiny_model()
     prompts = [np.random.default_rng(7).integers(0, 512, (n,))
                for n in (5, 11)]
     seqs = {}

@@ -22,11 +22,9 @@ import paddle_tpu as paddle
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 
 
-def _tiny_model(scan_layers=False, seed=0):
+def _tiny_model(seed=0):
     paddle.seed(seed)
-    cfg = GPTConfig.tiny()
-    cfg.scan_layers = scan_layers
-    m = GPTForCausalLM(cfg)
+    m = GPTForCausalLM(GPTConfig.tiny())
     m.eval()
     return m
 
@@ -57,9 +55,8 @@ def test_gen_cache_is_static_slotted():
         cache.lengths.dtype) == "int32"
 
 
-@pytest.mark.parametrize("scan_layers", [False, True])
-def test_model_level_slotted_decode_parity(scan_layers):
-    m = _tiny_model(scan_layers)
+def test_model_level_slotted_decode_parity():
+    m = _tiny_model()
     ids = np.random.default_rng(3).integers(0, 512, (1, 8)).astype("int32")
     full = m(paddle.to_tensor(ids)).numpy()
     cache = m.gen_cache(1, max_len=64)

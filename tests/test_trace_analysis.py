@@ -263,8 +263,8 @@ def test_registry_builds_and_is_strict_green():
     assert "gpt_decode" in names
     assert "pipeline_1f1b" in names, skipped   # conftest forces 8 devices
     assert any(n.startswith("pallas/flash_fwd/") for n in names)
-    assert any(n.startswith("pallas/ce_lse/") for n in names)
-    assert any(n.startswith("pallas/ln/") for n in names)
+    assert any(n.startswith("pallas/flash_bwd/") for n in names)
+    assert any(n.startswith("pallas/decode_attn/") for n in names)
     # every registered flash VARIANT is a program
     for v in ("base", "bf16chain", "iotafree", "pipelined"):
         assert "pallas/flash_fwd/%s" % v in names
@@ -311,12 +311,12 @@ def test_cli_trace_mode(tmp_path, capsys):
     # zero programs matched -> operational error, not silent green
     assert rc == 2
 
-    rc = main(["pallas/ln/*", "--trace", "--root", REPO, "--strict",
+    rc = main(["pallas/decode_attn/*", "--trace", "--root", REPO, "--strict",
                "-q"])
     assert rc == 0
 
     # JSON format is machine-readable and carries the findings
-    rc = main(["pallas/ln/*", "--trace", "--root", REPO,
+    rc = main(["pallas/decode_attn/*", "--trace", "--root", REPO,
                "--format", "json"])
     doc = json.loads(capsys.readouterr().out)
     assert rc == 0 and doc["ok"] and doc["files"] >= 1
@@ -327,7 +327,7 @@ def test_cli_select_and_github_format(capsys):
     from paddle_tpu.analysis.__main__ import main
 
     # --select with a trace rule id runs only that pass
-    rc = main(["pallas/ln/*", "--trace", "--select", "TPU504",
+    rc = main(["pallas/decode_attn/*", "--trace", "--select", "TPU504",
                "--root", REPO, "--strict", "-q"])
     assert rc == 0
     capsys.readouterr()

@@ -136,10 +136,7 @@ def test_zero_stage2_grads_reduce_scattered(sdp_mesh):
     ref = _build()
     ref_opt = paddle.optimizer.AdamW(parameters=ref.parameters(),
                                      learning_rate=0.01)
-    # explicit flat_master=False: _grads_core must expose per-name grads
-    # regardless of any future default-layout change
-    ref_step = TrainStep(ref, _loss, ref_opt, donate=False,
-                         flat_master=False)
+    ref_step = TrainStep(ref, _loss, ref_opt, donate=False)
     _, _, ref_grads = jax.jit(ref_step._grads_core)(
         ref_step.params, ref_step.buffers, jax.random.key(0),
         (x._array, y._array))
