@@ -58,6 +58,19 @@ def note_score_elements(computed: int, causal: int) -> None:
         pass
 
 
+def note_fwd_call(operands: str) -> None:
+    """Drive ``flash.fwd_calls{operands}`` at trace time — one inc per
+    forward call traced, ``operands`` how q, k and v reach the kernel:
+    ``packed`` (three block index maps onto the fused projection's (b, s,
+    3*h*d) output) or ``split`` (three arrays)."""
+    try:
+        from ..observability import registry as _reg
+        _reg.counter("flash.fwd_calls",
+                     ("operands",)).labels(operands=operands).inc()
+    except Exception:
+        pass
+
+
 def note_bwd_call(path: str) -> None:
     """Drive ``flash.bwd_calls{path}`` at trace time — one inc per
     backward call traced, ``path`` the residency its shape chose
@@ -102,6 +115,17 @@ def supported(q, k=None, interpret=None) -> bool:
     :func:`interpret_scope` is active) admits non-TPU backends — the kernel
     then runs in the Pallas interpreter.
     """
+    if q.ndim != 4:
+        return False
+    s, h, d = q.shape[1], q.shape[2], q.shape[3]
+    if k is not None and k.shape[1] != s:
+        return False
+    return _shape_supported(s, h, d, interpret)
+
+
+def _shape_supported(s, h, d, interpret) -> bool:
+    """:func:`supported` of square self-attention over ``s`` tokens and
+    ``h`` heads of ``d``."""
     import os
     if os.getenv("PADDLE_TPU_DISABLE_FLASH", "").lower() in ("1", "true",
                                                              "yes"):
@@ -109,11 +133,6 @@ def supported(q, k=None, interpret=None) -> bool:
     if interpret is None:
         interpret = _INTERPRET
     if not interpret and jax.default_backend() != "tpu":
-        return False
-    if q.ndim != 4:
-        return False
-    s, h, d = q.shape[1], q.shape[2], q.shape[3]
-    if k is not None and k.shape[1] != s:
         return False
     if s % _DEFAULT_BLOCK_Q or d not in (64, 128, 256):
         return False
@@ -127,16 +146,58 @@ def supported(q, k=None, interpret=None) -> bool:
     return s <= max_supported_seq(h, d)
 
 
-def flash_attention_bshd(q, k, v, causal=False, scale=None, interpret=None):
-    """q,k,v: (B, S, H, D) -> (B, S, H, D) — native layout, no transposes.
+def packed_supported(qkv, num_heads) -> bool:
+    """Whether :func:`flash_attention_packed` applies to a fused
+    projection's output, (B, S, 3*H*D) in ``[q | k | v]`` column order:
+    where :func:`supported` admits its (B, S, H, D) parts, a part is whole
+    lane-aligned column blocks (H*D % 128: a head group's block is then
+    never the part's full width, which Mosaic asks of an unaligned one),
+    and no head axis is live — the fused projection is column-sharded at
+    3*H*D / mp, which is not a head boundary of ``[q | k | v]``, so a
+    program under 'mp' keeps the slices (and its head re-deal)."""
+    if qkv.ndim != 3 or qkv.shape[2] % (3 * num_heads):
+        return False
+    s, d = qkv.shape[1], qkv.shape[2] // (3 * num_heads)
+    if (num_heads * d) % 128 or _live_axes(_active_mesh(), _HEAD_AXES):
+        return False
+    return _shape_supported(s, num_heads, d, None)
+
+
+def _per_shard(kernel, operands, batch, heads, in_spec, out_spec):
+    """``kernel(*operands)``, per shard under a multi-device mesh.
 
     A Mosaic custom call has no GSPMD partitioning rule, so in a program
     traced for a multi-device mesh the kernel is wrapped in a shard_map
     over the axes that shard batch ('dp', 'sdp') and heads ('mp'): every
-    device runs the kernel on its own (B/dp, S, H/mp, D) block and no
-    collective feeds it.  A batch or head count the mesh does not divide
-    raises — there is no quiet O(S^2) path behind this entry.
-    """
+    device runs the kernel on its own block and no collective feeds it.
+    ``in_spec`` / ``out_spec`` make an operand's and the result's
+    PartitionSpec from the live (batch axes, head axes).  A batch or head
+    count the mesh does not divide raises — there is no quiet O(S^2) path
+    behind these entries."""
+    mesh = _active_mesh()
+    batch_axes = _live_axes(mesh, _BATCH_AXES)
+    head_axes = _live_axes(mesh, _HEAD_AXES)
+    if not (batch_axes or head_axes):
+        return kernel(*operands)
+    if batch % _ways(mesh, batch_axes) or heads % _ways(mesh, head_axes):
+        raise ValueError(
+            "flash attention under mesh %s: batch %d / heads %d are not "
+            "divisible by the %s x %s axes that shard them"
+            % (dict(mesh.shape), batch, heads, batch_axes, head_axes))
+    # check_vma=False: pallas_call outputs carry no varying-axes type of
+    # their own, and nothing here is replicated across the mapped axes
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(in_spec(batch_axes or None, head_axes or None),)
+        * len(operands),
+        out_specs=out_spec(batch_axes or None, head_axes or None),
+        check_vma=False)(*operands)
+
+
+def flash_attention_bshd(q, k, v, causal=False, scale=None, interpret=None):
+    """q,k,v: (B, S, H, D) -> (B, S, H, D) — native layout, no transposes.
+    Under a multi-device mesh every device runs the kernel on its own
+    (B/dp, S, H/mp, D) block (:func:`_per_shard`)."""
     from .flash_attention_pallas import flash_attention_bshd_native
     if interpret is None:
         interpret = _INTERPRET
@@ -145,23 +206,38 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None, interpret=None):
         return flash_attention_bshd_native(q_, k_, v_, causal=causal,
                                            scale=scale, interpret=interpret)
 
-    mesh = _active_mesh()
-    batch_axes = _live_axes(mesh, _BATCH_AXES)
-    head_axes = _live_axes(mesh, _HEAD_AXES)
-    if not (batch_axes or head_axes):
-        return kernel(q, k, v)
-    b, h = q.shape[0], q.shape[2]
-    if b % _ways(mesh, batch_axes) or h % _ways(mesh, head_axes):
+    def spec(batch_axes, head_axes):
+        return jax.sharding.PartitionSpec(batch_axes, None, head_axes, None)
+
+    return _per_shard(kernel, (q, k, v), q.shape[0], q.shape[2], spec, spec)
+
+
+def flash_attention_packed(qkv, num_heads, causal=False, scale=None,
+                           interpret=None):
+    """:func:`flash_attention_bshd` of the ``[q | k | v]`` column parts of
+    qkv (B, S, 3*H*D) -> (B, S, H, D), the parts read where they lie: no
+    slice pass in front of the kernel (201 MB read and written a layer at
+    16 x 1,024 x 16 heads of 64).  Under a mesh that shards the batch every
+    device runs the kernel on its own (B/dp, S, 3*H*D) block; for
+    :func:`packed_supported` shapes only (a live head axis raises)."""
+    from .flash_attention_pallas import flash_attention_packed_native
+    if interpret is None:
+        interpret = _INTERPRET
+    if _live_axes(_active_mesh(), _HEAD_AXES):
         raise ValueError(
-            "flash attention under mesh %s: batch %d / heads %d are not "
-            "divisible by the %s x %s axes that shard them"
-            % (dict(mesh.shape), b, h, batch_axes, head_axes))
-    spec = jax.sharding.PartitionSpec(batch_axes or None, None,
-                                      head_axes or None, None)
-    # check_vma=False: pallas_call outputs carry no varying-axes type of
-    # their own, and nothing here is replicated across the mapped axes
-    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+            "flash_attention_packed under a live %s axis: the fused "
+            "projection's column shards are not head boundaries of "
+            "[q | k | v]; slice and call flash_attention_bshd"
+            % (_HEAD_AXES,))
+
+    def kernel(qkv_):
+        return flash_attention_packed_native(qkv_, num_heads, causal=causal,
+                                             scale=scale, interpret=interpret)
+
+    P = jax.sharding.PartitionSpec
+    return _per_shard(kernel, (qkv,), qkv.shape[0], num_heads,
+                      lambda batch_axes, _: P(batch_axes, None, None),
+                      lambda batch_axes, _: P(batch_axes, None, None, None))
 
 
 def flash_attention_bshd_with_lse(q, k, v, causal=False, scale=None,

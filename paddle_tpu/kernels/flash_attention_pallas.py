@@ -13,6 +13,20 @@ removes the six (B,S,H,D) <-> (B,H,S,D) transposes per layer that a
 head-major kernel forces around every call (history, retired set-up:
 about 9 ms a step of HBM copies on GPT-2 345M).
 
+Packed operands (PR 32).  The three operands need not be three arrays: a
+fused projection's (B, S, 3*H*D) output in ``[q | k | v]`` column order is
+passed three times and each part is picked by its BlockSpec's index map —
+column block ``g`` for q, ``n + g`` for k, ``2n + g`` for v, ``n = H*D /
+(hg*D)`` at the kernel's own head group (``_kv_parts``, ``_kv_specs``;
+``flash_attention_packed_native``).  A Mosaic call cannot take a slice as
+an operand, so slicing first is a three-output pass over the buffer a
+layer (201 MB at 16 x 1,024 x 16 heads of 64); in place, the DMA moves the
+same tiles from a row three times as wide and no kernel body changes.  The
+backward still writes dq, dk and dv as three arrays, and the packed
+``custom_vjp`` returns their sum of pads: XLA fuses that into the
+projection's gradient GEMMs (a concatenate would be three update-slice
+passes).
+
 Forward: grid (B, n_hg, nq); the whole K/V sequence stays VMEM-resident and
 is scanned with a fori loop over the fully-visible k blocks (no mask
 arithmetic), the causal band behind it.  Sequences whose K/V do not fit
@@ -190,6 +204,33 @@ def _pid(i):
     # strong int32: program_id is weakly typed and x64 mode would promote
     # its arithmetic to i64, which mosaic cannot lower
     return jax.lax.convert_element_type(pl.program_id(i), jnp.int32)
+
+
+def _kv_parts(packed: bool, n: int):
+    """Column-block offsets ``(k, v)`` of the key and value parts in the
+    operand they are read from.  Packed, q, k and v are ONE ``(b, s,
+    3*h*d)`` buffer in ``[q | k | v]`` column order (the fused projection's
+    output as the GEMM wrote it), each part ``n`` blocks of ``hg*d``
+    columns wide, passed to the call three times: the parts are told apart
+    by their block index maps alone and no kernel body knows.  Each kernel
+    has its own head group, hence its own ``n``."""
+    return (n, 2 * n) if packed else (0, 0)
+
+
+def _kv_specs(block, index_map, packed: bool, n: int):
+    """The key's and the value's BlockSpec: ``index_map`` (a part's own,
+    ``-> (batch, row block, column block)``) with the column block moved
+    right by the part's offset (:func:`_kv_parts`; the same map where that
+    is 0)."""
+    def at(off):
+        if not off:
+            return index_map
+
+        def shifted(*ids):
+            bi, r, g = index_map(*ids)
+            return bi, r, g + off
+        return shifted
+    return [pl.BlockSpec(block, at(off)) for off in _kv_parts(packed, n)]
 
 
 # VMEM budget for the forward's resident K+V per grid cell
@@ -655,7 +696,7 @@ def _fwd_kernel_streamed(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
 
 def _fwd_kernel_pipelined(q_ref, k_any, v_any, o_ref, lse_ref, k_sc, v_sc,
                           sem, *, causal, scale, hg, d, block_k, nk, tile,
-                          bf16chain=False):
+                          kv_parts=(0, 0), bf16chain=False):
     """Forward with EXPLICIT K/V streaming: K/V stay in HBM (ANY memory
     space) and block_k-sized chunks are double-buffered into VMEM scratch
     with async copies, so the fetch of chunk i+1 overlaps the softmax chain
@@ -669,7 +710,10 @@ def _fwd_kernel_pipelined(q_ref, k_any, v_any, o_ref, lse_ref, k_sc, v_sc,
     bi = _pid(0)
     g = _pid(1)
     qi = _pid(2)
-    col_base = jax.lax.mul(g, _i32(hgd))
+    # K/V are addressed by hand here: their parts' offsets (_kv_parts)
+    # enter the column base where the other kernels' index maps take them
+    k_base, v_base = (jax.lax.mul(g + _i32(off) if off else g, _i32(hgd))
+                      for off in kv_parts)
 
     if causal:
         # only chunks up to the band end attend; rest are strictly future
@@ -683,10 +727,10 @@ def _fwd_kernel_pipelined(q_ref, k_any, v_any, o_ref, lse_ref, k_sc, v_sc,
     def kv_dma(slot, kb):
         start = jax.lax.mul(kb, _i32(block_k))
         ck = pltpu.make_async_copy(
-            k_any.at[bi, pl.ds(start, block_k), pl.ds(col_base, hgd)],
+            k_any.at[bi, pl.ds(start, block_k), pl.ds(k_base, hgd)],
             k_sc.at[slot], sem.at[slot, 0])
         cv = pltpu.make_async_copy(
-            v_any.at[bi, pl.ds(start, block_k), pl.ds(col_base, hgd)],
+            v_any.at[bi, pl.ds(start, block_k), pl.ds(v_base, hgd)],
             v_sc.at[slot], sem.at[slot, 1])
         return ck, cv
 
@@ -745,11 +789,15 @@ def _fwd_kernel_pipelined(q_ref, k_any, v_any, o_ref, lse_ref, k_sc, v_sc,
                     (0, 0, hh, pl.ds(qi, 1), slice(None)))
 
 
-def _flash_fwd(q3, k3, v3, causal, scale, d, interpret, spec):
+def _flash_fwd(q3, k3, v3, causal, scale, d, interpret, spec, packed=False):
     """The forward's entry: settles what the program depends on beside
-    its operands (which family, the band's tile), counts the call's score
-    elements, and hands over to the jitted builder, so that the layers of
-    a model share ONE traced kernel instead of tracing one each."""
+    its operands (which family, the band's tile), counts the call and its
+    score elements, and hands over to the jitted builder, so that the
+    layers of a model share ONE traced kernel instead of tracing one each.
+    ``packed``: q3, k3 and v3 are the same ``(b, s, 3*h*d)`` buffer
+    (:func:`_kv_parts`)."""
+    from .flash_attention import note_fwd_call
+    note_fwd_call("packed" if packed else "split")
     variant, block_q, block_k, hg = spec
     feats = variant_features(variant, _FWD_FEATURES)
     family = ("pipelined" if "pipelined" in feats else
@@ -761,22 +809,25 @@ def _flash_fwd(q3, k3, v3, causal, scale, d, interpret, spec):
         # traced): the dispatch module writes the counter
         from .flash_attention import note_score_elements
         b, s, hd = q3.shape
-        note_score_elements(*(b * (hd // d) * n
+        heads = hd // d // (3 if packed else 1)
+        note_score_elements(*(b * heads * n
                               for n in score_elements(s, block_q, tile)))
     # trace with x64 off: the global x64 mode (needed for paddle's int64
     # semantics) surfaces i64/f64 intermediates that mosaic cannot lower
     with x64_scope(False):
         return _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret,
-                                spec, family, tile)
+                                spec, family, tile, packed)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec, family,
-                     tile):
+                     tile, packed):
     variant, block_q, block_k, hg = spec
     feats = variant_features(variant, _FWD_FEATURES)
     bf16chain = "bf16chain" in feats
     b, s, hd = q3.shape
+    if packed:
+        hd //= 3
     sk = k3.shape[1]
     n_hg = hd // (hg * d)
     nq = s // block_q
@@ -791,7 +842,8 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec, family,
         # grid-streamed paths; the autotuner decides when it wins)
         kernel = functools.partial(
             _fwd_kernel_pipelined, causal=causal, scale=scale, hg=hg, d=d,
-            block_k=block_k, nk=nk, tile=tile, bf16chain=bf16chain)
+            block_k=block_k, nk=nk, tile=tile,
+            kv_parts=_kv_parts(packed, n_hg), bf16chain=bf16chain)
         out, lse = pl.pallas_call(
             kernel,
             grid=(b, n_hg, nq),
@@ -822,7 +874,8 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec, family,
         kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
                                    hg=hg, d=d, block_k=block_k, tile=tile,
                                    bf16chain=bf16chain, parq=parq)
-        kv_spec = pl.BlockSpec((1, sk, hgd), lambda bi, g, i: (bi, 0, g))
+        kv_specs = _kv_specs((1, sk, hgd), lambda bi, g, i: (bi, 0, g),
+                             packed, n_hg)
         if parq:
             # per-q-block lse blocks: nothing is revisited, so every grid
             # dim can carry "parallel" dimension_semantics.  Stored
@@ -841,7 +894,7 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec, family,
         out, lse = pl.pallas_call(
             kernel,
             grid=(b, n_hg, nq),
-            in_specs=[q_spec3, kv_spec, kv_spec],
+            in_specs=[q_spec3, *kv_specs],
             out_specs=[q_spec3, lse_spec],
             out_shape=[out_shape, lse_shape],
             compiler_params=pltpu.CompilerParams(dimension_semantics=sem),
@@ -855,11 +908,12 @@ def _flash_fwd_inner(q3, k3, v3, causal, scale, d, interpret, spec, family,
                                scale=scale, hg=hg, d=d, nk=nk, tile=tile,
                                bf16chain=bf16chain)
     q_spec = pl.BlockSpec((1, block_q, hgd), lambda bi, g, i, j: (bi, i, g))
-    kv_spec = pl.BlockSpec((1, block_k, hgd), lambda bi, g, i, j: (bi, j, g))
+    kv_specs = _kv_specs((1, block_k, hgd),
+                         lambda bi, g, i, j: (bi, j, g), packed, n_hg)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, n_hg, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, *kv_specs],
         out_specs=[
             q_spec,
             pl.BlockSpec((1, 1, hg, nq, block_q),
@@ -1097,7 +1151,7 @@ def _bwd_resident_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest,
 #: the backward builders are jitted like the forward's: one traced kernel
 #: for all the layers of a model (operands dynamic, the rest static)
 _BWD_JIT = functools.partial(jax.jit,
-                             static_argnums=(6, 7, 8, 9, 10, 11, 12))
+                             static_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
 
 
 def _fold_lse(lse, b, h, hg, block_q):
@@ -1117,11 +1171,11 @@ def _fold_rows(x, b, h, hg, block_q):
 
 @_BWD_JIT
 def _bwd_dq(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
-            interpret, tile):
+            interpret, tile, packed):
     """The dq pallas_call of the split backward."""
     variant, block_q, block_k = spec
     feats = variant_features(variant, _BWD_FEATURES)
-    b, s, hd = q3.shape
+    b, s, hd = do3.shape
     sk = k3.shape[1]
     h = hd // d
     nq = s // block_q
@@ -1133,16 +1187,15 @@ def _bwd_dq(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
                             lambda bi, g, i, j: (bi, g, 0, 0, 0))
     q_spec_qout = pl.BlockSpec((1, block_q, hgd),
                                lambda bi, g, i, j: (bi, i, g))
-    kv_spec_qout = pl.BlockSpec((1, block_k, hgd),
-                                lambda bi, g, i, j: (bi, j, g))
+    kv_specs = _kv_specs((1, block_k, hgd),
+                         lambda bi, g, i, j: (bi, j, g), packed, h // hg)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
                           hg=hg, d=d, nk=nk,
                           tile=tile,
                           bf16chain="bf16chain" in feats),
         grid=(b, h // hg, nq, nk),
-        in_specs=[q_spec_qout, kv_spec_qout, kv_spec_qout, q_spec_qout,
-                  row_spec, row_spec],
+        in_specs=[q_spec_qout, *kv_specs, q_spec_qout, row_spec, row_spec],
         out_specs=q_spec_qout,
         out_shape=_sds((b, s, hd), q3.dtype, q3),
         scratch_shapes=[pltpu.VMEM((block_q, hgd), jnp.float32)],
@@ -1154,11 +1207,11 @@ def _bwd_dq(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
 
 @_BWD_JIT
 def _bwd_dkv(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
-             interpret, tile):
+             interpret, tile, packed):
     """The dk/dv pallas_call of the split backward."""
     variant, block_q, block_k = spec
     feats = variant_features(variant, _BWD_FEATURES)
-    b, s, hd = q3.shape
+    b, s, hd = do3.shape
     sk = k3.shape[1]
     h = hd // d
     nq = s // block_q
@@ -1170,16 +1223,16 @@ def _bwd_dkv(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
                             lambda bi, g, i, j: (bi, g, 0, 0, 0))
     q_spec_kout = pl.BlockSpec((1, block_q, hgd),
                                lambda bi, g, i, j: (bi, j, g))
-    kv_spec_kout = pl.BlockSpec((1, block_k, hgd),
-                                lambda bi, g, i, j: (bi, i, g))
+    kv_map = lambda bi, g, i, j: (bi, i, g)
+    kv_spec_kout = pl.BlockSpec((1, block_k, hgd), kv_map)
+    kv_specs = _kv_specs((1, block_k, hgd), kv_map, packed, h // hg)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
                           hg=hg, d=d, nq=nq,
                           tile=tile,
                           bf16chain="bf16chain" in feats),
         grid=(b, h // hg, nk, nq),
-        in_specs=[q_spec_kout, kv_spec_kout, kv_spec_kout, q_spec_kout,
-                  row_spec, row_spec],
+        in_specs=[q_spec_kout, *kv_specs, q_spec_kout, row_spec, row_spec],
         out_specs=[kv_spec_kout, kv_spec_kout],
         out_shape=[_sds((b, sk, hd), k3.dtype, k3),
                    _sds((b, sk, hd), v3.dtype, v3)],
@@ -1193,11 +1246,11 @@ def _bwd_dkv(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
 
 @_BWD_JIT
 def _bwd_merged(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
-                interpret, tile):
+                interpret, tile, packed):
     """The merged dQ/dK/dV pallas_call."""
     variant, block_q, block_k = spec
     feats = variant_features(variant, _BWD_FEATURES)
-    b, s, hd = q3.shape
+    b, s, hd = do3.shape
     sk = k3.shape[1]
     h = hd // d
     nq = s // block_q
@@ -1206,7 +1259,9 @@ def _bwd_merged(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
     lse5 = _fold_lse(lse, b, h, hg, block_q)
     delta5 = _fold_rows(delta, b, h, hg, block_q)
     q_spec = pl.BlockSpec((1, block_q, hgd), lambda bi, g, i, j: (bi, j, g))
-    kv_spec = pl.BlockSpec((1, block_k, hgd), lambda bi, g, i, j: (bi, i, g))
+    kv_map = lambda bi, g, i, j: (bi, i, g)
+    kv_spec = pl.BlockSpec((1, block_k, hgd), kv_map)
+    kv_specs = _kv_specs((1, block_k, hgd), kv_map, packed, h // hg)
     row_spec = pl.BlockSpec((1, 1, hg, nq, block_q),
                             lambda bi, g, i, j: (bi, g, 0, 0, 0))
     return pl.pallas_call(
@@ -1215,7 +1270,7 @@ def _bwd_merged(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
                           tile=tile,
                           bf16chain="bf16chain" in feats),
         grid=(b, h // hg, nk, nq),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, *kv_specs, q_spec, row_spec, row_spec],
         out_specs=[
             # dq: whole-sequence block, revisited; written at the last step
             pl.BlockSpec((1, s, hgd), lambda bi, g, i, j: (bi, 0, g)),
@@ -1240,25 +1295,27 @@ def _bwd_merged(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
 
 @_BWD_JIT
 def _bwd_resident(q3, k3, v3, do3, o3, rows, causal, scale, hg, d, spec,
-                  interpret, tile):
+                  interpret, tile, packed):
     """The resident backward's pallas_call: grid (b, h // hg), both
     parallel.  ``rows``: (lse,) or (lse, dlse) — f32 row statistics in any
     fold of (b, h, s)."""
     variant, block_q, block_k = spec
     feats = variant_features(variant, _BWD_FEATURES)
-    b, s, hd = q3.shape
+    b, s, hd = do3.shape
     sk = k3.shape[1]
     n_hg = hd // (hg * d)
     hgd = hg * d
-    q_spec = pl.BlockSpec((1, s, hgd), lambda bi, g: (bi, 0, g))
-    kv_spec = pl.BlockSpec((1, sk, hgd), lambda bi, g: (bi, 0, g))
+    cols = lambda bi, g: (bi, 0, g)
+    q_spec = pl.BlockSpec((1, s, hgd), cols)
+    kv_spec = pl.BlockSpec((1, sk, hgd), cols)
+    kv_specs = _kv_specs((1, sk, hgd), cols, packed, n_hg)
     row_spec = pl.BlockSpec((1, 1, hg, s), lambda bi, g: (bi, g, 0, 0))
     return pl.pallas_call(
         functools.partial(_bwd_resident_kernel, causal=causal, scale=scale,
                           hg=hg, d=d, block_q=block_q, block_k=block_k,
                           tile=tile, bf16chain="bf16chain" in feats),
         grid=(b, n_hg),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec] +
+        in_specs=[q_spec, *kv_specs, q_spec, q_spec] +
         [row_spec] * len(rows),
         out_specs=[q_spec, kv_spec, kv_spec],
         out_shape=[
@@ -1276,14 +1333,15 @@ def _bwd_resident(q3, k3, v3, do3, o3, rows, causal, scale, hg, d, spec,
 
 def _bwd_entry(builder):
     """``call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
-    interpret)`` over a jitted builder (the resident one takes ``o3`` and
-    its row statistics where the others take ``lse`` and ``delta``) — the
-    production entry and the autotuner's runner entry; the band's tile is
-    settled out here, where the builder's trace cache can see it."""
+    interpret, packed=False)`` over a jitted builder (the resident one
+    takes ``o3`` and its row statistics where the others take ``lse`` and
+    ``delta``) — the production entry and the autotuner's runner entry;
+    the band's tile is settled out here, where the builder's trace cache
+    can see it."""
     def call(q3, k3, v3, do3, lse, delta, causal, scale, hg, d, spec,
-             interpret):
+             interpret, packed=False):
         return builder(q3, k3, v3, do3, lse, delta, causal, scale, hg, d,
-                       spec, interpret, _band_tile(spec[2]))
+                       spec, interpret, _band_tile(spec[2]), packed)
     return call
 
 
@@ -1294,17 +1352,19 @@ _bwd_resident_call = _bwd_entry(_bwd_resident)
 
 
 def _flash_bwd(q3, k3, v3, o3, lse, do3, causal, scale, d, interpret, spec,
-               dlse=None):
+               dlse=None, packed=False):
     # dlse: optional (b, h, s) f32 rows, the cotangent of a base-e lse
     # OUTPUT (flash_attention_bshd_with_lse): dS_ij = P_ij (dP_ij - delta_i
     # + dlse_i), so it enters as delta - dlse.
     # spec: ("resident" | "merged", variant, block_q, block_k, hg) or
     #       ("split", (variant, bq, bk), (variant, bq, bk), hg) — decided
     # by _resolve_specs from the shape (_bwd_plan).
+    # packed: q3, k3 and v3 are the same (b, s, 3*h*d) buffer (_kv_parts);
+    # dq, dk and dv leave as three (b, s, h*d) arrays either way.
     from .flash_attention import note_bwd_call
     note_bwd_call(spec[0])
     with x64_scope(False):
-        b, s, hd = q3.shape
+        b, s, hd = do3.shape
         h = hd // d
         if spec[0] == "resident":
             # the kernel takes O and forms delta itself; the lse
@@ -1314,7 +1374,8 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, causal, scale, d, interpret, spec,
                 (lse, dlse.astype(jnp.float32))
             return _bwd_resident_call(q3, k3, v3, do3, o3, rows, causal,
                                       scale, hg, d,
-                                      (variant, block_q, block_k), interpret)
+                                      (variant, block_q, block_k), interpret,
+                                      packed)
         # delta = rowsum(dO * O) per head in XLA, folded to the kernels'
         # (b, n_hg, hg, nq, bq) row layout per call
         delta = jnp.sum(
@@ -1325,14 +1386,14 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, causal, scale, d, interpret, spec,
         if spec[0] == "split":
             _, dq_spec, dkv_spec, hg = spec
             dq = _bwd_dq_call(q3, k3, v3, do3, lse, delta, causal, scale,
-                              hg, d, dq_spec, interpret)
+                              hg, d, dq_spec, interpret, packed)
             dk, dv = _bwd_dkv_call(q3, k3, v3, do3, lse, delta, causal,
-                                   scale, hg, d, dkv_spec, interpret)
+                                   scale, hg, d, dkv_spec, interpret, packed)
             return dq, dk, dv
         _, variant, block_q, block_k, hg = spec
         return _bwd_merged_call(q3, k3, v3, do3, lse, delta, causal, scale,
                                 hg, d, (variant, block_q, block_k),
-                                interpret)
+                                interpret, packed)
 
 
 # ---------------------------------------------------------------------------
@@ -1374,6 +1435,45 @@ def _flash_vjp_bwd(causal, scale, d, interpret, fwd_spec, bwd_spec, res, g):
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
+def _flash_packed(qkv3, causal, scale, d, interpret, fwd_spec, bwd_spec):
+    """:func:`_flash` of the three column parts of ``qkv3`` (b, s, 3*h*d),
+    read where they lie (:func:`_kv_parts`): a Mosaic call cannot take a
+    slice as an operand, so slicing first costs a pass over the buffer and
+    three written copies a call."""
+    out, _ = _flash_fwd(qkv3, qkv3, qkv3, causal, scale, d, interpret,
+                        fwd_spec, packed=True)
+    return out
+
+
+def _flash_packed_vjp_fwd(qkv3, causal, scale, d, interpret, fwd_spec,
+                          bwd_spec):
+    out, lse = _flash_fwd(qkv3, qkv3, qkv3, causal, scale, d, interpret,
+                          fwd_spec, packed=True)
+    return out, (qkv3, out, lse)
+
+
+def _flash_packed_vjp_bwd(causal, scale, d, interpret, fwd_spec, bwd_spec,
+                          res, g):
+    qkv3, out, lse = res
+    dq, dk, dv = _flash_bwd(qkv3, qkv3, qkv3, out, lse, g, causal, scale, d,
+                            interpret, bwd_spec, packed=True)
+    # the cotangent as a SUM OF PADS, the transpose of three slices: XLA
+    # takes dq, dk and dv as operands of the projection's weight- and
+    # input-gradient GEMM fusions and of the bias reduce, and no (b, s,
+    # 3*h*d) buffer exists.  A concatenate here becomes three
+    # dynamic-update-slice passes over one (compiled for a described v5e;
+    # tests/test_flash_tpu_compile.py holds the program to it)
+    hd = dq.shape[2]
+    zero = jnp.zeros((), dq.dtype)
+    return (sum(jax.lax.pad(x, zero, ((0, 0, 0), (0, 0, 0),
+                                      (i * hd, (2 - i) * hd, 0)))
+                for i, x in enumerate((dq, dk, dv))),)
+
+
+_flash_packed.defvjp(_flash_packed_vjp_fwd, _flash_packed_vjp_bwd)
 
 
 def _prep_blocks(s, sk, causal, block_q, block_k, what):
@@ -1855,6 +1955,22 @@ def flash_attention_bshd_with_lse(q, k, v, causal=False, scale=None,
     return out.reshape(b, s, h, d), lse
 
 
+def _native_specs(b, s, sk, h, d, dtype, causal, block_q, block_k, variant):
+    """``(fwd_spec, bwd_spec)`` of a :func:`flash_attention_bshd_native`
+    or :func:`flash_attention_packed_native` call: the two resolve alike,
+    so the same shape runs the same kernels whichever way its operands
+    arrive."""
+    hg_b = _pick_head_group(h, d, max(s, sk))
+    hg_f = _pick_fwd_head_group(h, d, max(s, sk), hg_b)
+    default_blocks = (block_q, block_k) == (DEFAULT_BLOCK_Q,
+                                            DEFAULT_BLOCK_K)
+    block_q, block_k = _prep_blocks(s, sk, causal, block_q, block_k,
+                                    "flash_attention")
+    return _resolve_specs(
+        b, s, sk, h, d, dtype, causal, block_q, block_k, hg_f, hg_b,
+        variant=variant, use_autotune=default_blocks)
+
+
 def flash_attention_bshd_native(q, k, v, causal=False, scale=None,
                                 block_q=DEFAULT_BLOCK_Q,
                                 block_k=DEFAULT_BLOCK_K, interpret=False,
@@ -1866,20 +1982,37 @@ def flash_attention_bshd_native(q, k, v, causal=False, scale=None,
     sk = k.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    hg_b = _pick_head_group(h, d, max(s, sk))
-    hg_f = _pick_fwd_head_group(h, d, max(s, sk), hg_b)
-    default_blocks = (block_q, block_k) == (DEFAULT_BLOCK_Q,
-                                            DEFAULT_BLOCK_K)
-    block_q, block_k = _prep_blocks(s, sk, causal, block_q, block_k,
-                                    "flash_attention")
-    fwd_spec, bwd_spec = _resolve_specs(
-        b, s, sk, h, d, q.dtype, causal, block_q, block_k, hg_f, hg_b,
-        variant=variant, use_autotune=default_blocks)
+    fwd_spec, bwd_spec = _native_specs(b, s, sk, h, d, q.dtype, causal,
+                                       block_q, block_k, variant)
     q3 = q.reshape(b, s, h * d)
     k3 = k.reshape(b, sk, h * d)
     v3 = v.reshape(b, sk, h * d)
     out = _flash(q3, k3, v3, causal, float(scale), d, interpret, fwd_spec,
                  bwd_spec)
+    return out.reshape(b, s, h, d)
+
+
+def flash_attention_packed_native(qkv, num_heads, causal=False, scale=None,
+                                  block_q=DEFAULT_BLOCK_Q,
+                                  block_k=DEFAULT_BLOCK_K, interpret=False,
+                                  variant=None):
+    """:func:`flash_attention_bshd_native` of a fused projection's output:
+    qkv (B, S, 3*H*D) in ``[q | k | v]`` column order -> (B, S, H, D).  The
+    kernels read q, k and v where the GEMM wrote them, through their block
+    index maps; the result and the gradient equal those of the three
+    slices bit for bit (same kernels, same specs, same bytes)."""
+    b, s, width = qkv.shape
+    h = num_heads
+    if width % (3 * h):
+        raise ValueError("flash_attention_packed: a last dimension of %d "
+                         "is not 3 x %d heads x a head size" % (width, h))
+    d = width // (3 * h)
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    fwd_spec, bwd_spec = _native_specs(b, s, s, h, d, qkv.dtype, causal,
+                                       block_q, block_k, variant)
+    out = _flash_packed(qkv, causal, float(scale), d, interpret, fwd_spec,
+                        bwd_spec)
     return out.reshape(b, s, h, d)
 
 

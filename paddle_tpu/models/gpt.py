@@ -128,10 +128,22 @@ class GPTAttention(Layer):
                 name="mp_overlap_qkv")
         else:
             qkv = self.qkv_proj(x)
+            if cache is None and F.packed_attention_supported(
+                    qkv, self.num_heads, self.attn_dropout_p, self.training):
+                # the flash kernels read q, k and v where the projection
+                # wrote them (block index maps onto the fused buffer)
+                out = F.packed_attention(qkv, self.num_heads, is_causal=True)
+                out = ops.reshape(out, [b, s, self.hidden_size])
+                return self.resid_dropout(self._out_projection(out))
             # q/k/v as contiguous LAST-DIM slices of the fused projection:
             # reshape-to-(b,s,3,h,d)+unbind forces a transposed-layout copy
             # of the whole qkv activation per layer (~0.1 ms × 24 layers ×
-            # fwd+bwd on the 345M bench); last-dim slices are free
+            # fwd+bwd on the 345M bench).  Last-dim slices are free for an
+            # XLA consumer, which reads them in place (the cached and the
+            # reference attention, the GEMMs of the backward); a Mosaic
+            # kernel cannot take a slice as an operand, so in front of the
+            # flash kernel they are one three-output pass over the buffer
+            # (201 MB a layer at 16 x 1,024 x 1,024) — hence the branch above
             q = ops.reshape(qkv[:, :, :h],
                             [b, s, self.num_heads, self.head_dim])
             k = ops.reshape(qkv[:, :, h:2 * h],

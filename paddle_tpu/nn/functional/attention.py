@@ -4,6 +4,8 @@
 on TPU (paddle_tpu.kernels.flash_attention) and to a reference XLA
 implementation elsewhere — the TPU-native answer to the reference's fused
 FMHA (paddle/fluid/operators/fused/fmha_ref.h, fused_attention_op).
+``packed_attention`` is the same kernel over a fused q/k/v projection's
+output, read in place; ``packed_attention_supported`` says where it applies.
 """
 from __future__ import annotations
 
@@ -123,3 +125,36 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                   dropout_key)
 
     return call(raw, query, key, value, attn_mask, name="sdpa")
+
+
+def packed_attention_supported(qkv, num_heads, dropout_p=0.0,
+                               training=True) -> bool:
+    """Whether :func:`packed_attention` applies to ``qkv`` (batch, seq,
+    3 * heads * head_dim): exactly where ``scaled_dot_product_attention``
+    of its three column slices would run the flash kernel on one device or
+    under batch axes alone — dropout inactive, the shape supported
+    (kernels.flash_attention.packed_supported), no live head axis ('mp':
+    the fused projection's column shards are not head boundaries), and not
+    inside a 'sep' shard_map (the ring takes q, k and v apart).  Decided
+    from what the trace can see; there is no switch."""
+    if dropout_p > 0.0 and training:
+        return False
+    from ...distributed.collective import axis_in_trace
+    from ...kernels import flash_attention as fa
+    return fa.packed_supported(qkv, num_heads) and not axis_in_trace("sep")
+
+
+def packed_attention(qkv, num_heads, is_causal=False, scale=None):
+    """Self-attention over a fused projection's output: qkv (batch, seq,
+    3 * heads * head_dim) in ``[q | k | v]`` column order -> (batch, seq,
+    heads, head_dim), equal bit for bit to ``scaled_dot_product_attention``
+    of the three column slices.  The flash kernels read q, k and v where
+    the projection wrote them: a Mosaic call cannot take a slice as an
+    operand, so slicing first is a pass over the buffer and three written
+    copies a layer.  Only where :func:`packed_attention_supported`."""
+    def raw(x):
+        from ...kernels import flash_attention as fa
+        return fa.flash_attention_packed(x, num_heads, causal=is_causal,
+                                         scale=scale)
+
+    return call(raw, qkv, name="sdpa")

@@ -246,6 +246,13 @@ CATALOG = {
         "'split' (dq and dk/dv kernels).  Trace-time, one inc a backward "
         "call: a compile-once program contributes once",
         labels=("path",)),
+    "flash.fwd_calls": _m(
+        "counter", "flash forward calls traced so far by how q, k and v "
+        "reach the kernel: operands='packed' (three block index maps onto "
+        "the fused projection's (b, s, 3*h*d) output, no slice pass in "
+        "front of the kernel) or 'split' (three arrays).  Trace-time, one "
+        "inc a forward call: a compile-once program contributes once",
+        labels=("operands",)),
 
     # -- compile watchdog ---------------------------------------------------
     "compile.count": _m(

@@ -65,6 +65,24 @@ def _rows_family():
     at._FAMILIES.pop("rows", None)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _settled_worker():
+    """This file's first test starts on a worker that has nothing of an
+    earlier file's still in flight.  The one red run of that test (the
+    driver's take-up run of PR 31's tree) was no assertion: the worker died
+    of a segmentation fault on a thread with no Python frame (faulthandler
+    listed the main thread inside ``resolve`` reading the cache file, and
+    execnet's reader, neither as the current one) — work another file of
+    that worker had dispatched, not a state of the autotuner, which
+    ``_isolated_cache`` already sets up for every test.  So: collect what
+    the earlier files dropped and wait for what they dispatched."""
+    import gc
+    gc.collect()
+    jax.effects_barrier()
+    jax.block_until_ready(jax.live_arrays())
+    yield
+
+
 ROWS_KEY = dict(n=64, f=256, dtype="float32", platform="cpu")
 
 

@@ -85,6 +85,30 @@ def test_resident_backward_matches_reference_and_merged(s, sk, h, d, bq, bk,
            dict(atol=2e-5, rtol=2e-4), "against the merged kernel")
 
 
+@pytest.mark.parametrize("s,sk,h,d,bq,bk,hg", SHAPES)
+def test_resident_backward_reads_packed_operands_in_place(s, sk, h, d, bq,
+                                                          bk, hg):
+    """q, k and v as the column parts of ONE (b, s, 3*h*d) buffer, passed
+    three times and told apart by the block index maps (PR 32): the same
+    kernel on the same bytes, so dq, dk and dv are the three-operand
+    call's bit for bit — at this kernel's own head group (``n = h / hg``
+    column blocks a part), whatever group the forward ran with."""
+    q3, k3, v3, do3 = _operands(2, s, sk, h, d, seed=s + d + hg)
+    qkv3 = jnp.concatenate([q3, k3, v3], axis=-1)
+    scale = 1.0 / d ** 0.5
+    hg_f = fap._pick_fwd_head_group(h, d, s, hg)
+    out, lse = fap._flash_fwd(q3, k3, v3, True, scale, d, True,
+                              ("base", bq, bk, hg_f))
+    spec = ("resident", "base", bq, bk, hg)
+    want = fap._flash_bwd(q3, k3, v3, out, lse, do3, True, scale, d, True,
+                          spec)
+    got = fap._flash_bwd(qkv3, qkv3, qkv3, out, lse, do3, True, scale, d,
+                         True, spec, packed=True)
+    for name, a, w in zip(("dq", "dk", "dv"), got, want):
+        assert a.shape == w.shape == (2, s, h * d), name
+        assert bool(jnp.all(a == w)), name
+
+
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 def test_resident_backward_under_bf16chain(causal):
     ops = _operands(1, 1024, 1024, 2, 64, seed=5)
@@ -366,3 +390,66 @@ def test_resident_share_reader_reads_the_counter_or_nothing():
     assert read(snap(resident=18, merged=4, split=2), None, run) == 75.0
     assert read(snap(merged=24), None, run) == 0.0
     assert read(snap(resident=24), None, {"kind": "serve_open"}) is None
+
+
+# ---------------------------------------------------------------------------
+# flash.fwd_calls{operands} and the benchmark's reader (PR 32)
+# ---------------------------------------------------------------------------
+
+def _fwd_calls():
+    from paddle_tpu.observability import registry as reg
+    ctr = reg.counter("flash.fwd_calls", ("operands",))
+    return {o: ctr.labels(operands=o).value for o in ("packed", "split")}
+
+
+@pytest.mark.parametrize("operands", ["packed", "split"])
+@pytest.mark.parametrize("transform", ["forward", "grad"])
+def test_fwd_calls_counts_one_a_traced_forward(operands, transform):
+    from paddle_tpu.observability import CATALOG
+    assert CATALOG["flash.fwd_calls"]["type"] == "counter"
+    assert CATALOG["flash.fwd_calls"]["labels"] == ("operands",)
+    b, s, h, d = 1, 256, 2, 64
+
+    def attend(x):
+        if operands == "packed":
+            return fap.flash_attention_packed_native(
+                jnp.concatenate([x, x, x], -1), h, causal=True,
+                interpret=True).reshape(b, s, h * d)
+        x4 = x.reshape(b, s, h, d)
+        return fap.flash_attention_bshd_native(
+            x4, x4, x4, causal=True, interpret=True).reshape(b, s, h * d)
+
+    def two_layers(x):
+        return jnp.sum(attend(attend(x)))
+
+    fn = jax.jit({"forward": two_layers,
+                  "grad": jax.grad(two_layers)}[transform])
+    x = jnp.ones((b, s, h * d), jnp.float32)
+    before, bwd_before = _fwd_calls(), _bwd_calls()
+    fn(x)
+    fn(x)                                         # traced once, run twice
+    after = _fwd_calls()
+    assert {o: after[o] - before[o] for o in after} == \
+        {o: 2 * (o == operands) for o in after}
+    # the packed call's backward is counted where the split one's is
+    assert _bwd_calls()["resident"] - bwd_before["resident"] == \
+        (2 if transform == "grad" else 0)
+
+
+def test_packed_share_reader_reads_the_counter_or_nothing():
+    """The benchmark's reader: the share from a registry snapshot, None on
+    a program without the counter (the parent) or outside a training run."""
+    from benchmarks.lib import harness
+    read = harness.layer_reader("flash_packed_pct.train")
+    run = {"kind": "train"}
+    assert read({}, None, run) is None
+    assert read(None, None, run) is None
+
+    def snap(**calls):
+        return {"flash.fwd_calls": {"series": [
+            {"labels": {"operands": o}, "value": float(n)}
+            for o, n in calls.items()]}}
+    assert read(snap(packed=24), None, run) == 100.0
+    assert read(snap(packed=18, split=6), None, run) == 75.0
+    assert read(snap(split=24), None, run) == 0.0
+    assert read(snap(packed=24), None, {"kind": "serve_open"}) is None

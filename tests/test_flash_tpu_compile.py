@@ -120,3 +120,143 @@ def test_resident_backward_compiles_for_v5e(one_chip, no_persistent_cache,
     text = _compiled_text(bwd, x, x, x, x, x, rows,
                           *([rows] if with_dlse else []))
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+# ---------------------------------------------------------------------------
+# one attention layer around the packed kernels (PR 32): what XLA puts
+# between the fused projection's GEMMs and the two custom calls
+# ---------------------------------------------------------------------------
+
+def _balanced(text, start):
+    """Index just past the parenthesis group that opens at ``start``."""
+    depth = 0
+    for i in range(start, len(text)):
+        depth += (text[i] == "(") - (text[i] == ")")
+        if depth == 0:
+            return i + 1
+    raise ValueError(text[start:start + 80])
+
+
+def _entry_instructions(text):
+    """The ENTRY computation of a compiled module's text as ``{name: (type
+    without layouts, opcode, [operand names], attributes)}``."""
+    import re
+    body = text[text.index("\nENTRY "):]
+    out = {}
+    for line in body[:body.index("\n}")].splitlines()[2:]:
+        name, _, rest = line.strip().removeprefix("ROOT ").partition(" = ")
+        end = _balanced(rest, 0) if rest[0] == "(" else rest.index(" ")
+        kind = re.sub(r"\{[^{}]*\}", "", rest[:end])
+        rest = rest[end:].lstrip()
+        open_ = rest.index("(")
+        close = _balanced(rest, open_)
+        out[name.lstrip("%")] = (
+            kind, rest[:open_],
+            re.findall(r"%([\w.\-]+)", rest[open_:close]), rest[close:])
+    return out
+
+
+def _through_async_copies(ins, name):
+    """``name``, or what an asynchronous copy between memory spaces that
+    ends in ``name`` began from (the compiler's own placement: no pass of
+    the program's)."""
+    while ins[name][1] == "copy-done":
+        name = ins[ins[name][2][0]][2][0]
+    return name
+
+
+def _attention_layer(packed, b, s, h, d):
+    """qkv GEMM + bias, causal flash attention (the packed entry, or the
+    three last-dimension slices ``models/gpt.py`` takes), output GEMM."""
+    hd = h * d
+
+    def layer(x, w_qkv, b_qkv, w_out):
+        qkv = jnp.einsum("bsh,hk->bsk", x, w_qkv) + b_qkv
+        if packed:
+            out = fap.flash_attention_packed_native(qkv, h, causal=True,
+                                                    interpret=False)
+        else:
+            out = fap.flash_attention_bshd_native(
+                *(qkv[:, :, i * hd:(i + 1) * hd].reshape(b, s, h, d)
+                  for i in range(3)), causal=True, interpret=False)
+        y = jnp.einsum("bsh,hk->bsk", out.reshape(b, s, hd), w_out)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+    return jax.grad(layer, argnums=(0, 1, 2, 3))
+
+
+# (b, s, h, d, packed?): the benchmark's cell and PR 29's proof shape; the
+# sliced layer at the cell's shape is the control: the pass the packed
+# operands remove is there, and this test sees it
+LAYERS = [
+    pytest.param(16, 1024, 16, 64, True, id="gpt2-medium-s1024"),
+    pytest.param(2, 2048, 16, 128, True, id="cerebras-1.3b-s2048"),
+    pytest.param(16, 1024, 16, 64, False, id="gpt2-medium-s1024-sliced"),
+]
+
+
+@pytest.mark.parametrize("b,s,h,d,packed", LAYERS)
+def test_no_pass_between_the_projection_and_the_packed_kernels(
+        one_chip, no_persistent_cache, b, s, h, d, packed):
+    hd = h * d
+    wide = "bf16[%d,%d,%d]" % (b, s, 3 * hd)
+    part = "bf16[%d,%d,%d]" % (b, s, hd)
+    shapes = [(b, s, hd), (hd, 3 * hd), (3 * hd,), (hd, hd)]
+    ins = _entry_instructions(_compiled_text(
+        _attention_layer(packed, b, s, h, d),
+        *(jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+          for shape in shapes)))
+
+    def is_kernel(name):
+        return ins[name][1] == "custom-call" and \
+            'custom_call_target="tpu_custom_call"' in ins[name][3]
+
+    def kind(name):
+        return "kLoop" if "kind=kLoop" in ins[name][3] else \
+            "kOutput" if "kind=kOutput" in ins[name][3] else None
+
+    def consumers(producer):
+        return [n for n, (_, op, operands, _) in ins.items()
+                if op not in ("copy-start", "copy-done") and producer in
+                {_through_async_copies(ins, o) for o in operands}]
+
+    kernels = [n for n in ins if is_kernel(n)]
+    assert len(kernels) == 2
+    # the projection's output is ONE buffer, written by the GEMM's fusion
+    (gemm,) = [n for n, (t, op, _, _) in ins.items()
+               if t == wide and op != "copy-done"]
+    assert kind(gemm) == "kOutput"
+    # every three-output split, concatenate or update-slice pass would be
+    # a kLoop fusion that reads or writes the wide buffer or writes three
+    # parts at once
+    passes = [n for n, (t, op, operands, _) in ins.items()
+              if kind(n) == "kLoop" and (
+                  t == wide or t.count(part) >= 3 or
+                  wide in {ins[o][0] for o in operands})]
+    if not packed:
+        # today's slices: one pass a layer, three written copies
+        assert len(passes) == 1 and consumers(gemm) == passes
+        assert ins[passes[0]][0] == "(%s)" % ", ".join([part] * 3)
+        return
+    assert passes == []
+    # ... and its only consumers are the two kernels, three operands each
+    assert sorted(consumers(gemm)) == sorted(kernels)
+    for k in kernels:
+        assert [_through_async_copies(ins, o)
+                for o in ins[k][2][:3]] == [gemm] * 3
+    # dq, dk and dv leave the backward as three arrays and enter the bias
+    # reduce and BOTH gradient GEMMs of the projection as operands: the
+    # pads fused away, no cotangent buffer of the wide shape exists
+    (bwd,) = [k for k in kernels if ins[k][0] == "(%s)" % ", ".join(
+        [part] * 3)]
+    grads = {n for n, (_, op, operands, _) in ins.items()
+             if op == "get-tuple-element" and operands == [bwd]}
+    assert len(grads) == 3
+    takers = {}
+    for n in ins:
+        if grads <= {_through_async_copies(ins, o) for o in ins[n][2]}:
+            takers[ins[n][0]] = kind(n)
+    assert takers == {"bf16[%d]" % (3 * hd): "kLoop",           # the bias
+                      "bf16[%d,%d]" % (hd, 3 * hd): "kOutput",  # the weight
+                      part: "kOutput"}                          # the input
+    for g in grads:
+        assert len(consumers(g)) == 3

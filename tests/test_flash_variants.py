@@ -410,6 +410,106 @@ def test_noncausal_kernel_bodies_are_the_parents(monkeypatch, family):
 
 
 # ---------------------------------------------------------------------------
+# packed operands (PR 32): q, k and v read where the fused projection wrote
+# them, through the block index maps of every forward and backward kernel
+# ---------------------------------------------------------------------------
+
+# (s, h, d, block_q, block_k, variant, patched budgets, the forward's
+# family, the backward's path, (hg_f, hg_b))
+PACKED = [
+    pytest.param(1024, 4, 64, 512, 512, None, {}, "resident", "resident",
+                 (4, 2), id="d64-several-blocks-hg_f4-hg_b2"),
+    pytest.param(512, 2, 128, 512, 512, None, {}, "resident", "resident",
+                 (2, 1), id="d128-one-block-hg_f2-hg_b1"),
+    pytest.param(256, 2, 64, 512, 512, None, {}, "resident", "resident",
+                 (2, 2), id="d64-one-block"),
+    # 64 block pairs: too long a walk for the resident backward
+    pytest.param(1024, 2, 64, 128, 128, None, {}, "resident", "merged",
+                 (2, 2), id="d64-falls-to-merged"),
+    pytest.param(512, 2, 64, 128, 128, None,
+                 {"_RESIDENT_BWD_BUDGET": 0, "_RESIDENT_KV_BUDGET": 1,
+                  "_DQ_SCRATCH_BUDGET": 1}, "streamed", "split", (2, 2),
+                 id="d64-streamed-split"),
+    pytest.param(512, 4, 64, 256, 128, "pipelined", {}, "pipelined",
+                 "resident", (4, 2), id="d64-pipelined"),
+    pytest.param(512, 2, 128, 256, 256, "parq", {}, "resident", "resident",
+                 (2, 1), id="d128-parq"),
+]
+
+
+@pytest.mark.parametrize("s,h,d,bq,bk,variant,budgets,family,path,groups",
+                         PACKED)
+def test_packed_operands_equal_the_three_slices_bit_for_bit(
+        monkeypatch, s, h, d, bq, bk, variant, budgets, family, path,
+        groups):
+    """``flash_attention_packed_native`` of a (b, s, 3*h*d) buffer against
+    ``flash_attention_bshd_native`` of its three column slices: the same
+    kernels at the same specs over the same bytes, so the output and the
+    gradient are equal bit for bit, in the forward's families and down the
+    backward's rungs, the two directions at head groups of their own."""
+    for name, value in budgets.items():
+        monkeypatch.setattr(fap, name, value)
+    b, hd = 2, h * d
+    fwd_spec, bwd_spec = fap._native_specs(b, s, s, h, d, jnp.float32, True,
+                                           bq, bk, variant)
+    assert bwd_spec[0] == path
+    assert (fwd_spec[3], bwd_spec[3 if path == "split" else 4]) == groups
+    assert family == ("pipelined" if variant == "pipelined" else
+                      "resident" if fap._kv_fits_resident(s, groups[0] * d)
+                      else "streamed")
+    rng = np.random.RandomState(s + d)
+    qkv = jnp.asarray(rng.randn(b, s, 3 * hd), jnp.float32) * 0.5
+    ct = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
+    kw = dict(causal=True, block_q=bq, block_k=bk, interpret=True,
+              variant=variant)
+
+    def packed(x):
+        return fap.flash_attention_packed_native(x, h, **kw)
+
+    def sliced(x):
+        return fap.flash_attention_bshd_native(
+            *(x[:, :, i * hd:(i + 1) * hd].reshape(b, s, h, d)
+              for i in range(3)), **kw)
+
+    got, got_vjp = jax.vjp(packed, qkv)
+    want, want_vjp = jax.vjp(sliced, qkv)
+    assert bool(jnp.all(got == want))
+    (dgot,), (dwant,) = got_vjp(ct), want_vjp(ct)
+    assert dgot.shape == qkv.shape
+    for i, name in enumerate(("dq", "dk", "dv")):
+        part = slice(i * hd, (i + 1) * hd)
+        assert float(jnp.max(jnp.abs(dwant[:, :, part]))) > 0, name
+        assert bool(jnp.all(dgot[:, :, part] == dwant[:, :, part])), name
+    # and the packed call is the reference's attention, not only the twin
+    q, k, v = (qkv[:, :, i * hd:(i + 1) * hd].reshape(b, s, h, d)
+               for i in range(3))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_ref_bshd(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_packed_entry_refuses_a_width_that_is_not_three_parts():
+    with pytest.raises(ValueError, match="3 x 2 heads"):
+        fap.flash_attention_packed_native(
+            jnp.zeros((1, 128, 200), jnp.float32), 2, interpret=True)
+
+
+def test_packed_cotangent_is_a_sum_of_pads_not_a_concatenate():
+    """What XLA is handed for the fused buffer's cotangent: three pads
+    and two adds (the transpose of three slices, which it fuses into the
+    projection's gradient GEMMs); a concatenate becomes three
+    dynamic-update-slice passes over a (b, s, 3*h*d) buffer
+    (tests/test_flash_tpu_compile.py holds the compiled program to it)."""
+    qkv = jnp.zeros((1, 256, 3 * 128), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda x, ct: jax.vjp(
+        lambda y: fap.flash_attention_packed_native(
+            y, 2, causal=True, interpret=True), x)[1](ct))(
+                qkv, jnp.zeros((1, 256, 2, 64), jnp.float32)).jaxpr
+    names = [e.primitive.name for e in jaxpr.eqns]
+    assert names.count("pad") == 3
+    assert "concatenate" not in names and "dynamic_update_slice" not in names
+
+
+# ---------------------------------------------------------------------------
 # flash.score_elements: how far the band walk engages
 # ---------------------------------------------------------------------------
 

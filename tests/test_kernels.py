@@ -233,3 +233,94 @@ def test_flash_with_lse_matches_reference_and_grads():
     for a, b_ in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-3, atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# GPTAttention hands the fused projection to the packed kernels exactly where
+# the rule says (PR 32): no cache, attention dropout inactive, the shape
+# supported, a part whole lane-aligned blocks, no live head axis.  The
+# counter flash.fwd_calls{operands} says which way each traced call went.
+# ---------------------------------------------------------------------------
+
+def _fwd_calls():
+    from paddle_tpu.observability import registry as reg
+    ctr = reg.counter("flash.fwd_calls", ("operands",))
+    return {o: ctr.labels(operands=o).value for o in ("packed", "split")}
+
+
+# (config overrides, sequence, training, mesh axes, a cache?, flash forward
+# calls traced as (packed, split): (0, 0) is the XLA reference path)
+ATTENTION = [
+    pytest.param({}, 128, True, None, False, (1, 0), id="training"),
+    pytest.param({"attention_dropout_prob": 0.1}, 128, False, None, False,
+                 (1, 0), id="eval-of-a-model-with-dropout"),
+    pytest.param({"attention_dropout_prob": 0.1}, 128, True, None, False,
+                 (0, 0), id="dropout-live"),
+    pytest.param({}, 128, True, None, True, (0, 1), id="a-cache-is-passed"),
+    pytest.param({}, 128, True, {"mp": 2}, False, (0, 1), id="mp-mesh"),
+    pytest.param({}, 128, True, {"dp": 2}, False, (1, 0), id="dp-mesh"),
+    pytest.param({}, 96, True, None, False, (0, 0),
+                 id="unsupported-sequence-length"),
+    pytest.param({"hidden_size": 64, "num_attention_heads": 1}, 128, True,
+                 None, False, (0, 1), id="a-part-of-half-a-lane-block"),
+]
+
+
+@pytest.mark.parametrize("overrides,s,training,axes,cached,calls", ATTENTION)
+def test_gpt_attention_takes_the_packed_path_where_the_rule_says(
+        monkeypatch, overrides, s, training, axes, cached, calls):
+    import contextlib
+    import dataclasses
+
+    import paddle_tpu as paddle
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.jit import functional_call
+    from paddle_tpu.kernels import flash_attention as fa
+    from paddle_tpu.models.gpt import GPTAttention, GPTConfig
+    from paddle_tpu.nn import functional as F
+
+    if axes and len(jax.devices()) < 2:
+        pytest.skip("needs two devices (conftest gives the CPU eight)")
+    config = dataclasses.replace(
+        GPTConfig.tiny(), **{"hidden_size": 128, "num_attention_heads": 2,
+                             "max_position_embeddings": 256, **overrides})
+    paddle.seed(7)
+    attn = GPTAttention(config)
+    attn.train() if training else attn.eval()
+    b, hidden = 2, config.hidden_size
+    x = jnp.asarray(np.random.RandomState(1).randn(b, s, hidden),
+                    jnp.float32)
+    empty = jnp.zeros((b, 0, config.num_attention_heads,
+                       hidden // config.num_attention_heads), jnp.float32)
+
+    def loss(state, x_):
+        args = (paddle.Tensor(x_),) + (
+            ((paddle.Tensor(empty), paddle.Tensor(empty)),) if cached
+            else ())
+        out, _ = functional_call(attn, state, *args, rng=jax.random.key(0))
+        return jnp.sum(jnp.sin((out[0] if cached else out)))
+
+    def run():
+        mesh = contextlib.nullcontext() if axes is None else \
+            mesh_mod.mesh_scope(jax.sharding.Mesh(
+                np.asarray(jax.devices()[:2]), tuple(axes)))
+        with fa.interpret_scope(), mesh:
+            before = _fwd_calls()
+            # a fresh wrapper: dispatch reads the scopes at trace time
+            got = jax.jit(jax.value_and_grad(lambda *a: loss(*a),
+                                             argnums=(0, 1)))(
+                attn.functional_state(), x)
+            after = _fwd_calls()
+        return got, tuple(after[o] - before[o] for o in ("packed", "split"))
+
+    got, counted = run()
+    assert counted == calls
+    if calls[0]:
+        # and where it is taken it is the sliced layer's result, bit for bit
+        monkeypatch.setattr(F, "packed_attention_supported",
+                            lambda *a, **k: False)
+        want, recounted = run()
+        assert recounted == (0, 1)
+        for a, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert bool(jnp.all(a == w))
