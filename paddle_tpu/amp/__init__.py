@@ -86,7 +86,9 @@ def decorate(models, optimizers=None, level="O2", dtype="bfloat16",
              master_weight=None, save_dtype=None):
     """reference parity: paddle.amp.decorate (auto_cast.py:81) — O2 casts
     parameters to the amp dtype (master fp32 weights are kept by optimizers
-    whose slots are fp32, which ours are)."""
+    whose slots are fp32, which ours are).  Norm layers stay fp32, and so
+    does any parameter marked ``keep_fp32`` (``RMSNorm`` marks its gain; a
+    model marks a decay rate, a step-size bias, a router)."""
     from ..nn.layer.norm import _BatchNormBase, LayerNorm
 
     single = not isinstance(models, (list, tuple))
@@ -97,7 +99,8 @@ def decorate(models, optimizers=None, level="O2", dtype="bfloat16",
                 if isinstance(layer, (_BatchNormBase, LayerNorm)):
                     continue  # keep norms fp32 (reference keep_batch_norm_fp32)
                 for p in layer._parameters.values():
-                    if p is not None and p.dtype == np.dtype("float32"):
+                    if (p is not None and not p.keep_fp32
+                            and p.dtype == np.dtype("float32")):
                         p._array = p._array.astype(dtype)
     if optimizers is None:
         return models if single else model_list

@@ -308,7 +308,8 @@ class Tensor:
 class Parameter(Tensor):
     """A trainable tensor (reference: python/paddle/fluid/framework.py Parameter)."""
 
-    __slots__ = ("trainable", "optimize_attr", "regularizer", "is_distributed", "pspec")
+    __slots__ = ("trainable", "optimize_attr", "regularizer", "is_distributed", "pspec",
+                 "keep_fp32")
 
     _param_counter = [0]
 
@@ -322,6 +323,9 @@ class Parameter(Tensor):
         self.regularizer = None
         self.is_distributed = False
         self.pspec = None  # optional jax PartitionSpec annotation
+        # amp.decorate(level="O2") leaves a marked parameter in float32 (a
+        # decay rate, a router: what a bf16 rounding changes in kind)
+        self.keep_fp32 = False
         self.persistable = True
 
     @property

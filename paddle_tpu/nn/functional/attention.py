@@ -116,6 +116,17 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                     jnp.swapaxes(v, 1, 2), "sep", causal=is_causal,
                     scale=scale, attn_mask=mask)  # ring is (B, H, S, D)
                 return jnp.swapaxes(out, 1, 2)
+        if q.ndim == 4 and k.shape[2] != q.shape[2]:
+            # a key/value group: query head h reads key/value head
+            # h // (heads / kv heads).  Expanded in front of the kernels,
+            # which is exact (the group's gradient is the sum over its
+            # query heads, as jnp.repeat's transpose gives it)
+            if q.shape[2] % k.shape[2]:
+                raise ValueError(
+                    "grouped-query attention needs q heads (%d) divisible "
+                    "by k/v heads (%d)" % (q.shape[2], k.shape[2]))
+            group = q.shape[2] // k.shape[2]
+            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
         if use_flash and m is None and dropout_p == 0.0:
             from ...kernels import flash_attention as fa
             if fa.supported(q, k):

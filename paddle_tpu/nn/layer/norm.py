@@ -48,6 +48,7 @@ class RMSNorm(Layer):
         self.epsilon = epsilon
         self.weight = self.create_parameter(
             (hidden_size,), default_initializer=I.Constant(1.0))
+        self.weight.keep_fp32 = True   # as LayerNorm's under amp O2
 
     def forward(self, x):
         return F.rms_norm(x, self.weight, self.epsilon)

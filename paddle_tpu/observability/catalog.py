@@ -253,6 +253,30 @@ CATALOG = {
         "front of the kernel) or 'split' (three arrays).  Trace-time, one "
         "inc a forward call: a compile-once program contributes once",
         labels=("operands",)),
+    "ssm.scan_calls": _m(
+        "counter", "state-space scans traced so far by implementation: "
+        "path='chunked_jnp' (chunked contractions differentiated by JAX, "
+        "kept as a checkpoint of their operands).  Trace-time, one inc a "
+        "traced scan: a compile-once program contributes once a trace of "
+        "the layer (a recomputed block is traced again)",
+        labels=("path",)),
+    "moe.calls": _m(
+        "counter", "routed expert layers traced so far by the grouped "
+        "product they launch: path='megablox' (the Pallas kernels, on a "
+        "TPU) or 'ragged_dot' (elsewhere).  Trace-time, as ssm.scan_calls",
+        labels=("path",)),
+    "moe.rows": _m(
+        "counter", "rows of the expert layers traced so far: "
+        "which='routed' tokens x experts a token, 'expected_held' the "
+        "share of them that uniform routing sends to the experts this "
+        "chip holds, 'launched' the rows of the sorted buffer the grouped "
+        "products are launched over in a step whose routing fits them "
+        "(three times expected_held in whole tiles; the products visit the "
+        "tiles the step's assignments cover and no others; a step that "
+        "does not fit launches the dropless worst case, tokens x min(k, "
+        "held)); "
+        "(launched - expected_held) / launched is the padded share.  "
+        "Trace-time", labels=("which",)),
 
     # -- compile watchdog ---------------------------------------------------
     "compile.count": _m(

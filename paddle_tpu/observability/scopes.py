@@ -30,7 +30,8 @@ from typing import Dict, Optional, Tuple
 
 __all__ = ["EMBED", "ATTN", "MLP", "NORM", "LM_HEAD", "LOSS", "OPTIMIZER",
            "DECODE_ATTN", "KV_WRITE", "SAMPLE", "PREFILL_ATTN",
-           "TRAIN", "SERVE", "VOCABULARY", "UNSCOPED",
+           "SSM", "SSM_SCAN", "MOE", "MOE_EXPERTS",
+           "TRAIN", "SERVE", "HYBRID", "VOCABULARY", "UNSCOPED",
            "scope", "scope_of", "instruction_scopes", "index"]
 
 EMBED = "embed"
@@ -44,12 +45,21 @@ DECODE_ATTN = "decode_attn"
 KV_WRITE = "kv_write"
 SAMPLE = "sample"
 PREFILL_ATTN = "prefill_attn"
+SSM = "ssm"
+SSM_SCAN = "ssm_scan"
+MOE = "moe"
+MOE_EXPERTS = "moe_experts"
 
 #: roles of a training step
 TRAIN = (EMBED, ATTN, MLP, NORM, LM_HEAD, LOSS, OPTIMIZER)
 #: roles only a serving program has (it has the model's too)
 SERVE = (DECODE_ATTN, KV_WRITE, SAMPLE, PREFILL_ATTN)
-VOCABULARY = TRAIN + SERVE
+#: roles of the blocks a hybrid model has beside attention: a state-space
+#: mixer (its projections, convolution and gated norm; the scan alone) and
+#: a routed expert layer (router, sort, gather, scatter and shared expert;
+#: the grouped products alone).  The inner role of each pair wins
+HYBRID = (SSM, SSM_SCAN, MOE, MOE_EXPERTS)
+VOCABULARY = TRAIN + SERVE + HYBRID
 _ROLES = frozenset(VOCABULARY)
 
 #: where readers file device time whose instruction carries no role

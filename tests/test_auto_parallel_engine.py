@@ -4,6 +4,16 @@ import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
+from paddle_tpu.distributed import mesh as _mesh
+
+
+@pytest.fixture(autouse=True)
+def _mesh_as_found():
+    """``Engine.prepare`` and ``init_mesh`` install a global mesh, as their
+    users ask of them; a test puts back what it found."""
+    before = _mesh.get_mesh()
+    yield
+    _mesh.set_mesh(before)
 
 
 def test_engine_fit_evaluate_predict():
@@ -52,7 +62,6 @@ def test_engine_params_sharded_on_mesh():
 def test_shard_op_constrains():
     import jax
     from paddle_tpu.distributed.auto_parallel import shard_op
-    from paddle_tpu.distributed import mesh as _mesh
     _mesh.init_mesh({"dp": 8})
 
     def matmul(a, b):

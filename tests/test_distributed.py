@@ -17,6 +17,15 @@ pytestmark = pytest.mark.skipif(jax.device_count() < 8,
                                 reason="needs 8 virtual devices")
 
 
+@pytest.fixture(autouse=True)
+def _mesh_as_found():
+    """``init_mesh`` and ``fleet.init`` install a global mesh, as their
+    users ask of them; a test puts back what it found."""
+    before = mesh_mod.get_mesh()
+    yield
+    mesh_mod.set_mesh(before)
+
+
 @pytest.fixture
 def mesh8():
     return mesh_mod.init_mesh({"dp": 8})

@@ -260,3 +260,30 @@ def test_no_pass_between_the_projection_and_the_packed_kernels(
                       part: "kOutput"}                          # the input
     for g in grads:
         assert len(consumers(g)) == 3
+
+
+@pytest.mark.usefixtures("no_persistent_cache")
+@pytest.mark.parametrize("rows", [9216, 49152])
+def test_grouped_products_compile_at_the_expert_cells_shapes(one_chip, rows):
+    """The megablox kernels behind ``kernels.grouped_matmul`` at the widths
+    of ``train_nemo3nano_s8192`` (8 experts, 2,688 -> 1,856 -> 2,688; the
+    usual launch and the worst case), forward and both gradients: Mosaic
+    takes the 896- and 640-wide tiles, the irregular last tile of 1,856 and
+    the masks around the calls."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+
+    def two(xs, w_up, w_down, sizes):
+        # ``grouped_matmul`` asks the backend, which is a CPU here: the
+        # kernels' builder is called directly, as the guide says to
+        up = gm._gmm(xs, w_up, sizes, jnp.bfloat16, 512, False)
+        return jnp.sum(gm._gmm(jnp.square(jax.nn.relu(up)), w_down, sizes,
+                               jnp.float32, 512, False))
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = _compiled_text(
+        jax.grad(two, argnums=(0, 1, 2)),
+        spec((rows, 2688), jnp.bfloat16), spec((8, 2688, 1856), jnp.bfloat16),
+        spec((8, 1856, 2688), jnp.bfloat16), spec((8,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') >= 5
+    assert gm._tile(2688) == 896 and gm._tile(1856) == 640

@@ -413,6 +413,14 @@ def test_catalog_coverage_is_two_way(monkeypatch, tmp_path):
     jax.grad(lambda x: jnp.sum(flash_attention_bshd_native(
         x, x, x, causal=True, interpret=True)))(qkv)
 
+    # -- hybrid blocks: one traced Mamba-2 scan and one traced expert
+    # layer count themselves (ssm.scan_calls, moe.calls, moe.rows) ---------
+    from paddle_tpu.models.nemotron_h import (NemotronHConfig,
+                                              NemotronHForCausalLM)
+    hybrid = NemotronHForCausalLM(NemotronHConfig.tiny(
+        hybrid_override_pattern="ME"))
+    hybrid(paddle.Tensor(jnp.zeros((1, 16), jnp.int32)))
+
     # -- HBM ledger: one armed sample prices live arrays + KV pools --------
     hbm.enable()
     try:
