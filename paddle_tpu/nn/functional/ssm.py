@@ -6,10 +6,14 @@
 * :func:`ssd_scan_raw` — ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``,
   ``y_t = C_t . S_t + D x_t``, computed a chunk at a time: inside a chunk as
   masked matrix products (the "dual" quadratic form), between chunks as a
-  recurrence on the chunk states.  Plain ``jnp`` contractions, differentiated
-  by JAX; what is kept for the backward is its operands only (the function
-  is a ``jax.checkpoint``: the (chunk, chunk) decay matrices are made again,
-  as the published kernels make them again);
+  recurrence on the chunk states.  On a TPU, for a chunk, a state and a
+  group's heads in whole 128-lane tiles, that is ``kernels/ssd_scan.py``: a
+  Pallas kernel each way under a ``custom_vjp`` (the (chunk, chunk) decays
+  stay in VMEM, the state is carried down the grid).  Everywhere else (a
+  CPU, the tiny test configuration) plain ``jnp`` contractions,
+  differentiated by JAX, a ``jax.checkpoint`` of their operands.  Both make
+  the decay matrices again in the backward, as the published kernels do,
+  and ``ssm.scan_calls{path}`` says which was traced;
 * :func:`ssd_recurrence_raw` — the same equations a token at a time, for
   tests;
 * :func:`gated_group_rms_norm_raw` — ``GroupRMSNorm(y * silu(z)) * w``.
@@ -25,6 +29,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from ...kernels import flash_attention as _fa
+from ...kernels import ssd_scan as _kernel
 
 F32 = jnp.float32
 
@@ -118,12 +125,18 @@ def ssd_scan_raw(x, dt, a, b, c, d, chunk):
     if pad:
         x, dt, b, c = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) *
                                (t.ndim - 2)) for t in (x, dt, b, c))
-    note_scan_call("chunked_jnp")
-    y = _ssd_chunks(x.reshape(bsz, s + pad, g, h // g, p),
-                    dt.astype(F32).reshape(bsz, s + pad, g, h // g),
-                    a.astype(F32).reshape(g, h // g), b, c,
-                    d.astype(F32).reshape(g, h // g), chunk)
-    return y.reshape(bsz, s + pad, h, p)[:, :s]
+    dt, a, d = dt.astype(F32), a.astype(F32), d.astype(F32)
+    interpret = bool(_fa._INTERPRET)
+    if _kernel.supported(chunk, h // g, p, b.shape[3], interpret):
+        note_scan_call("pallas")
+        y = _kernel.ssd_scan(x, dt, a, b, c, d, chunk, interpret)
+    else:
+        note_scan_call("chunked_jnp")
+        y = _ssd_chunks(x.reshape(bsz, s + pad, g, h // g, p),
+                        dt.reshape(bsz, s + pad, g, h // g),
+                        a.reshape(g, h // g), b, c, d.reshape(g, h // g),
+                        chunk).reshape(bsz, s + pad, h, p)
+    return y[:, :s]
 
 
 def ssd_recurrence_raw(x, dt, a, b, c, d):

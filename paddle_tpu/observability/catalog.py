@@ -255,10 +255,13 @@ CATALOG = {
         labels=("operands",)),
     "ssm.scan_calls": _m(
         "counter", "state-space scans traced so far by implementation: "
-        "path='chunked_jnp' (chunked contractions differentiated by JAX, "
-        "kept as a checkpoint of their operands).  Trace-time, one inc a "
-        "traced scan: a compile-once program contributes once a trace of "
-        "the layer (a recomputed block is traced again)",
+        "path='pallas' (kernels/ssd_scan.py: a forward and a backward "
+        "kernel, on a TPU for chunk, state and a group's heads in whole "
+        "lane tiles) or 'chunked_jnp' (chunked contractions differentiated "
+        "by JAX, kept as a checkpoint of their operands: everywhere "
+        "else).  Trace-time, one inc a traced scan: a compile-once "
+        "program contributes once a trace of the layer (a recomputed "
+        "block is traced again)",
         labels=("path",)),
     "moe.calls": _m(
         "counter", "routed expert layers traced so far by the grouped "

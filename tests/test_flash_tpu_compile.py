@@ -5,6 +5,8 @@ offset into a dynamically indexed row, more VMEM than a kernel may use):
 PR 26's sub-tiled band met both only here.  One file, topology inside a
 fixture (only one process may hold the TPU library; see the
 on-chip-measurement guide)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -287,3 +289,126 @@ def test_grouped_products_compile_at_the_expert_cells_shapes(one_chip, rows):
         spec((8, 1856, 2688), jnp.bfloat16), spec((8,), jnp.int32))
     assert text.count('custom_call_target="tpu_custom_call"') >= 5
     assert gm._tile(2688) == 896 and gm._tile(1856) == 640
+
+
+# ---------------------------------------------------------------------------
+# the Mamba-2 scan's kernels at the hybrid cell's shapes: 1 x 8,192 tokens,
+# 64 heads of 64, 8 groups of state 128, chunk 128, bf16
+
+SCAN = dict(b=1, s=8192, h=64, p=64, g=8, n=128, chunk=128, hidden=2688)
+
+
+def _scan_specs(one_chip):
+    c = SCAN
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    return (spec((c["b"], c["s"], c["h"], c["p"]), jnp.bfloat16),
+            spec((c["b"], c["s"], c["h"]), jnp.float32),
+            spec((c["h"],), jnp.float32),
+            spec((c["b"], c["s"], c["g"], c["n"]), jnp.bfloat16),
+            spec((c["b"], c["s"], c["g"], c["n"]), jnp.bfloat16),
+            spec((c["h"],), jnp.float32))
+
+
+@pytest.mark.usefixtures("no_persistent_cache")
+@pytest.mark.parametrize("way", ["forward", "backward"])
+def test_scan_kernels_compile_at_the_hybrid_cells_shapes(one_chip, way):
+    """``ssd_scan_raw`` asks the backend, which is a CPU here: the
+    ``custom_vjp`` under it is called directly.  Mosaic takes the (R, L)
+    blocks it turns over, the lane masks of two heads a tile, the
+    one-row stores and the transposed products."""
+    from paddle_tpu.kernels import ssd_scan as ks
+    assert ks.supported(SCAN["chunk"], SCAN["h"] // SCAN["g"], SCAN["p"],
+                        SCAN["n"], interpret=True)
+
+    def scan(*args):
+        return ks.ssd_scan(*args, SCAN["chunk"], False)
+
+    fn = scan if way == "forward" else jax.grad(
+        lambda *a: jnp.sum(scan(*a).astype(jnp.float32)), argnums=range(6))
+    text = _compiled_text(fn, *_scan_specs(one_chip))
+    kernels = re.findall(r"%(ssd_scan_\w+?)[.\d]* = ", text)
+    assert sorted(set(kernels)) == (
+        ["ssd_scan_fwd"] if way == "forward" else
+        ["ssd_scan_bwd", "ssd_scan_fwd"])
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        len(kernels) == (1 if way == "forward" else 2)
+
+
+def _mixer_layer():
+    """``Mamba2Mixer`` as ``models/nemotron_h.py`` writes it (projection,
+    convolution, scan, gated norm, projection) under its two roles, a
+    recomputed block as the cell runs it, loss and gradients."""
+    from paddle_tpu.kernels import ssd_scan as ks
+    from paddle_tpu.nn.functional import ssm as fs
+    from paddle_tpu.observability import scopes
+    c = SCAN
+    inner = c["h"] * c["p"]
+    conv = inner + 2 * c["g"] * c["n"]
+
+    def mixer(u, w_in, conv_w, conv_b, a_log, dt_bias, d, norm_w, w_out):
+        with scopes.scope(scopes.SSM):
+            proj = jnp.einsum("bsh,hk->bsk", u, w_in)
+            z, xbc, dt = (proj[..., :inner], proj[..., inner:inner + conv],
+                          proj[..., inner + conv:])
+            xbc = fs.causal_conv1d_raw(xbc, conv_w, conv_b, silu=True)
+            x = xbc[..., :inner].reshape(c["b"], c["s"], c["h"], c["p"])
+            bm, cm = (xbc[..., inner + i * c["g"] * c["n"]:
+                          inner + (i + 1) * c["g"] * c["n"]].reshape(
+                              c["b"], c["s"], c["g"], c["n"])
+                      for i in range(2))
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+            with scopes.scope(scopes.SSM_SCAN):
+                y = ks.ssd_scan(x, dt, -jnp.exp(a_log), bm, cm, d,
+                                c["chunk"], False)
+            y = fs.gated_group_rms_norm_raw(
+                y.reshape(c["b"], c["s"], inner), z, norm_w, c["g"], 1e-5)
+            out = jnp.einsum("bsk,kh->bsh", y, w_out)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    shapes = [((c["b"], c["s"], c["hidden"]), jnp.bfloat16),
+              ((c["hidden"], inner + conv + c["h"]), jnp.bfloat16),
+              ((4, conv), jnp.bfloat16), ((conv,), jnp.bfloat16),
+              ((c["h"],), jnp.float32), ((c["h"],), jnp.float32),
+              ((c["h"],), jnp.float32), ((inner,), jnp.float32),
+              ((inner, c["hidden"]), jnp.bfloat16)]
+    return jax.value_and_grad(jax.checkpoint(mixer),
+                              argnums=tuple(range(9))), shapes
+
+
+@pytest.mark.usefixtures("no_persistent_cache")
+def test_the_mixer_layer_keeps_its_scan_in_the_kernels(one_chip):
+    """Forward, the block's recomputation and the backward: three Mosaic
+    calls under ``ssm_scan``; the first writes y alone, the second the
+    entering states beside it (134 MB of float32); under that role no
+    matrix product is XLA's and no float32 buffer has the 268 MB of a
+    layer's (chunk, chunk) matrices."""
+    fn, shapes = _mixer_layer()
+    text = _compiled_text(fn, *(jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                                for s, d in shapes))
+    under = [line for line in text.splitlines()
+             if re.search(r'op_name="[^"]*\bssm_scan\b', line)]
+    calls = [line for line in under
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = sorted(re.match(r"\s*%([a-z_]+)", line).group(1)
+                   for line in calls)
+    assert names == ["ssd_scan_bwd", "ssd_scan_fwd", "ssd_scan_fwd"]
+    assert len(calls) == text.count('custom_call_target="tpu_custom_call"')
+    c = SCAN
+    states = "f32[%d,%d,%d,%d,%d]" % (
+        c["b"], c["g"], c["s"] // c["chunk"], c["n"],
+        c["h"] // c["g"] * c["p"])
+    head = lambda line: line.split(" custom-call(")[0]
+    assert sorted(states in head(line) for line in calls
+                  if "%ssd_scan_fwd" in line) == [False, True]
+    assert not [line for line in under
+                if re.search(r" (dot|convolution)\(", line)]
+    matrices = 4 * c["s"] // c["chunk"] * c["h"] * c["chunk"] ** 2
+    for line in under:
+        for dims in re.findall(r"f32\[([\d,]+)\]", head(line)
+                               if line in calls else line.split(" = ")[1]
+                               .split("(")[0]):
+            size = 4
+            for dim in dims.split(","):
+                size *= int(dim)
+            assert size < matrices, line[:200]
