@@ -21,4 +21,5 @@ from .loss import (binary_cross_entropy, binary_cross_entropy_with_logits,
                    smooth_l1_loss, softmax_with_cross_entropy,
                    square_error_cost, triplet_margin_loss)
 from .attention import (packed_attention, packed_attention_supported,
-                        scaled_dot_product_attention, sdpa_reference_raw)
+                        rotary_embedding, scaled_dot_product_attention,
+                        sdpa_reference_raw)

@@ -6,6 +6,8 @@ implementation elsewhere — the TPU-native answer to the reference's fused
 FMHA (paddle/fluid/operators/fused/fmha_ref.h, fused_attention_op).
 ``packed_attention`` is the same kernel over a fused q/k/v projection's
 output, read in place; ``packed_attention_supported`` says where it applies.
+``rotary_embedding`` turns the leading lanes of each head by the token's
+position (partial rotary: the lanes past ``rotary_dim`` pass untouched).
 """
 from __future__ import annotations
 
@@ -46,6 +48,36 @@ def sdpa_reference_raw(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
     if bthd:
         out = jnp.swapaxes(out, 1, 2)
     return out
+
+
+def rotary_embedding_raw(x, rotary_dim=None, theta=10000.0):
+    """x (b, s, heads, d) -> the same with lanes 0..rotary_dim-1 of every
+    head turned by the token's position (its index in the row) and the
+    others untouched.  Half-split pairing (GPT-NeoX's, the
+    published ``rotate_half``): lane i < rotary_dim / 2 pairs with lane i +
+    rotary_dim / 2, at the angle ``position * theta^(-2 i / rotary_dim)``.
+    Angles and the turn in float32, x's type out."""
+    d = x.shape[-1]
+    rotary_dim = d if rotary_dim is None else rotary_dim
+    if rotary_dim % 2 or not 0 < rotary_dim <= d:
+        raise ValueError("rotary_dim %r is no even number of a head's %d "
+                         "lanes" % (rotary_dim, d))
+    half = rotary_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0
+                         / rotary_dim)
+    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:rotary_dim].astype(jnp.float32)
+    return jnp.concatenate(
+        [(x1 * cos - x2 * sin).astype(x.dtype),
+         (x2 * cos + x1 * sin).astype(x.dtype), x[..., rotary_dim:]], axis=-1)
+
+
+def rotary_embedding(x, rotary_dim=None, theta=10000.0):
+    """:func:`rotary_embedding_raw` as a Tensor op."""
+    return call(lambda a: rotary_embedding_raw(a, rotary_dim, theta), x,
+                name="rotary_embedding")
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,

@@ -36,18 +36,22 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5):
     return layer_norm_raw(x, weight, bias, normalized_shape, epsilon)
 
 
-def rms_norm_raw(x, weight, epsilon=1e-6):
+def rms_norm_raw(x, weight, epsilon=1e-6, zero_centered=False):
+    """``x / sqrt(mean x^2 + epsilon) * weight`` in float32; a zero-centred
+    gain multiplies by ``1 + weight`` (the gain is stored as its distance
+    from one, so weight decay pulls it to one)."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     out = xf * jax.lax.rsqrt(var + epsilon)
     if weight is not None:
-        out = out * weight.astype(jnp.float32)
+        gain = weight.astype(jnp.float32)
+        out = out * (1.0 + gain if zero_centered else gain)
     return out.astype(x.dtype)
 
 
 @wrap_op
-def rms_norm(x, weight=None, epsilon=1e-6):
-    return rms_norm_raw(x, weight, epsilon)
+def rms_norm(x, weight=None, epsilon=1e-6, zero_centered=False):
+    return rms_norm_raw(x, weight, epsilon, zero_centered)
 
 
 def batch_norm(x, running_mean, running_var, weight=None, bias=None,

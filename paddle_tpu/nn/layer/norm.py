@@ -43,14 +43,20 @@ class LayerNorm(Layer):
 class RMSNorm(Layer):
     _scope = _scopes.NORM
 
-    def __init__(self, hidden_size, epsilon=1e-6):
+    def __init__(self, hidden_size, epsilon=1e-6, zero_centered=False):
         super().__init__()
         self.epsilon = epsilon
+        # a zero-centred gain is stored as its distance from one and
+        # applied as ``1 + weight`` (Qwen3-Next's norms)
+        self.zero_centered = zero_centered
         self.weight = self.create_parameter(
-            (hidden_size,), default_initializer=I.Constant(1.0))
+            (hidden_size,), default_initializer=I.Constant(
+                0.0 if zero_centered else 1.0))
         self.weight.keep_fp32 = True   # as LayerNorm's under amp O2
 
     def forward(self, x):
+        if self.zero_centered:
+            return F.rms_norm(x, self.weight, self.epsilon, True)
         return F.rms_norm(x, self.weight, self.epsilon)
 
 

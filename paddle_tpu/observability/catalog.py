@@ -263,6 +263,14 @@ CATALOG = {
         "program contributes once a trace of the layer (a recomputed "
         "block is traced again)",
         labels=("path",)),
+    "linear_attn.scan_calls": _m(
+        "counter", "gated delta rules (the recurrent layer of a Gated "
+        "DeltaNet mixer) traced so far by implementation: "
+        "path='chunked_jnp' (nn/functional/linear_attn.py: the triangular "
+        "inverse inside a chunk, the state carried by a lax.scan; "
+        "differentiated by JAX, kept as a checkpoint of its operands).  "
+        "Trace-time, as ssm.scan_calls",
+        labels=("path",)),
     "moe.calls": _m(
         "counter", "routed expert layers traced so far by the grouped "
         "product they launch: path='megablox' (the Pallas kernels, on a "
@@ -276,8 +284,8 @@ CATALOG = {
         "products are launched over in a step whose routing fits them "
         "(three times expected_held in whole tiles; the products visit the "
         "tiles the step's assignments cover and no others; a step that "
-        "does not fit launches the dropless worst case, tokens x min(k, "
-        "held)); "
+        "does not fit takes the dropless worst case, tokens x min(k, "
+        "held), in one launch or window by window); "
         "(launched - expected_held) / launched is the padded share.  "
         "Trace-time", labels=("which",)),
 

@@ -31,7 +31,8 @@ from typing import Dict, Optional, Tuple
 __all__ = ["EMBED", "ATTN", "MLP", "NORM", "LM_HEAD", "LOSS", "OPTIMIZER",
            "DECODE_ATTN", "KV_WRITE", "SAMPLE", "PREFILL_ATTN",
            "SSM", "SSM_SCAN", "MOE", "MOE_EXPERTS",
-           "TRAIN", "SERVE", "HYBRID", "VOCABULARY", "UNSCOPED",
+           "LINEAR_ATTN", "LINEAR_ATTN_SCAN",
+           "TRAIN", "SERVE", "HYBRID", "LINEAR", "VOCABULARY", "UNSCOPED",
            "scope", "scope_of", "instruction_scopes", "index"]
 
 EMBED = "embed"
@@ -49,6 +50,8 @@ SSM = "ssm"
 SSM_SCAN = "ssm_scan"
 MOE = "moe"
 MOE_EXPERTS = "moe_experts"
+LINEAR_ATTN = "linear_attn"
+LINEAR_ATTN_SCAN = "linear_attn_scan"
 
 #: roles of a training step
 TRAIN = (EMBED, ATTN, MLP, NORM, LM_HEAD, LOSS, OPTIMIZER)
@@ -59,7 +62,11 @@ SERVE = (DECODE_ATTN, KV_WRITE, SAMPLE, PREFILL_ATTN)
 #: a routed expert layer (router, sort, gather, scatter and shared expert;
 #: the grouped products alone).  The inner role of each pair wins
 HYBRID = (SSM, SSM_SCAN, MOE, MOE_EXPERTS)
-VOCABULARY = TRAIN + SERVE + HYBRID
+#: roles of a linear-attention (Gated DeltaNet) mixer: its projections,
+#: convolution, normalisations, gates and gated norm; the delta rule alone
+#: (the inner role wins)
+LINEAR = (LINEAR_ATTN, LINEAR_ATTN_SCAN)
+VOCABULARY = TRAIN + SERVE + HYBRID + LINEAR
 _ROLES = frozenset(VOCABULARY)
 
 #: where readers file device time whose instruction carries no role

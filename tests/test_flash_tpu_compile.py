@@ -50,6 +50,8 @@ CELLS = [
     pytest.param(1, 4096, 16, 64, "merged", True, id="gpt2-medium-s4096"),
     pytest.param(4, 2048, 8, 128, "resident", True,
                  id="cerebras-1.3b-s2048-mp2-shard"),
+    pytest.param(1, 16384, 16, 256, "split", False,
+                 id="qwen3-next-s16384-head256"),
 ]
 
 
@@ -289,6 +291,35 @@ def test_grouped_products_compile_at_the_expert_cells_shapes(one_chip, rows):
         spec((8, 1856, 2688), jnp.bfloat16), spec((8,), jnp.int32))
     assert text.count('custom_call_target="tpu_custom_call"') >= 5
     assert gm._tile(2688) == 896 and gm._tile(1856) == 640
+
+
+@pytest.mark.usefixtures("no_persistent_cache")
+@pytest.mark.parametrize("rows", [30720, 163840])
+def test_gated_grouped_products_compile_at_the_narrow_experts_shapes(
+        one_chip, rows):
+    """The same kernels at the widths of ``train_qwen3next_s16384`` (32
+    gated experts, 2,048 -> 512 twice and 512 -> 2,048; the usual launch of
+    30,720 rows and the dropless worst case of 163,840), forward and every
+    gradient: nine Mosaic calls, the axes of 512 one tile each."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+
+    def three(xs, w_gate, w_up, w_down, sizes):
+        gate = gm._gmm(xs, w_gate, sizes, jnp.bfloat16, 512, False)
+        up = gm._gmm(xs, w_up, sizes, jnp.bfloat16, 512, False)
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(jnp.bfloat16)
+        return jnp.sum(gm._gmm(act, w_down, sizes, jnp.float32, 512, False))
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    wide = spec((32, 2048, 512), jnp.bfloat16)
+    text = _compiled_text(
+        jax.grad(three, argnums=(0, 1, 2, 3)),
+        spec((rows, 2048), jnp.bfloat16), wide, wide,
+        spec((32, 512, 2048), jnp.bfloat16), spec((32,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') >= 8
+    assert gm._tile(2048) == 1024 and gm._tile(512) == 512
+    assert gm._row_tile(rows) == 512
 
 
 # ---------------------------------------------------------------------------

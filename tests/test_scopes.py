@@ -137,7 +137,7 @@ def test_a_layer_without_the_attribute_adds_no_scope():
 def test_scope_refuses_a_name_outside_the_vocabulary():
     with pytest.raises(ValueError, match="not in the vocabulary"):
         scopes.scope("attention")
-    assert len(set(scopes.VOCABULARY)) == len(scopes.VOCABULARY) == 15
+    assert len(set(scopes.VOCABULARY)) == len(scopes.VOCABULARY) == 17
 
 
 @pytest.mark.parametrize("op_name,role", [

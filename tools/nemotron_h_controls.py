@@ -47,7 +47,7 @@ if ROOT not in sys.path:
 CELL = "train_nemo3nano_s8192"
 
 
-def setup(rehearse):
+def setup(rehearse, cell=CELL):
     import jax
     if rehearse:
         jax.config.update("jax_platforms", "cpu")
@@ -55,7 +55,7 @@ def setup(rehearse):
         sys.exit("controls: no TPU here; --rehearse runs tiny on the CPU")
     from benchmarks.lib import harness
     spec = harness.benchmark_spec()
-    _, config, traffic = harness.load_cell(spec, CELL, rehearse)
+    _, config, traffic = harness.load_cell(spec, cell, rehearse)
     return config, traffic
 
 
