@@ -15,13 +15,20 @@ state it meets, so a chunk is no masked product alone: inside a chunk of C
 tokens the corrections solve a unit lower-triangular system, ``(I +
 tril_(beta K K^T (.) decay)) U = beta (V - decayed K S_in)``.
 
-* :func:`gated_delta_rule_raw` — the chunked form a training step runs: the
-  triangular inverse by (block) forward substitution, five (C, C) products
-  a value head, then the state carried from chunk to chunk by a
-  ``lax.scan`` (three state products a chunk).  Plain ``jnp`` contractions
-  differentiated by JAX, a ``jax.checkpoint`` of their operands; the
-  inverse has a backward of its own (``dA = -T^T dT T^T``), so no
-  substitution step is ever kept or differentiated;
+* :func:`gated_delta_rule_raw` — the chunked form a training step runs, by
+  one of two implementations chosen by what the code can observe
+  (``kernels.delta_rule.supported``).  On a TPU, or inside
+  ``flash_attention.interpret_scope()``, with key and value heads of whole
+  128-lane tiles: two Pallas kernels under a ``custom_vjp``
+  (``kernels/delta_rule.py``), a chunk's (C, C) system made, inverted and
+  used in VMEM, the state carried down the grid, q, k, v and o read and
+  written where the projections keep them.  Everywhere else (a CPU, the
+  rehearsal sizes of 16 lanes a head): plain ``jnp`` contractions
+  differentiated by JAX, a ``jax.checkpoint`` of their operands — the
+  triangular inverse by (block) forward substitution with a backward of
+  its own (``dA = -T^T dT T^T``), five (C, C) products a value head, then
+  the state carried from chunk to chunk by a ``lax.scan``, key heads in
+  groups past a budget of kept states;
 * :func:`gated_delta_rule_recurrence_raw` — the equations above a token at
   a time, for tests;
 * :func:`l2_normalize_raw` — the per-head normalisation of q and k in front
@@ -30,8 +37,8 @@ tril_(beta K K^T (.) decay)) U = beta (V - decayed K S_in)``.
 Decays, write strengths, the inverse and the carried state are float32
 whatever the activations' type; the matrix products take their operands in
 the activations' type and accumulate in float32.  ``linear_attn.scan_calls
-{path}`` says which implementation was traced (``chunked_jnp``; a Pallas
-kernel is queued, ROADMAP B7).  Raw functions over jax arrays.
+{path}`` says which implementation was traced (``pallas`` or
+``chunked_jnp``).  Raw functions over jax arrays.
 """
 from __future__ import annotations
 
@@ -39,6 +46,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from ...kernels import delta_rule as _kernel
+from ...kernels import flash_attention as _fa
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -212,21 +222,30 @@ def gated_delta_rule_raw(q, k, v, g, beta, chunk=64):
     reads key head ``h // (Hv / Hk)``; g (B, S, Hv) float32 log-decays
     (<= 0); beta (B, S, Hv) in (0, 1).  Returns o (B, S, Hv, P) in v's
     type.  A length that is no multiple of ``chunk`` is padded with tokens
-    that neither decay the state (g = 0) nor write to it (beta = 0).  A row
-    whose chunks' states would pass ``_STATE_HISTORY_BYTES`` in the
-    backward (a layer of 16k tokens and 32 value heads keeps 512 MiB of
-    them) goes through in groups of key heads, one after the other."""
+    that neither decay the state (g = 0) nor write to it (beta = 0).  Where
+    ``kernels.delta_rule.supported`` says so the Pallas kernels run; else
+    the ``jnp`` chunks, in which a row whose chunks' states would pass
+    ``_STATE_HISTORY_BYTES`` in the backward (a layer of 16k tokens and 32
+    value heads keeps 512 MiB of them) goes through in groups of key heads,
+    one after the other."""
     bsz, s, hv, p = v.shape
     hk = k.shape[2]
-    pad = -s % chunk
+    rep = hv // hk
+    interpret = bool(_fa._INTERPRET)
+    kernels = _kernel.supported(chunk, rep, q.shape[-1], p, interpret)
+    # the kernels take whole spans of 128 tokens
+    pad = -s % (max(chunk, 128) if kernels else chunk)
     if pad:
         q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) *
                                     (t.ndim - 2)) for t in (q, k, v, g, beta))
+    g, beta = g.astype(F32), beta.astype(F32)
+    if kernels:
+        note_scan_call("pallas")
+        return _kernel.delta_rule(q, k, v, g, beta, chunk, interpret)[:, :s]
     note_scan_call("chunked_jnp")
-    rep = hv // hk
     operands = (q, k, v.reshape(bsz, s + pad, hk, rep, p),
-                g.astype(F32).reshape(bsz, s + pad, hk, rep),
-                beta.astype(F32).reshape(bsz, s + pad, hk, rep))
+                g.reshape(bsz, s + pad, hk, rep),
+                beta.reshape(bsz, s + pad, hk, rep))
     groups = _head_groups(bsz * (s + pad) // chunk * hv * q.shape[-1] * p
                           * 4, hk)
     if groups == 1:

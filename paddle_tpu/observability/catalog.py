@@ -265,10 +265,13 @@ CATALOG = {
         labels=("path",)),
     "linear_attn.scan_calls": _m(
         "counter", "gated delta rules (the recurrent layer of a Gated "
-        "DeltaNet mixer) traced so far by implementation: "
-        "path='chunked_jnp' (nn/functional/linear_attn.py: the triangular "
-        "inverse inside a chunk, the state carried by a lax.scan; "
-        "differentiated by JAX, kept as a checkpoint of its operands).  "
+        "DeltaNet mixer) traced so far by implementation: path='pallas' "
+        "(kernels/delta_rule.py: a forward and a backward kernel, a chunk's "
+        "(C, C) system made, inverted and used in VMEM, on a TPU for key and "
+        "value heads of whole lane tiles) or 'chunked_jnp' "
+        "(nn/functional/linear_attn.py: the triangular inverse inside a "
+        "chunk, the state carried by a lax.scan; differentiated by JAX, "
+        "kept as a checkpoint of its operands: everywhere else).  "
         "Trace-time, as ssm.scan_calls",
         labels=("path",)),
     "moe.calls": _m(

@@ -443,3 +443,134 @@ def test_the_mixer_layer_keeps_its_scan_in_the_kernels(one_chip):
             for dim in dims.split(","):
                 size *= int(dim)
             assert size < matrices, line[:200]
+
+
+# ---------------------------------------------------------------------------
+# the gated delta rule's kernels at the linear-attention cell's shapes:
+# 1 x 16,384 tokens, 16 key and 32 value heads of 128, chunk 64, bf16
+
+RULE = dict(b=1, s=16384, hk=16, hv=32, d=128, chunk=64, hidden=2048)
+
+
+def _rule_specs(one_chip):
+    c = RULE
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    return (spec((c["b"], c["s"], c["hk"], c["d"]), jnp.bfloat16),
+            spec((c["b"], c["s"], c["hk"], c["d"]), jnp.bfloat16),
+            spec((c["b"], c["s"], c["hv"], c["d"]), jnp.bfloat16),
+            spec((c["b"], c["s"], c["hv"]), jnp.float32),
+            spec((c["b"], c["s"], c["hv"]), jnp.float32))
+
+
+@pytest.mark.usefixtures("no_persistent_cache")
+@pytest.mark.parametrize("way", ["forward", "backward"])
+def test_delta_rule_kernels_compile_at_the_linear_attention_cells_shapes(
+        one_chip, way):
+    """``gated_delta_rule_raw`` asks the backend, which is a CPU here: the
+    ``custom_vjp`` under it is called directly.  Mosaic takes the (8, 128)
+    tile of per-token vectors it turns over, the float32 products of the
+    block substitution, the one-row stores and the transposed products."""
+    from paddle_tpu.kernels import delta_rule as kd
+    assert kd.supported(RULE["chunk"], RULE["hv"] // RULE["hk"], RULE["d"],
+                        RULE["d"], interpret=True)
+
+    def rule(*args):
+        return kd.delta_rule(*args, RULE["chunk"], False)
+
+    fn = rule if way == "forward" else jax.grad(
+        lambda *a: jnp.sum(rule(*a).astype(jnp.float32)), argnums=range(5))
+    text = _compiled_text(fn, *_rule_specs(one_chip))
+    kernels = re.findall(r"%(delta_rule_\w+?)[.\d]* = ", text)
+    assert sorted(set(kernels)) == (
+        ["delta_rule_fwd"] if way == "forward" else
+        ["delta_rule_bwd", "delta_rule_fwd"])
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        len(kernels) == (1 if way == "forward" else 2)
+
+
+def _delta_net_layer():
+    """``GatedDeltaNet`` as ``models/qwen3_next.py`` writes it (projections,
+    convolution, normalisations, gates, the rule, gated norm, projection)
+    under its two roles, a recomputed layer as the cell runs it, loss and
+    gradients."""
+    import math
+    from paddle_tpu.kernels import delta_rule as kd
+    from paddle_tpu.nn.functional import linear_attn as fl
+    from paddle_tpu.nn.functional import ssm as fs
+    from paddle_tpu.observability import scopes
+    c = RULE
+    key_dim, value_dim = c["hk"] * c["d"], c["hv"] * c["d"]
+
+    def layer(x, w_qkvz, w_ba, conv_w, a_log, dt_bias, w_out):
+        with scopes.scope(scopes.LINEAR_ATTN):
+            qkvz = jnp.einsum("bsh,hk->bsk", x, w_qkvz)
+            ba = jnp.einsum("bsh,hk->bsk", x, w_ba).astype(jnp.float32)
+            qkv = fs.causal_conv1d_raw(qkvz[..., :2 * key_dim + value_dim],
+                                       conv_w, silu=True)
+            z = qkvz[..., 2 * key_dim + value_dim:]
+            q = fl.l2_normalize_raw(
+                qkv[..., :key_dim].reshape(c["b"], c["s"], c["hk"], c["d"]),
+                scale=1.0 / math.sqrt(c["d"]))
+            k = fl.l2_normalize_raw(qkv[..., key_dim:2 * key_dim].reshape(
+                c["b"], c["s"], c["hk"], c["d"]))
+            v = qkv[..., 2 * key_dim:].reshape(c["b"], c["s"], c["hv"],
+                                               c["d"])
+            beta = jax.nn.sigmoid(ba[..., :c["hv"]])
+            g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., c["hv"]:] + dt_bias)
+            with scopes.scope(scopes.LINEAR_ATTN_SCAN):
+                o = kd.delta_rule(q, k, v, g, beta, c["chunk"], False)
+            gated = (o.reshape(c["b"], c["s"], value_dim).astype(jnp.float32)
+                     * jax.nn.silu(z.astype(jnp.float32))).astype(o.dtype)
+            out = jnp.einsum("bsk,kh->bsh", gated, w_out)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    shapes = [((c["b"], c["s"], c["hidden"]), jnp.bfloat16),
+              ((c["hidden"], 2 * key_dim + 2 * value_dim), jnp.bfloat16),
+              ((c["hidden"], 2 * c["hv"]), jnp.bfloat16),
+              ((4, 2 * key_dim + value_dim), jnp.bfloat16),
+              ((c["hv"],), jnp.float32), ((c["hv"],), jnp.float32),
+              ((value_dim, c["hidden"]), jnp.bfloat16)]
+    return jax.value_and_grad(jax.checkpoint(layer),
+                              argnums=tuple(range(7))), shapes
+
+
+@pytest.mark.usefixtures("no_persistent_cache")
+def test_the_delta_net_layer_keeps_its_rule_in_the_kernels(one_chip):
+    """Forward, the layer's recomputation and the backward: three Mosaic
+    calls under ``linear_attn_scan``; the first writes o alone, the second
+    the states entering its grid steps beside it (128 MiB of float32); under that role no
+    ``while`` (the ``jnp`` path's ``lax.scan`` and head groups), no matrix
+    product of XLA's and no float32 buffer of the size of a layer's (C, C)
+    matrices (the decays, systems and inverses of the ``jnp`` path: 128
+    MiB each) but those states, nor any (..., 64, 64) one."""
+    fn, shapes = _delta_net_layer()
+    text = _compiled_text(fn, *(jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                                for s, d in shapes))
+    under = [line for line in text.splitlines()
+             if re.search(r'op_name="[^"]*\blinear_attn_scan\b', line)]
+    calls = [line for line in under
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = sorted(re.match(r"\s*%([a-z_]+)", line).group(1)
+                   for line in calls)
+    assert names == ["delta_rule_bwd", "delta_rule_fwd", "delta_rule_fwd"]
+    assert len(calls) == text.count('custom_call_target="tpu_custom_call"')
+    c = RULE
+    states = "f32[%d,%d,%d,%d,%d]" % (     # a grid step takes 256 tokens
+        c["b"], c["hk"], c["s"] // 256, c["d"], c["hv"] // c["hk"] * c["d"])
+    head = lambda line: line.split(" custom-call(")[0]
+    assert sorted(states in head(line) for line in calls
+                  if "%delta_rule_fwd" in line) == [False, True]
+    assert not [line for line in under
+                if re.search(r" (dot|convolution|while)\(", line)]
+    matrices = 4 * c["s"] // c["chunk"] * c["hv"] * c["chunk"] ** 2
+    for line in under:
+        if line in calls:
+            continue
+        for dims in re.findall(r"f32\[([\d,]+)\]",
+                               line.split(" = ")[1].split("(")[0]):
+            size = 4
+            for dim in dims.split(","):
+                size *= int(dim)
+            assert size < matrices or "f32[%s]" % dims == states, line[:200]
+            assert not dims.endswith(",%d,%d" % (c["chunk"], c["chunk"]))

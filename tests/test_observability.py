@@ -421,6 +421,12 @@ def test_catalog_coverage_is_two_way(monkeypatch, tmp_path):
         hybrid_override_pattern="ME"))
     hybrid(paddle.Tensor(jnp.zeros((1, 16), jnp.int32)))
 
+    # -- a traced gated delta rule counts itself (linear_attn.scan_calls) --
+    from paddle_tpu.nn.functional import linear_attn
+    tokens = jnp.zeros((1, 16, 1, 16), jnp.float32)
+    linear_attn.gated_delta_rule_raw(tokens, tokens, tokens,
+                                     tokens[..., 0], tokens[..., 0], 16)
+
     # -- HBM ledger: one armed sample prices live arrays + KV pools --------
     hbm.enable()
     try:
