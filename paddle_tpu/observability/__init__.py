@@ -20,7 +20,9 @@ subsystem:
 * :mod:`.scopes` — the program's own names inside its compiled programs:
   the one vocabulary of ``jax.named_scope`` roles (``attn``, ``mlp``,
   ``optimizer``, ``decode_attn``, ...) and the index from a compiled
-  program's instructions back to them, by which a device trace is read.
+  program's instructions back to them, by which a device trace is read:
+  each instruction's role, its phase (forward, recompute, backward,
+  update), and for what the compiler made the role of the work it serves.
 * :mod:`.exporters` — Prometheus text, JSONL snapshots, chrome-trace
   metric marks injected into the :mod:`paddle_tpu.profiler` stream.
 * :mod:`.tracing` — request-scoped span tracing (ISSUE 9): a trace_id

@@ -23,8 +23,12 @@ disconnected — CI uses both as hard gates.
 
 ``programs`` (ISSUE 11) prices the trace-audit canonical registry with
 XLA's own cost/memory analysis: one FLOPs / bytes-accessed / peak-HBM
-row per program (:mod:`.costs`), and how many of its instructions carry
-each of the program's scopes (:mod:`.scopes`).  Same operational discipline as the
+row per program (:mod:`.costs`), how many of its instructions carry
+each of the program's scopes and, under the row, the same instructions by
+role and phase, counted ``own+user+operand`` after where the role came
+from, with ``unresolved`` for those no role was found for
+(:func:`.scopes.instruction_provenance`): which instructions of a new
+model have no owner, seen without a chip.  Same operational discipline as the
 ``--trace`` analysis CLI: an empty registry exits 2 (never silent
 green), broken builders exit 1, and the process must be launched with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` off-chip so the

@@ -263,21 +263,33 @@ def collective_stats(compiled) -> Optional[Dict[str, Any]]:
             "by_kind": by_kind}
 
 
-def scope_ops(compiled) -> Optional[Dict[str, int]]:
-    """``{scope: instructions}`` of a compiled program
-    (:func:`.scopes.instruction_scopes` over its HLO text); None when the
-    backend gives no text."""
+#: the ``provenance_ops`` key of instructions no role was found for
+UNRESOLVED = "unresolved"
+
+
+def instruction_counts(compiled) -> Optional[Dict[str, Dict[str, int]]]:
+    """How many instructions of a compiled program (those that run as
+    operations of their own: :func:`.scopes.instruction_provenance` over
+    its HLO text) carry what: ``{"scope_ops": {scope: n}}`` by the role of
+    their own ``op_name`` (``unscoped`` for none), ``{"provenance_ops":
+    {"role/phase/how": n}}`` by role, phase and where the role came from,
+    those with no role even from their neighbours under ``unresolved``.
+    None when the backend gives no text."""
     try:
         text = compiled.as_text()
     except Exception:
         return None
     if not text:
         return None
-    counts: Dict[str, int] = {}
-    for role in _scopes.instruction_scopes(text)[1].values():
-        role = role or _scopes.UNSCOPED
-        counts[role] = counts.get(role, 0) + 1
-    return counts
+    own: Dict[str, int] = {}
+    found: Dict[str, int] = {}
+    for p in _scopes.instruction_provenance(text)[1].values():
+        role = (p.role if p.how == "own" else None) or _scopes.UNSCOPED
+        own[role] = own.get(role, 0) + 1
+        key = ("%s/%s/%s" % (p.role, p.phase or "none", p.how) if p.role
+               else UNRESOLVED)
+        found[key] = found.get(key, 0) + 1
+    return {"scope_ops": own, "provenance_ops": found}
 
 
 @dataclasses.dataclass
@@ -321,6 +333,12 @@ class ProgramReport:
     #: names a device trace of this program can be read by.  None when the
     #: backend exposes no HLO text
     scope_ops: Optional[Dict[str, int]] = None
+    #: PR 37: the same instructions by ``role/phase/how`` (forward,
+    #: recompute, backward, update or none; the role from the instruction's
+    #: ``own`` name, its ``user``s or its ``operand``s), and ``unresolved``
+    #: for those no role was found for: which of a new model's instructions
+    #: have no owner, seen without a chip
+    provenance_ops: Optional[Dict[str, int]] = None
 
     def as_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -350,7 +368,7 @@ def report_from_compiled(name: str, compiled, backend: Optional[str] = None,
     mem = memory_analysis_dict(compiled)
     coll = collective_stats(compiled)
     return ProgramReport(
-        scope_ops=scope_ops(compiled),
+        **(instruction_counts(compiled) or {}),
         name=name, backend=backend, available=True, note=note,
         flops=(float(ca["flops"]) if "flops" in ca else None),
         bytes_accessed=(float(ca["bytes accessed"])
@@ -483,8 +501,30 @@ def _fmt_num(v: Optional[float]) -> str:
     return "%.0f" % v
 
 
+def _provenance_lines(counts: Dict[str, int]) -> List[str]:
+    """``provenance_ops`` as one line a role: for each phase the
+    instructions by their own name + by their users + by their operands."""
+    rows: Dict[str, Dict[str, Dict[str, int]]] = {}
+    for key, n in counts.items():
+        if key != UNRESOLVED:
+            role, phase, how = key.split("/")
+            rows.setdefault(role, {}).setdefault(phase, {})[how] = n
+    order = _scopes.PHASES + ("none",)
+    lines = ["    %-16s %s" % (role, "  ".join(
+        "%s %s" % (phase, "+".join(
+            str(rows[role][phase].get(how, 0))
+            for how in ("own", "user", "operand")))
+        for phase in order if phase in rows[role]))
+        for role in sorted(rows)]
+    lines.append("    %-16s %d" % (UNRESOLVED, counts.get(UNRESOLVED, 0)))
+    return lines
+
+
 def format_table(reports: Sequence[ProgramReport]) -> str:
-    """Human table for ``python -m paddle_tpu.observability programs``."""
+    """Human table for ``python -m paddle_tpu.observability programs``:
+    under each program's row its instructions by role and phase, counted
+    ``own+user+operand`` (where the role came from), and how many have no
+    role even from their neighbours."""
     lines = ["%-42s %10s %10s %10s %10s %10s  %s"
              % ("program", "flops", "hbm_bytes", "peak", "args", "temps",
                 "scopes (instructions) / note")]
@@ -497,6 +537,8 @@ def format_table(reports: Sequence[ProgramReport]) -> str:
                         _fmt_num(r.bytes_accessed), _fmt_num(r.peak_bytes),
                         _fmt_num(r.argument_bytes), _fmt_num(r.temp_bytes),
                         " / ".join(x for x in (roles, note) if x)))
+        if r.provenance_ops:
+            lines.extend(_provenance_lines(r.provenance_ops))
     avail = sum(1 for r in reports if r.available)
     lines.append("%d program(s), %d priced (backend: %s)"
                  % (len(reports), avail, _backend_name()))

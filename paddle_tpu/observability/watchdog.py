@@ -39,6 +39,7 @@ import contextlib
 import json
 import os
 import threading
+import time
 import warnings
 import weakref
 from typing import Callable, Dict, Optional
@@ -290,23 +291,39 @@ class Program:
     after the step object has gone, as the benchmark's do, still finds its
     program."""
 
-    __slots__ = ("entry_name", "fn_name", "_traced", "_scopes")
+    __slots__ = ("entry_name", "fn_name", "_traced", "_provenance",
+                 "_scopes", "read_seconds")
 
     def __init__(self, entry_name, fn_name, traced):
         self.entry_name = entry_name
         self.fn_name = fn_name
         self._traced = traced
+        self._provenance = None
         self._scopes = None
+        #: what reading the text cost (compile look-up and parse), once read
+        self.read_seconds = None
 
-    def instruction_scopes(self):
-        """``(HLO module name, {instruction name: scope or None})``
-        (:func:`.scopes.instruction_scopes`); kept, so a second call
-        compiles nothing."""
-        if self._scopes is None:
+    def provenance(self):
+        """``(HLO module name, {instruction name: Provenance})``
+        (:func:`.scopes.instruction_provenance`); kept, and the text is
+        not, so a second call compiles and parses nothing."""
+        if self._provenance is None:
             from . import scopes as _scopes
+            t0 = time.perf_counter()
             with _filing_under(self):   # its compile events are the entry's
                 text = self._traced.lower().compile().as_text()
-            self._scopes = _scopes.instruction_scopes(text)
+            self._provenance = _scopes.instruction_provenance(text)
+            self.read_seconds = time.perf_counter() - t0
+        return self._provenance
+
+    def instruction_scopes(self):
+        """``(HLO module name, {instruction name: scope or None})``:
+        :meth:`provenance` as :func:`.scopes.instruction_scopes` projects
+        it, from the same compile and the same parse."""
+        if self._scopes is None:
+            from . import scopes as _scopes
+            module, table = self.provenance()
+            self._scopes = (module, _scopes.own_roles(table))
         return self._scopes
 
 
