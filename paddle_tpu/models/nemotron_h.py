@@ -158,12 +158,14 @@ class Mamba2Mixer(Layer):
         def raw(proj, conv_w, conv_b, a_log, dt_bias, d, norm_w):
             b, s, _ = proj.shape
             z = proj[..., :d_inner]
-            xbc = proj[..., d_inner:d_inner + conv_dim]
             dt = proj[..., d_inner + conv_dim:]
-            xbc = FS.causal_conv1d_raw(xbc, conv_w, conv_b, silu=True)
-            x = xbc[..., :d_inner].reshape(b, s, heads, p)
-            bmat = xbc[..., d_inner:d_inner + g * n].reshape(b, s, g, n)
-            cmat = xbc[..., d_inner + g * n:].reshape(b, s, g, n)
+            # convolution, SiLU and the split: flat (b, s, width) parts
+            x, bmat, cmat = FS.conv_split_raw(
+                proj, d_inner, ((d_inner, None, 1.0), (g * n, None, 1.0),
+                                (g * n, None, 1.0)),
+                conv_w, conv_b, silu=True)
+            x = x.reshape(b, s, heads, p)
+            bmat, cmat = bmat.reshape(b, s, g, n), cmat.reshape(b, s, g, n)
             dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
             with _scopes.scope(_scopes.SSM_SCAN):
                 y = FS.ssd_scan_raw(x, dt, -jnp.exp(a_log), bmat, cmat, d,

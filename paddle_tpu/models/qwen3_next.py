@@ -180,15 +180,15 @@ class GatedDeltaNet(Layer):
 
         def raw(qkvz, ba, conv_w, a_log, dt_bias, norm_w):
             b, s, _ = qkvz.shape
-            qkv = FS.causal_conv1d_raw(qkvz[..., :2 * key_dim + value_dim],
-                                       conv_w, silu=True)
+            # convolution, SiLU, the split and q's and k's normalisation
+            # over a head's lanes: flat (b, s, width) parts
+            q, k, v = FS.conv_split_raw(
+                qkvz, 0, ((key_dim, dk, 1.0 / math.sqrt(dk)),
+                          (key_dim, dk, 1.0), (value_dim, None, 1.0)),
+                conv_w, silu=True)
+            q, k = q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk)
+            v = v.reshape(b, s, hv, dv)
             z = qkvz[..., 2 * key_dim + value_dim:].reshape(b, s, hv, dv)
-            q = FL.l2_normalize_raw(
-                qkv[..., :key_dim].reshape(b, s, hk, dk),
-                scale=1.0 / math.sqrt(dk))
-            k = FL.l2_normalize_raw(
-                qkv[..., key_dim:2 * key_dim].reshape(b, s, hk, dk))
-            v = qkv[..., 2 * key_dim:].reshape(b, s, hv, dv)
             ba32 = ba.astype(jnp.float32)
             beta = jax.nn.sigmoid(ba32[..., :hv])
             g = -jnp.exp(a_log) * jax.nn.softplus(ba32[..., hv:] + dt_bias)

@@ -3,6 +3,14 @@
 
 * :func:`causal_conv1d_raw` — the causal depthwise convolution in front of
   the scan;
+* :func:`conv_split_raw` — all that stands between a mixer's fused input
+  projection and its scan: that convolution over some of the projection's
+  columns, SiLU, the split into the scan's operands and a per-head L2
+  normalisation of the parts that ask.  On a TPU, for columns in whole
+  128-lane tiles, ``kernels/causal_conv.py``: a Pallas kernel each way
+  that reads the projection's buffer in place and writes each part where
+  the scan's kernels read it.  Everywhere else the ``jnp`` functions, and
+  ``ssm.conv_calls{path}`` says which was traced;
 * :func:`ssd_scan_raw` — ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``,
   ``y_t = C_t . S_t + D x_t``, computed a chunk at a time: inside a chunk as
   masked matrix products (the "dual" quadratic form), between chunks as a
@@ -30,21 +38,32 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...kernels import causal_conv as _conv_kernel
 from ...kernels import flash_attention as _fa
 from ...kernels import ssd_scan as _kernel
 
 F32 = jnp.float32
 
 
+def _count(name: str, path: str) -> None:
+    try:
+        from ...observability import registry as _reg
+        _reg.counter(name, ("path",)).labels(path=path).inc()
+    except Exception:
+        pass
+
+
 def note_scan_call(path: str) -> None:
     """Drive ``ssm.scan_calls{path}`` at trace time: one inc per scan
     traced (a compile-once program contributes once a trace, as the
     ``flash.*`` counters do)."""
-    try:
-        from ...observability import registry as _reg
-        _reg.counter("ssm.scan_calls", ("path",)).labels(path=path).inc()
-    except Exception:
-        pass
+    _count("ssm.scan_calls", path)
+
+
+def note_conv_call(path: str) -> None:
+    """Drive ``ssm.conv_calls{path}`` at trace time: one inc per
+    :func:`conv_split_raw` traced, ``pallas`` or ``jnp``."""
+    _count("ssm.conv_calls", path)
 
 
 def causal_conv1d_raw(x, weight, bias=None, silu=False):
@@ -61,6 +80,47 @@ def causal_conv1d_raw(x, weight, bias=None, silu=False):
     if silu:
         out = jax.nn.silu(out)
     return out.astype(x.dtype)
+
+
+def _conv_split_jnp(proj, offset, parts, weight, bias, silu):
+    """:func:`conv_split_raw` by ``causal_conv1d_raw`` on a slice, slices
+    and ``l2_normalize_raw``."""
+    from .linear_attn import l2_normalize_raw
+    bsz, s, _ = proj.shape
+    x = causal_conv1d_raw(proj[..., offset:offset + weight.shape[1]],
+                          weight, bias, silu)
+    outs, at = [], 0
+    for width, head, scale in parts:
+        part = x[..., at:at + width]
+        if head:
+            part = l2_normalize_raw(
+                part.reshape(bsz, s, width // head, head),
+                scale=scale).reshape(bsz, s, width)
+        outs.append(part)
+        at += width
+    return tuple(outs)
+
+
+def conv_split_raw(proj, offset, parts, weight, bias=None, silu=False):
+    """proj (b, s, w): a fused projection as its GEMM leaves it; weight (k,
+    c), bias (c,) over the ``c`` columns from ``offset`` on, ``c`` the sum
+    of the parts' widths.  ``parts``: ``(width, head_dim or None, scale)``
+    each, in the columns' order.  Returns a flat (b, s, width) array a
+    part: ``causal_conv1d_raw`` of those columns, cut into the parts, a
+    part with a ``head_dim`` L2-normalised over each head's lanes and
+    multiplied by ``scale`` (``linear_attn.l2_normalize_raw``).  Where
+    ``kernels.causal_conv.supported`` says so the Pallas kernels run, which
+    read ``proj`` in place; else the ``jnp`` functions."""
+    parts = tuple((int(w), h and int(h), float(scale))
+                  for w, h, scale in parts)
+    interpret = bool(_fa._INTERPRET)
+    if _conv_kernel.supported(proj.shape[1], proj.shape[2], offset, parts,
+                              weight.shape[0], interpret):
+        note_conv_call("pallas")
+        return _conv_kernel.conv_split(proj, weight, bias, offset, parts,
+                                       silu, interpret)
+    note_conv_call("jnp")
+    return _conv_split_jnp(proj, offset, parts, weight, bias, silu)
 
 
 def _chunked(a, chunk):

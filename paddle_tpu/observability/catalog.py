@@ -263,6 +263,17 @@ CATALOG = {
         "program contributes once a trace of the layer (a recomputed "
         "block is traced again)",
         labels=("path",)),
+    "ssm.conv_calls": _m(
+        "counter", "what stands in front of a recurrent scan (causal "
+        "convolution, SiLU, the split of the fused projection, per-head "
+        "L2 normalisation: nn/functional/ssm.py::conv_split_raw) traced "
+        "so far by implementation: path='pallas' (kernels/causal_conv.py: "
+        "a forward and a backward kernel that read the projection's buffer "
+        "in place, on a TPU for an offset and parts in whole lane tiles) "
+        "or 'jnp' (a slice, shifted multiply-adds and slices "
+        "differentiated by JAX: everywhere else).  Trace-time, as "
+        "ssm.scan_calls",
+        labels=("path",)),
     "linear_attn.scan_calls": _m(
         "counter", "gated delta rules (the recurrent layer of a Gated "
         "DeltaNet mixer) traced so far by implementation: path='pallas' "

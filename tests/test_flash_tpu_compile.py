@@ -574,3 +574,163 @@ def test_the_delta_net_layer_keeps_its_rule_in_the_kernels(one_chip):
                 size *= int(dim)
             assert size < matrices or "f32[%s]" % dims == states, line[:200]
             assert not dims.endswith(",%d,%d" % (c["chunk"], c["chunk"]))
+
+
+# ---------------------------------------------------------------------------
+# what stands in front of the scans (PR 38): the convolution's kernels at the
+# two recurrent cells' shapes, and a mixer of each family around them
+
+# (tokens, the projection's width, the convolved columns' offset, parts,
+# bias?): ``models/nemotron_h.py``'s [z | x B C | dt] and
+# ``models/qwen3_next.py``'s [q k v | z]
+CONV = {
+    "nemo3nano": (8192, 10304, 4096,
+                  ((4096, None, 1.0), (1024, None, 1.0), (1024, None, 1.0)),
+                  True),
+    "qwen3next": (16384, 12288, 0,
+                  ((2048, 128, 128 ** -0.5), (2048, 128, 1.0),
+                   (4096, None, 1.0)), False),
+}
+
+
+@pytest.mark.usefixtures("no_persistent_cache")
+@pytest.mark.parametrize("way", ["forward", "backward"])
+@pytest.mark.parametrize("cell", CONV)
+def test_conv_kernels_compile_at_the_recurrent_cells_shapes(one_chip, cell,
+                                                            way):
+    """``conv_split_raw`` asks the backend, which is a CPU here: the
+    ``custom_vjp`` under it is called directly.  Mosaic takes the channel
+    tiles picked out of a buffer of 80.5 lane tiles, the 16-row block in
+    front, the sublane rotations, the one-row accumulator stores and the
+    reversed token axis."""
+    from paddle_tpu.kernels import causal_conv as kc
+    seq, width, offset, parts, bias = CONV[cell]
+    assert kc.supported(seq, width, offset, parts, 4, interpret=True)
+    channels = sum(p[0] for p in parts)
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                               sharding=one_chip)
+    args = [spec(1, seq, width), spec(4, channels)] + [spec(channels)] * bias
+
+    def split(proj, taps, b=None):
+        return kc.conv_split(proj, taps, b, offset, parts, True, False)
+
+    fn = split if way == "forward" else jax.grad(
+        lambda *a: sum(jnp.sum(o.astype(jnp.float32) ** 2)
+                       for o in split(*a)), argnums=range(len(args)))
+    text = _compiled_text(fn, *args)
+    kernels = re.findall(r"%(causal_conv_\w+?)[.\d]* = ", text)
+    assert sorted(kernels) == (["causal_conv_bwd"] * 3 if way == "backward"
+                               else []) + ["causal_conv_fwd"] * 3
+    assert text.count('custom_call_target="tpu_custom_call"') == len(kernels)
+
+
+def _recurrent_mixer(cell):
+    """(value_and_grad of a recomputed mixer built from the model's own
+    class at the cell's widths, its arguments' shapes)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.jit import functional_call
+    if cell == "qwen3next":
+        from paddle_tpu.models.qwen3_next import (GatedDeltaNet,
+                                                  Qwen3NextConfig)
+        layer, hidden = GatedDeltaNet(Qwen3NextConfig(
+            num_hidden_layers=4)), 2048
+    else:
+        from paddle_tpu.models.nemotron_h import Mamba2Mixer, NemotronHConfig
+        layer, hidden = Mamba2Mixer(NemotronHConfig()), 2688
+    state = {name: (t._array.shape, jnp.float32 if getattr(
+        t, "keep_fp32", False) else jnp.bfloat16)
+        for name, t in layer.state_dict().items()}
+
+    def loss(state, x):
+        out, _ = functional_call(layer, state, paddle.Tensor(x))
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    return (jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1)), state,
+            ((1, CONV[cell][0], hidden), jnp.bfloat16))
+
+
+@pytest.mark.usefixtures("no_persistent_cache")
+@pytest.mark.parametrize("cell", CONV)
+def test_no_pass_between_the_projection_and_the_scan(one_chip, monkeypatch,
+                                                     cell):
+    """``Mamba2Mixer`` / ``GatedDeltaNet`` themselves, a recomputed block,
+    loss and gradients, dispatched as on a TPU.  Forward and second
+    forward: the input projection's GEMM fusion writes ONE wide buffer,
+    the convolution's kernels read it as it is and the scan's kernel reads
+    what they write as it is.  Backward: the scan's backward kernel hands
+    its cotangents to the convolution's, whose column gradients enter both
+    gradient GEMMs of the projection as operands (no cotangent buffer of
+    the wide shape exists).  Nowhere a float32 array of the convolved
+    width, nor any array of a part's shape that a kernel did not write."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    seq, width, _, parts, _ = CONV[cell]
+    fn, state, (x_shape, x_dtype) = _recurrent_mixer(cell)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    text = _compiled_text(fn, {k: spec(*v) for k, v in state.items()},
+                          spec(x_shape, x_dtype))
+    ins = _entry_instructions(text)
+
+    def source(name):
+        """``name`` behind the compiler's own plumbing."""
+        while ins[name][1] in ("copy-done", "bitcast", "get-tuple-element"):
+            name = (_through_async_copies(ins, name)
+                    if ins[name][1] == "copy-done" else ins[name][2][0])
+        return name
+
+    def kernels(prefix):
+        return [n for n in ins if n.startswith(prefix)
+                and ins[n][1] == "custom-call"]
+
+    def kind(name):
+        return "kOutput" if "kind=kOutput" in ins[name][3] else \
+            "kLoop" if "kind=kLoop" in ins[name][3] else None
+
+    scan = "delta_rule" if cell == "qwen3next" else "ssd_scan"
+    conv_fwd, conv_bwd = kernels("causal_conv_fwd"), kernels("causal_conv_bwd")
+    scan_fwd, scan_bwd = kernels(scan + "_fwd"), kernels(scan + "_bwd")
+    assert (len(conv_fwd), len(conv_bwd), len(scan_fwd), len(scan_bwd)) == (
+        6, 3, 2, 1)
+    wide = "bf16[1,%d,%d]" % (seq, width)
+    gemms = [n for n, (t, op, _, _) in ins.items()
+             if t == wide and op not in ("copy-done", "bitcast")]
+    assert len(gemms) == 2 and {kind(g) for g in gemms} == {"kOutput"}
+    # each projection's buffer goes into three kernels whole, twice each
+    # (the block and the rows in front of it)
+    for gemm in gemms:
+        mine = [k for k in conv_fwd if source(ins[k][2][0]) == gemm]
+        assert len(mine) == 3
+        for k in mine:
+            assert source(ins[k][2][1]) == gemm
+    # ... and each scan reads three of those kernels' outputs as they are
+    part_types = ["bf16[1,%d,%d]" % (seq, p[0]) for p in parts]
+    for k in scan_fwd:
+        feeds = [source(o) for o in ins[k][2][:3]]
+        assert sorted(ins[f][0] for f in feeds) == sorted(part_types)
+        assert set(feeds) <= set(conv_fwd) and len(set(feeds)) == 3
+    # the backward: cotangents from the scan's kernel straight into the
+    # convolution's, together with the second forward's projection
+    for k in conv_bwd:
+        assert source(ins[k][2][0]) == scan_bwd[0]
+        assert source(ins[k][2][1]) in gemms
+    # a convolution kernel's column gradient is read by the projection's
+    # two gradient GEMMs and nothing else
+    for k in conv_bwd:
+        readers = [n for n, (_, op, operands, _) in ins.items()
+                   if op == "fusion" and k in {source(o) for o in operands}]
+        assert sorted(kind(r) for r in readers) == ["kOutput"] * 2
+        weight = "bf16[%d,%d]" % (x_shape[2], width)
+        (inputs,) = {ins[r][0] for r in readers} - {weight}
+        assert inputs.endswith("%d,%d]" % (seq, x_shape[2]))
+    # nothing else has the convolved width or the wide shape, and no
+    # float32 array of the convolved width exists anywhere
+    channels = sum(p[0] for p in parts)
+    allowed = set(conv_fwd + conv_bwd + scan_fwd + scan_bwd + gemms)
+    for n, (t, op, _, _) in ins.items():
+        if op in ("get-tuple-element", "bitcast", "copy-start", "copy-done",
+                  "parameter") or n in allowed:
+            continue
+        assert "[1,%d,%d]" % (seq, channels) not in t, (n, t)
+        assert "[1,%d,%d]" % (seq + 3, channels) not in t, (n, t)
+        assert wide not in t, (n, t)
+    assert not re.findall(r"f32\[1,%d,%d\]" % (seq, channels), text)

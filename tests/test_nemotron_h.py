@@ -345,6 +345,9 @@ def test_the_counters_count_at_trace_time(step_op_names):
     # one Mamba-2 block, recomputed: traced for the forward and again for
     # the backward; one expert block, traced once
     assert delta("ssm.scan_calls", ("chunked_jnp",)) >= 1
+    # ... and what stands in front of it, at 4 heads of 16: the jnp path
+    assert delta("ssm.conv_calls", ("jnp",)) >= 1
+    assert delta("ssm.conv_calls", ("pallas",)) == 0
     calls = delta("moe.calls", ("ragged_dot",))
     assert calls >= 1
     tokens, k, held, width = 2 * 32, 2, 8, 8

@@ -414,7 +414,8 @@ def test_catalog_coverage_is_two_way(monkeypatch, tmp_path):
         x, x, x, causal=True, interpret=True)))(qkv)
 
     # -- hybrid blocks: one traced Mamba-2 scan and one traced expert
-    # layer count themselves (ssm.scan_calls, moe.calls, moe.rows) ---------
+    # layer count themselves (ssm.scan_calls, ssm.conv_calls, moe.calls,
+    # moe.rows) --------------------------------------------------------------
     from paddle_tpu.models.nemotron_h import (NemotronHConfig,
                                               NemotronHForCausalLM)
     hybrid = NemotronHForCausalLM(NemotronHConfig.tiny(

@@ -509,6 +509,10 @@ def test_the_counters_count_at_trace_time(step_op_names):
     # three Gated DeltaNet layers, recomputed: traced for the forward and
     # again for the backward; four expert layers
     assert delta("linear_attn.scan_calls", ("chunked_jnp",)) >= 3
+    # ... and what stands in front of each rule, at heads of 16 lanes
+    assert CATALOG["ssm.conv_calls"]
+    assert delta("ssm.conv_calls", ("jnp",)) >= 3
+    assert delta("ssm.conv_calls", ("pallas",)) == 0
     calls = delta("moe.calls", ("ragged_dot",))
     assert calls >= 4
     tokens, k, held, width = 2 * 32, 2, 8, 8
